@@ -128,9 +128,6 @@ def _apply_impl(prim, args, kwargs, name):
         elif _amp.should_cast_to_high(name):
             from .dtypes import float32
             prim = _amp_cast_prim(prim, float32)
-    # NOTE: unwrap() reads Tensor._value, which (under host staging) pulls
-    # accelerator-resident state back to the host before eager execution —
-    # see core/tensor.py _pull_host_value.
     raw = [unwrap(a) for a in args]
     record = autograd.is_grad_enabled()
     diff_idx = []

@@ -11,17 +11,35 @@ from __future__ import annotations
 
 import jax
 
-from .tensor import Tensor
+from .tensor import Tensor, _TraceHooks
 
 __all__ = ["seed", "next_key", "get_state", "set_state", "Generator", "default_generator"]
 
 
 class Generator:
     def __init__(self, seed_: int = 0):
-        self._key = Tensor(jax.random.key_data(jax.random.PRNGKey(seed_)),
+        # the key Tensor is built on first use: building it here would make
+        # `import paddle_tpu` initialise a JAX backend (default_generator
+        # below), and a process that has done that holds the chip
+        self._seed = int(seed_)
+        self._key_tensor = None
+
+    @property
+    def _key(self):
+        t = self._key_tensor
+        if t is None:
+            # pre-existing state, not a trace-local temporary: a first use
+            # inside a to_static discovery pass must not report it as created
+            prev, _TraceHooks.on_create = _TraceHooks.on_create, None
+            try:
+                t = Tensor(jax.random.key_data(jax.random.PRNGKey(self._seed)),
                            stop_gradient=True)
-        self._key.persistable = True
-        self._key.name = "generator_key"
+            finally:
+                _TraceHooks.on_create = prev
+            t.persistable = True
+            t.name = "generator_key"
+            self._key_tensor = t
+        return t
 
     def manual_seed(self, seed_: int):
         self._key._value = jax.random.key_data(jax.random.PRNGKey(int(seed_)))

@@ -27,46 +27,6 @@ class _TraceHooks:
     on_create = None  # fn(tensor) — called from Tensor.__init__
 
 
-class _HostPull:
-    """Host-staging placement guard (core/device.py host_staging_enabled).
-
-    Compiled to_static programs write their updated state back as accelerator
-    arrays; eager ops execute on the host. Reading `_value` of a tensor whose
-    buffer a compiled program left on the accelerator pulls it back to the
-    host once (the pull rebinds `_val`, so it doesn't repeat). `enabled` is
-    resolved lazily on first read: None = unknown, then True/False.
-    """
-    enabled = None
-    cpu = None
-
-
-# write-seam: host-staging pull rebinds _val to a device_put copy of the
-# same logical value; taint state is deliberately untouched
-def _pull_host_value(t):
-    en = _HostPull.enabled
-    if en is None:
-        from .device import host_staging_enabled
-        en = host_staging_enabled()
-        if en:
-            import jax
-            try:
-                _HostPull.cpu = jax.devices("cpu")[0]
-            except RuntimeError:
-                en = False
-        _HostPull.enabled = en
-    v = t._val
-    if not en:
-        return v
-    import jax
-    if not isinstance(v, jax.core.Tracer):
-        sh = getattr(v, "sharding", None)
-        if (sh is not None and len(sh.device_set) == 1
-                and next(iter(sh.device_set)).platform != "cpu"):
-            v = jax.device_put(v, _HostPull.cpu)
-            t._val = v
-    return v
-
-
 class Tensor:
     # True on static-graph Variables: they are always written inside a traced
     # region before being read, so to_static discovery must NOT treat them as
@@ -140,8 +100,6 @@ class Tensor:
     def _value(self):
         if _TraceHooks.on_read is not None:
             _TraceHooks.on_read(self)
-        if _HostPull.enabled is not False:
-            return _pull_host_value(self)
         return self._val
 
     @_value.setter

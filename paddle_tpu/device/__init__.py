@@ -144,11 +144,7 @@ def set_allocator_strategy(strategy):
         raise ValueError(
             f"unknown allocator strategy {strategy!r}; "
             f"expected one of {sorted(mapping)}")
-    try:
-        initialized = bool(jax._src.xla_bridge._backends)
-    except AttributeError:  # private probe moved in a jax upgrade
-        initialized = True  # conservative: direct users to the env var
-    if initialized:
+    if jax._src.xla_bridge._backends:
         raise RuntimeError(
             "set_allocator_strategy must be called before the first device "
             "use (the XLA client allocator is fixed at backend init); set "

@@ -35,11 +35,7 @@ def _constrain(x, spec):
     if mesh is None or mesh.empty:
         return x
     def prim(v):
-        try:
-            return jax.lax.with_sharding_constraint(
-                v, NamedSharding(mesh, spec))
-        except Exception:
-            return v
+        return jax.lax.with_sharding_constraint(v, NamedSharding(mesh, spec))
     return apply(prim, x, name="sharding_constraint")
 
 
@@ -189,10 +185,7 @@ class _ParallelWrapper(Layer):
             return
         for p in self._layers.parameters():
             spec = getattr(p, "sharding_spec", None) or P()
-            try:
-                p._value = jax.device_put(p._val, NamedSharding(mesh, spec))
-            except Exception:
-                pass
+            p._value = jax.device_put(p._val, NamedSharding(mesh, spec))
 
     def forward(self, *args, **kwargs):
         return self._layers(*args, **kwargs)

@@ -88,24 +88,6 @@ def recompute(function, *args, **kwargs):
 
     n_args = len(tensor_args)
 
-    # Pallas placement hint, decided ONCE per recompute() call: inside the
-    # checkpoint trace every value is a tracer, so the flash-attention
-    # kernel's per-call placement inference cannot see where this region
-    # executes. Here we can: concrete (eager) inputs mean the region runs
-    # where they live — under host staging that is the CPU, where only the
-    # pallas interpreter works. The hint must be applied INSIDE pure(),
-    # because jax.checkpoint re-traces pure() at BACKWARD time (that is the
-    # whole point of remat) — a hint scoped around the forward apply() alone
-    # would have expired by then. Under the to_static compile pass the
-    # inputs are outer-jit tracers: no hint, Mosaic lowering for the
-    # accelerator holds.
-    from ...ops.pallas import flash_attention as _fa
-    _vals = [unwrap(t) for t in tensor_args]
-    _force = None
-    if _vals and not any(isinstance(v, jax.core.Tracer) for v in _vals):
-        if _fa._interpret(_vals[0]):
-            _force = True
-
     # traced-fn: checkpointed region body; write-seam: tracer rebind + restore
     def pure(*vals):
         saved = [(t, t._val) for t in closure_reads]
@@ -124,9 +106,6 @@ def recompute(function, *args, **kwargs):
                 prev_write(t, new_value)
 
         _TraceHooks.on_write = on_write
-        prev_force = _fa._FORCE_INTERPRET[0]
-        if _force is not None:
-            _fa._FORCE_INTERPRET[0] = _force
         # the body runs under no_grad yet the region IS differentiated (the
         # outer apply wraps the checkpoint in jax.vjp), so tell the fusion
         # policy this is fwd+bwd — grad-mode inspection alone would
@@ -150,7 +129,6 @@ def recompute(function, *args, **kwargs):
             # apply() both handle pytree outputs
             return jax.tree_util.tree_map(unwrap, out)
         finally:
-            _fa._FORCE_INTERPRET[0] = prev_force
             _autotune._FORCE_DIRECTION[0] = prev_dir
             _TraceHooks.on_write = prev_write
             for t, old in written.values():

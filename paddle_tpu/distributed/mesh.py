@@ -8,11 +8,55 @@ hybrid order ["data", "pipe", "sharding", "model"] (topology.py:36) plus
 """
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 import jax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 
 _STATE = {"mesh": None, "axis_degrees": None}
+
+# The mesh a compiled program's inputs are laid out on, visible while that
+# program is traced (to_static sets it): a tracer carries no placement, and
+# code that must map a kernel over the mesh by hand (Mosaic kernels cannot
+# be partitioned automatically) has nothing else to observe.
+_TRACE_MESH = [None]
+
+
+def mesh_of(values):
+    """The multi-device Mesh the given concrete arrays are laid out on, or
+    None when every one of them sits on a single device."""
+    for v in values:
+        sh = getattr(v, "sharding", None)
+        if sh is None or isinstance(v, jax.core.Tracer) \
+                or len(sh.device_set) == 1:
+            continue
+        if isinstance(sh, NamedSharding):
+            return sh.mesh
+        reg = _STATE["mesh"]
+        if reg is not None and set(reg.devices.flat) == set(sh.device_set):
+            return reg
+        raise ValueError(
+            f"array laid out over {len(sh.device_set)} devices by {sh!r}, "
+            f"which names no mesh and matches no mesh built by build_mesh()")
+    return None
+
+
+@contextlib.contextmanager
+def trace_mesh(mesh):
+    prev, _TRACE_MESH[0] = _TRACE_MESH[0], mesh
+    try:
+        yield
+    finally:
+        _TRACE_MESH[0] = prev
+
+
+def operand_mesh(value):
+    """The mesh `value`'s computation is spread over: its own layout when it
+    is concrete, the enclosing compiled program's when it is a tracer."""
+    if isinstance(value, jax.core.Tracer):
+        return _TRACE_MESH[0]
+    return mesh_of((value,))
 
 HYBRID_AXES = ("data", "pipe", "sharding", "sep", "model")
 
@@ -66,15 +110,7 @@ def axis_degree(axis):
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check_rep=True):
-    """Version-portable shard_map: top-level ``jax.shard_map`` when the
-    installed jax has it (replication checking spelled ``check_vma``),
-    ``jax.experimental.shard_map`` otherwise (spelled ``check_rep``). The
-    lane engines route through this so one jax pin change doesn't strand
-    every shard_map call site."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_rep)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_rep)
+    """The one shard_map call site of the lane engines (``jax.shard_map``
+    spells replication checking ``check_vma``)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)

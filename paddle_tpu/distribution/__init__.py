@@ -337,11 +337,7 @@ def sampling_id(x, min=0.0, max=1.0, seed=0, dtype="int64"):  # noqa: A002
     key_data = next_key_data()
 
     def prim(p, kd):
-        if hasattr(jax.random, "wrap_key_data"):
-            key = jax.random.wrap_key_data(kd)
-        else:  # derive a key from the data so repeated calls still vary
-            key = jax.random.PRNGKey(
-                jnp.asarray(kd).ravel()[0].astype(jnp.uint32))
+        key = jax.random.wrap_key_data(kd)
         logits = jnp.log(jnp.maximum(p, 1e-12))
         return jax.random.categorical(key, logits, axis=-1).astype(dtype)
 

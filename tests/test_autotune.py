@@ -4,7 +4,9 @@ Covers: search picks the measured winner and persists it; a warm cache
 (second tuner = second process) performs ZERO timed searches; corrupt/torn
 cache files are ignored and rebuilt; a kernel-source-hash bump invalidates
 stale entries; unsearchable placements (CPU/interpret — this suite) get the
-deterministic fallback without timing anything; FLAGS_fusion_policy
+deterministic fallback without timing anything; on a searchable placement a
+failing candidate is counted, an all-fail search and a failing fused
+candidate raise; FLAGS_fusion_policy
 auto/always/never routing and the profiler counter event.
 """
 import json
@@ -16,7 +18,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.framework.flags import set_flags
 from paddle_tpu.ops import autotune
-from paddle_tpu.ops.autotune import Autotuner
+from paddle_tpu.ops.autotune import AutotuneError, Autotuner
 
 
 class ScriptedMeasure:
@@ -128,12 +130,80 @@ class TestAutotuner:
         # nothing persisted: a later on-device run still gets to search
         assert list(tmp_path.glob("*.json")) == []
 
-    def test_all_candidates_failing_returns_fallback(self, tmp_path):
+    def test_all_candidates_failing_raises(self, tmp_path):
+        """On a searchable placement a search whose every candidate fails is
+        an error — never the fallback, and nothing is persisted."""
         def boom(fn, args):
             raise RuntimeError("does not fit")
         t = Autotuner(cache_dir=str(tmp_path), measure_fn=boom,
                       searchable=lambda: True)
-        assert _get(t, fallback="b") == "b"
+        with pytest.raises(AutotuneError, match="every candidate failed"):
+            _get(t, fallback="b")
+        assert autotune.counters()["candidate_failures"] == 3
+        assert "does not fit" in t.first_failure
+        assert list(tmp_path.glob("*.json")) == []
+
+    def test_failing_candidate_counted_and_loses(self, tmp_path):
+        def measure(fn, args):
+            if fn[1] == "a":
+                raise RuntimeError("VMEM exceeded")
+            return {"b": 2.0, "c": 1.0}[fn[1]]
+        t = Autotuner(cache_dir=str(tmp_path), measure_fn=measure,
+                      searchable=lambda: True)
+        assert _get(t) == "c"
+        c = autotune.counters()
+        assert c["candidate_failures"] == 1 and c["searches"] == 1
+        assert "'a'" in t.first_failure and "VMEM exceeded" in t.first_failure
+
+    def test_required_candidate_failure_raises(self, tmp_path):
+        def measure(fn, args):
+            if fn[1] == "a":
+                raise RuntimeError("mosaic refused")
+            return 1.0
+        t = Autotuner(cache_dir=str(tmp_path), measure_fn=measure,
+                      searchable=lambda: True)
+        with pytest.raises(AutotuneError, match="required candidate 'a'"):
+            t.get("testop", "sig1", candidates=("a", "b"), build=_build,
+                  make_args=lambda: (), fallback="b", version="v1",
+                  required=("a",))
+        assert list(tmp_path.glob("*.json")) == []
+
+    def test_search_inside_a_trace_times_real_execution(self, tmp_path):
+        """The block search is reached from inside the jitted fused probe:
+        its probe arguments and calls must be concrete there, or the timing
+        would be of staging a call."""
+        import jax
+        import jax.numpy as jnp
+        seen = []
+
+        def measure(fn, args):
+            out = jax.tree_util.tree_leaves(fn(*args))[0]
+            seen.append(isinstance(out, jax.core.Tracer))
+            return 1.0
+
+        t = Autotuner(cache_dir=str(tmp_path), measure_fn=measure,
+                      searchable=lambda: True)
+
+        def traced(x):
+            t.get("testop", "sig", candidates=("a",),
+                  build=lambda cand: jax.jit(lambda y: y * 2),
+                  make_args=lambda: [jnp.ones((4,)) + 1], fallback="a")
+            # a real kernel as the candidate: its body has primitives with
+            # no operands (program_id), which only a trace may see
+            import functools
+            from paddle_tpu.ops.pallas import flash_attention as fa
+            t.get("flash", "sig", candidates=((128, 128),),
+                  build=lambda cand: functools.partial(
+                      fa._flash_fwd_bh, causal=True, scale=1.0,
+                      block_q=cand[0], block_k=cand[1], interpret=True),
+                  make_args=lambda: fa._synth_bh([(1, 128, 64)] * 3,
+                                                 [jnp.float32] * 3),
+                  fallback=(128, 128))
+            return x + 1
+
+        jax.jit(traced)(1.0)
+        assert seen == [False, False]
+        assert autotune.counters()["candidate_failures"] == 0
 
     def test_tuple_values_roundtrip_through_disk(self, tmp_path):
         t = _tuner(tmp_path, {(512, 512): 2.0, (256, 512): 1.0})
@@ -228,6 +298,47 @@ class TestFusionPolicy:
             outs[pol] = np.asarray(fused_ffn(*self._ffn_args())._value)
         np.testing.assert_allclose(outs["always"], outs["never"],
                                    rtol=1e-5, atol=1e-5)
+
+    def test_failing_fused_candidate_raises_on_searchable_placement(
+            self, tmp_path):
+        """A fused path the compiler refuses must not become the answer
+        "unfused": on a searchable placement choose_fused raises."""
+        import jax.numpy as jnp
+
+        def fused(x):
+            raise RuntimeError("Mosaic: kernel refused")
+
+        def unfused(x):
+            return x * 2
+
+        old = autotune.set_tuner(Autotuner(
+            cache_dir=str(tmp_path), searchable=lambda: True,
+            measure_fn=lambda fn, args: (fn(*args), 1.0)[1]))
+        try:
+            with pytest.raises(AutotuneError, match="required candidate "
+                                                    "'fused'"):
+                autotune.choose_fused("toy_op", fused, unfused,
+                                      (jnp.ones((4, 4)),))
+            assert autotune.counters()["candidate_failures"] == 1
+            assert autotune.counters()["policy_unfused"] == 0
+        finally:
+            autotune.set_tuner(old)
+
+    def test_measured_slower_fused_may_still_lose(self, tmp_path):
+        import jax.numpy as jnp
+        times = iter([2.0, 1.0])   # fused, unfused
+        old = autotune.set_tuner(Autotuner(
+            cache_dir=str(tmp_path), searchable=lambda: True,
+            measure_fn=lambda fn, args: next(times)))
+        try:
+            _, choice = autotune.choose_fused(
+                "toy_op", lambda x: x + 1, lambda x: x + 1,
+                (jnp.ones((4, 4)),))
+            assert choice == "unfused"
+            (rec,) = autotune.get_tuner().last_times.values()
+            assert rec == {"'fused'": 2.0, "'unfused'": 1.0}
+        finally:
+            autotune.set_tuner(old)
 
     def test_invalid_policy_raises(self):
         set_flags({"FLAGS_fusion_policy": "sometimes"})

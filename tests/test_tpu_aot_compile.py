@@ -1,0 +1,181 @@
+"""The flash-attention kernels, compiled by the TPU's own compiler at the
+widths the models use — for a chip that is described, not attached.
+
+Interpret mode (every other flash test on CPU) cannot see what Mosaic
+refuses: a block that does not fit VMEM, a slice off the tiling, compiler
+params that no longer exist. These compiles can, and cost no chip time:
+each default block configuration and each autotune candidate of
+ops/pallas/flash_attention.py is lowered with interpret=False for one v5e
+chip and must contain the kernel (`tpu_custom_call`).
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process at a time may load libtpu, so nothing here may touch it at
+import or collection time, and the compiles run in the test's own process.
+"""
+import os
+
+import pytest
+
+SHAPES = [
+    pytest.param(32, 1024, 128, id="gpt1p3b-bh32-s1024-d128"),
+    pytest.param(64, 1024, 64, id="gpt-medium-bh64-s1024-d64"),
+    pytest.param(32, 2048, 128, id="bh32-s2048-d128"),
+    pytest.param(64, 2048, 64, id="bh64-s2048-d64"),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile_fwd(one_chip, bh, s, d, blocks):
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    x = _sds((bh, s, d), jnp.bfloat16, one_chip)
+    return fa._flash_fwd_bh.lower(
+        x, x, x, causal=True, scale=d ** -0.5, block_q=blocks[0],
+        block_k=blocks[1], interpret=False).compile()
+
+
+def _compile_bwd(one_chip, bh, s, d, blocks):
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    x = _sds((bh, s, d), jnp.bfloat16, one_chip)
+    lse = _sds((bh, s), jnp.float32, one_chip)
+    return fa._flash_bwd_bh.lower(
+        x, x, x, x, lse, x, causal=True, scale=d ** -0.5,
+        block_q_dkv=blocks[0], block_k_dkv=blocks[1], block_q_dq=blocks[2],
+        block_k_dq=blocks[3], interpret=False).compile()
+
+
+def _assert_kernel(compiled, n_kernels):
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= n_kernels, text[:2000]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 16 * 2 ** 30
+
+
+def test_compiler_params_carry_dimension_semantics():
+    """`pltpu.CompilerParams` really reaches the kernels: every grid axis is
+    marked parallel (the old class name was swallowed by an except and the
+    kernels lowered without it)."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    params = fa._tpu_params(False, 2)["compiler_params"]
+    assert isinstance(params, pltpu.CompilerParams)
+    assert tuple(params.dimension_semantics) == ("parallel", "parallel")
+    assert fa._tpu_params(True, 2) == {}
+
+
+@pytest.mark.parametrize("bh,s,d", SHAPES)
+def test_fwd_default_blocks_compile(one_chip, bh, s, d):
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    blocks = (fa._clamp(fa.DEFAULT_BLOCK_Q, s), fa._clamp(fa.DEFAULT_BLOCK_K, s))
+    _assert_kernel(_compile_fwd(one_chip, bh, s, d, blocks), 1)
+
+
+@pytest.mark.parametrize("bh,s,d", SHAPES)
+def test_bwd_default_blocks_compile(one_chip, bh, s, d):
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    blocks = fa._bwd_default_blocks(jnp.bfloat16)
+    _assert_kernel(_compile_bwd(one_chip, bh, s, d, blocks), 2)
+
+
+@pytest.mark.parametrize("bh,s,d", SHAPES)
+def test_every_fwd_candidate_compiles(one_chip, bh, s, d):
+    """A candidate Mosaic refuses belongs out of _FWD_CANDIDATES, not in a
+    run-time skip: the search on the chip must find every one runnable."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    for cand in fa._FWD_CANDIDATES:
+        blocks = (fa._clamp(cand[0], s), fa._clamp(cand[1], s))
+        _assert_kernel(_compile_fwd(one_chip, bh, s, d, blocks), 1)
+
+
+@pytest.mark.parametrize("bh,s,d", SHAPES)
+def test_every_bwd_candidate_compiles(one_chip, bh, s, d):
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    for cand in fa._BWD_CANDIDATES:
+        blocks = tuple(fa._clamp(b, s) for b in cand)
+        _assert_kernel(_compile_bwd(one_chip, bh, s, d, blocks), 2)
+
+
+def test_public_vjp_pair_compiles_at_gpt1p3b_widths(one_chip):
+    """What the train step holds: the custom_vjp pair behind
+    scaled_dot_product_attention, (b 2, s 1024, h 16, d 128) bf16 causal,
+    differentiated, with interpret resolved to False as on a TPU."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.attention import _flash_attention_diff
+
+    def loss(q, k, v):
+        out = _flash_attention_diff(q, k, v, True, 128 ** -0.5, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    x = _sds((2, 1024, 16, 128), jnp.bfloat16, one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    _assert_kernel(compiled, 3)
+
+
+def test_flash_under_a_dp2_mp2_mesh_compiles(topo):
+    """Mosaic kernels cannot be partitioned automatically: under a mesh
+    ops.attention maps them over it by hand (batch over 'data', heads over
+    'model'). The program for four described chips holds the kernels and
+    needs no collective for attention itself."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.distributed.mesh import trace_mesh
+    from paddle_tpu.ops import attention
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    shape = (4, 1024, 16, 128)
+
+    def prim(q, k, v):
+        # as on a TPU: Mosaic, not the interpreter this CPU process picks
+        with pytest.MonkeyPatch.context() as mp, trace_mesh(mesh):
+            mp.setattr(fa, "_interpret", lambda x=None: False)
+            return attention._flash_prim(q, True, 128 ** -0.5)(q, k, v)
+
+    def loss(q, k, v):
+        return jnp.sum(prim(q, k, v).astype(jnp.float32))
+
+    x = _sds(shape, jnp.bfloat16,
+             NamedSharding(mesh, P("data", None, "model", None)))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert " all-gather(" not in text and " all-reduce(" not in text

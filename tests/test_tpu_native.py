@@ -251,3 +251,101 @@ class TestFlashAttentionBackward:
                      .sum(-1)) + s_mat.max(-1)
         np.testing.assert_allclose(np.asarray(lse), ref, rtol=1e-5,
                                    atol=1e-5)
+
+
+class TestFlashAttentionUnderMesh:
+    """Mosaic kernels cannot be partitioned automatically, so under a mesh
+    scaled_dot_product_attention maps the flash kernel over it by hand
+    (ops/attention.py _flash_prim). Results and gradients must equal the
+    single-device ones — eagerly (mesh read off the operand) and inside a
+    to_static program (mesh read off the program's inputs)."""
+
+    def _qkv(self, sharding=None):
+        import jax
+        rng = np.random.RandomState(11)
+        vals = [jnp.asarray(rng.randn(4, 256, 4, 64).astype("float32")) * 0.3
+                for _ in range(3)]
+        if sharding is not None:
+            vals = [jax.device_put(v, sharding) for v in vals]
+        ts = [paddle.to_tensor(v) for v in vals]
+        for t in ts:
+            t.stop_gradient = False
+        return ts
+
+    def _run(self, q, k, v):
+        from paddle_tpu.ops.attention import scaled_dot_product_attention
+        out = scaled_dot_product_attention(q, k, v, is_causal=True,
+                                           use_pallas=True)
+        out.sum().backward()
+        return [np.asarray(t._val) for t in (out, q.grad, k.grad, v.grad)]
+
+    def _mesh_sharding(self):
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                    ("data", "model"))
+        return mesh, NamedSharding(mesh, P("data", None, "model", None))
+
+    def test_eager_sharded_operands_match_single_device(self):
+        ref = self._run(*self._qkv())
+        mesh, sharding = self._mesh_sharding()
+        q, k, v = self._qkv(sharding)
+        got = self._run(q, k, v)
+        assert len(q.grad._val.sharding.device_set) == 4
+        for a, b in zip(got, ref):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+    def test_to_static_program_over_a_mesh_maps_the_kernel(self):
+        from paddle_tpu.distributed.mesh import operand_mesh
+        from paddle_tpu.ops.attention import scaled_dot_product_attention
+        mesh, sharding = self._mesh_sharding()
+        seen = []
+
+        @paddle.jit.to_static
+        def fwd(q, k, v):
+            seen.append(operand_mesh(q._val))
+            return scaled_dot_product_attention(q, k, v, is_causal=True,
+                                                use_pallas=True)
+
+        ref = self._run(*self._qkv())[0]
+        with paddle.no_grad():
+            q, k, v = self._qkv(sharding)
+            outs = [fwd(q, k, v) for _ in range(3)]   # eager, compile, cached
+        assert seen[0] == mesh      # eager pass: read off the operand
+        assert seen[-1] == mesh     # compile trace: the program's inputs'
+        for o in outs:
+            np.testing.assert_allclose(np.asarray(o._val), ref, rtol=1e-5,
+                                       atol=1e-6)
+
+    def test_fusion_probe_times_the_mapped_kernel(self, tmp_path):
+        """Where the policy can search (a TPU), the fused probe it times
+        must be the mesh-mapped kernel too — the bare one cannot be
+        partitioned and would fail as a required candidate."""
+        from paddle_tpu.ops import attention, autotune
+        mesh, sharding = self._mesh_sharding()
+        q, k, v = (t._val for t in self._qkv(sharding))
+        old = autotune.set_tuner(autotune.Autotuner(
+            cache_dir=str(tmp_path), searchable=lambda: True, warmup=0,
+            reps=1))
+        try:
+            attention._flash_wins(q, k, v, True, 0.125)
+            (times,) = [t for key, t in
+                        autotune.get_tuner().last_times.items()
+                        if key.startswith("fusion.flash_attention|")]
+            assert set(times) == {"'fused'", "'unfused'"}
+            assert autotune.counters()["candidate_failures"] == 0
+        finally:
+            autotune.set_tuner(old)
+
+    def test_single_device_program_sees_no_mesh(self):
+        from paddle_tpu.distributed.mesh import operand_mesh, trace_mesh
+        import jax
+        q = self._qkv()[0]
+        assert operand_mesh(q._val) is None
+        seen = []
+        jax.jit(lambda x: seen.append(operand_mesh(x)) or x)(q._val)
+        assert seen == [None]
+        mesh, _ = self._mesh_sharding()
+        with trace_mesh(mesh):
+            jax.jit(lambda x: seen.append(operand_mesh(x)) or x + 1)(q._val)
+        assert seen[-1] == mesh
