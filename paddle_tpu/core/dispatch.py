@@ -10,9 +10,12 @@ tracers, so the whole tape lowers into one XLA computation.
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
+from ..profiler import metrics as _metrics
 from . import autograd
 from .autograd import GradNode
 from .tensor import Tensor
@@ -28,7 +31,16 @@ def _is_diff_value(v):
     return hasattr(v, "dtype") and jnp.issubdtype(v.dtype, jnp.inexact)
 
 
-_DEBUG = {"check_nan_inf": False, "record_ops": False}
+_DEBUG = {"check_nan_inf": False}
+
+# Every op the tape dispatches, eager or under a trace: `dispatch.ops_total`
+# in the metrics registry, which reads it when asked. A compiled step adds
+# none, so what a window adds is what ran outside its programs. A plain
+# integer: the add is all the hot path pays (threads may lose one).
+OPS_DISPATCHED = [0]
+_metrics.get_registry().register_counter_fn("dispatch.ops_total",
+                                            lambda: OPS_DISPATCHED[0])
+_NO_SCOPE = contextlib.nullcontext()
 
 # Static-graph builder (paddle_tpu/static/graph.py). When set, apply() records
 # ops into the current Program instead of executing (framework.py append_op
@@ -44,13 +56,10 @@ def get_static_builder():
     return _STATIC_BUILDER[0]
 
 
-def set_debug(check_nan_inf=None, record_ops=None):
+def set_debug(check_nan_inf):
     """Wire FLAGS_check_nan_inf (nan_inf_utils_detail.cc parity: scan outputs
-    after every op) and per-op RecordEvent spans (tracer.cc:150 parity)."""
-    if check_nan_inf is not None:
-        _DEBUG["check_nan_inf"] = bool(check_nan_inf)
-    if record_ops is not None:
-        _DEBUG["record_ops"] = bool(record_ops)
+    after every op)."""
+    _DEBUG["check_nan_inf"] = bool(check_nan_inf)
 
 
 def _check_finite(out, name):
@@ -77,10 +86,6 @@ def apply(prim, *args, name=None, **kwargs):
     """
     if _STATIC_BUILDER[0] is not None:
         return _STATIC_BUILDER[0].record(prim, args, kwargs, name)
-    if _DEBUG["record_ops"]:
-        from ..profiler import RecordEvent
-        with RecordEvent(name or getattr(prim, "__name__", "op")):
-            return _apply_impl(prim, args, kwargs, name)
     return _apply_impl(prim, args, kwargs, name)
 
 
@@ -118,6 +123,13 @@ def _amp_cast_prim(prim, target):
 
 
 def _apply_impl(prim, args, kwargs, name):
+    OPS_DISPATCHED[0] += 1
+    # the op's name on every instruction it stages (tracer.cc:150 RecordEvent
+    # parity, kept in the HLO): a device trace then says which Paddle op a
+    # fusion came from. Entered inside the differentiated function, so that
+    # the backward keeps it too: `jvp(linear)`, `transpose(jvp(linear))`; a
+    # scope round jax.vjp would name the forward only
+    scope = _NO_SCOPE if name is None else jax.named_scope(name)
     # AMP O1/O2: white-list ops compute in the low dtype, black-list ops are
     # promoted to f32 (softmax/norm/loss numerics) — consulted per-op at this
     # single dispatch seam, the tracer.cc AmpOperators analog
@@ -141,7 +153,8 @@ def _apply_impl(prim, args, kwargs, name):
                 diff_idx.append(i)
 
     if not diff_idx:
-        out = prim(*raw, **kwargs)
+        with scope:
+            out = prim(*raw, **kwargs)
         if _DEBUG["check_nan_inf"]:
             _check_finite(out, name or getattr(prim, "__name__", "op"))
         return _wrap_outputs(out, stop_gradient=True)
@@ -150,7 +163,8 @@ def _apply_impl(prim, args, kwargs, name):
         vals = list(raw)
         for i, dv in zip(diff_idx, diff_vals):
             vals[i] = dv
-        r = prim(*vals, **kwargs)
+        with scope:
+            r = prim(*vals, **kwargs)
         # normalize list->tuple so the vjp cotangent structure is always tuple
         return tuple(r) if isinstance(r, list) else r
 

@@ -1,8 +1,7 @@
 """ctypes bindings to the native runtime (csrc/ → libpaddle_tpu.so).
 
 The native layer provides the framework runtime the reference implements in
-C++ (SURVEY.md §2.1/§2.3): flags registry (platform/flags.cc), profiler
-RecordEvent + chrome trace (platform/profiler.h), stat monitor
+C++ (SURVEY.md §2.1/§2.3): flags registry (platform/flags.cc), stat monitor
 (platform/monitor.h), host arena allocator (memory/allocation/
 auto_growth_best_fit_allocator.cc), DataLoader queues/collate
 (fluid/reader.py native queues), and the ProgramDesc graph IR
@@ -35,7 +34,7 @@ class NativeUnavailable(RuntimeError):
 
 def _sources():
     return [os.path.join(_CSRC, f) for f in
-            ("common.h", "graph_ir.h", "flags.cc", "profiler.cc", "memory.cc",
+            ("common.h", "graph_ir.h", "flags.cc", "stats.cc", "memory.cc",
              "io.cc", "graph.cc", "executor.cc")]
 
 
@@ -86,15 +85,6 @@ def _declare(lib: ctypes.CDLL) -> None:
     sig("pt_flag_get", cp, [cp])
     sig("pt_flag_type", i32, [cp])
     sig("pt_flag_list", cp, [])
-    sig("pt_prof_enable", None, [])
-    sig("pt_prof_disable", None, [])
-    sig("pt_prof_enabled", i32, [])
-    sig("pt_prof_push", None, [cp])
-    sig("pt_prof_pop", None, [])
-    sig("pt_prof_instant", None, [cp])
-    sig("pt_prof_counter", None, [cp, f64])
-    sig("pt_prof_event_count", i64, [])
-    sig("pt_prof_dump_chrome", i64, [c.c_char_p, i64, i32])
     sig("pt_stat_add", None, [cp, i64])
     sig("pt_stat_get", i64, [cp])
     sig("pt_stat_list", cp, [])

@@ -34,9 +34,12 @@ import jax
 import jax.numpy as jnp
 
 from ..core import autograd
+from ..core import dispatch as _dispatch
 from ..core.autograd import GradNode
 from ..core.dtypes import is_inexact
 from ..core.tensor import Tensor, _TraceHooks
+from ..profiler import metrics as _metrics
+from ..profiler.compile_events import compile_span
 
 __all__ = ["to_static", "not_to_static", "TracedLayer", "InputSpec"]
 
@@ -154,10 +157,30 @@ class _DiscoveryCtx:
             self.captured.append(t)
 
 
+# The dispatch decisions, counted in the metrics registry where each is taken
+# (`to_static.<name>_total`); every `to_static.call` span carries the running
+# values, so a trace gives counts per step as differences between two spans.
+_RUNNING = ("launches", "undonated_launches", "grad_path_launches",
+            "diverted_calls")
+
+
+def _count(name, n=1):
+    _metrics.get_registry().inc_counter(f"to_static.{name}_total", n)
+
+
+def _running_counts():
+    reg = _metrics.get_registry()
+    counts = {name: int(reg.counter_value(f"to_static.{name}_total"))
+              for name in _RUNNING}
+    counts["dispatch_ops"] = _dispatch.OPS_DISPATCHED[0]
+    return counts
+
+
 class _Program:
     __slots__ = ("captured", "mutated", "ro", "jitted", "jitted_donate",
                  "out_tree", "n_outs", "stage", "internal_backward",
-                 "pure_fn", "scanned", "scanned_donate", "scanned_ready")
+                 "pure_fn", "scanned", "scanned_donate", "scanned_ready",
+                 "ran")
 
     def __init__(self):
         self.captured = []
@@ -178,6 +201,9 @@ class _Program:
         self.scanned = None
         self.scanned_donate = None
         self.scanned_ready = False
+        # which of "plain", "donating", "grad" have been launched: the first
+        # launch of each traces and compiles (to_static.compile)
+        self.ran = set()
 
 
 # Discovery/trace phases mutate global state (_TraceHooks, and shared model
@@ -278,6 +304,7 @@ class StaticFunction:
         # control flow survives XLA tracing. Falls back to `fn` untouched.
         from .ast_transform import apply_ast_transforms
         self._fn = apply_ast_transforms(fn)
+        self._name = getattr(fn, "__qualname__", type(fn).__name__)  # in spans
         self._input_spec = input_spec
         self._programs = {}
         self._enabled = True  # per-function; see also _default_enabled
@@ -589,6 +616,14 @@ class StaticFunction:
     def __call__(self, *args, **kwargs):
         if not (self._enabled and StaticFunction._default_enabled):
             return self._fn(*args, **kwargs)
+        # one span a call, from the signature to the returned outputs, with
+        # the running counters: on the fast path it and its one child,
+        # to_static.launch, are all the annotations a step opens
+        with jax.profiler.TraceAnnotation("to_static.call", fn=self._name,
+                                          **_running_counts()):
+            return self._call(args, kwargs)
+
+    def _call(self, args, kwargs):
         key = (_sig_of(args), _sig_of(kwargs), autograd.is_grad_enabled())
         prog = self._programs.get(key)
         if (prog is not None and prog.stage >= _discovery_passes()
@@ -598,6 +633,7 @@ class StaticFunction:
                     return self._run(prog, args, kwargs)
                 finally:
                     _exit_fast_path()
+            _count("diverted_calls")
         with _compile_guard():
             prog = self._programs.get(key)
             # ONE eager discovery call warms lazily-created state (optimizer
@@ -622,8 +658,14 @@ class StaticFunction:
         _TraceHooks.on_write = ctx.on_write
         _TraceHooks.on_create = ctx.on_create
         bwd_before = autograd.backward_run_counter[0]
+        ops_before = _dispatch.OPS_DISPATCHED[0]
         try:
-            out = self._fn(*args, **kwargs)
+            with jax.profiler.TraceAnnotation("to_static.discover",
+                                              fn=self._name) as span:
+                out = self._fn(*args, **kwargs)
+                ops = _dispatch.OPS_DISPATCHED[0] - ops_before
+                _count("discover_ops", ops)
+                span.set_metadata(ops=ops)
         finally:
             (_TraceHooks.on_read, _TraceHooks.on_write,
              _TraceHooks.on_create) = prev
@@ -731,10 +773,12 @@ class StaticFunction:
             for _ in range(5):
                 probe = {"reads": {}, "writes": {}, "promote": {}}
                 probe_fn = self._make_pure_fn(prog, args, kwargs, probe=probe)
-                jax.eval_shape(probe_fn,
-                               tuple(aval(t) for t in prog.mutated),
-                               tuple(aval(t) for t in prog.ro),
-                               tuple(aval(t) for t in arg_tensors))
+                with compile_span("to_static.probe", probe_fn.__name__,
+                                  fn=self._name):
+                    jax.eval_shape(probe_fn,
+                                   tuple(aval(t) for t in prog.mutated),
+                                   tuple(aval(t) for t in prog.ro),
+                                   tuple(aval(t) for t in arg_tensors))
                 if not (probe["reads"] or probe["writes"]
                         or probe["promote"]):
                     break
@@ -761,6 +805,21 @@ class StaticFunction:
         else:
             prog.jitted_donate = prog.jitted
 
+    def _launch(self, prog, which, launch, *operands):
+        """One launch of a program's executable `which` ("plain",
+        "donating", or "grad": jax.vjp of the plain one) under its span;
+        the first launch of each traces and compiles, and says so."""
+        _count("launches")
+        if which in prog.ran:
+            with jax.profiler.TraceAnnotation("to_static.launch"):
+                return launch(*operands)
+        with compile_span("to_static.compile", prog.pure_fn.__name__,
+                          fn=self._name, program=which):
+            with jax.profiler.TraceAnnotation("to_static.launch"):
+                out = launch(*operands)
+        prog.ran.add(which)
+        return out
+
     def _run(self, prog, args, kwargs):   # write-seam: compiled write-back of XLA-owned outputs clears taint
         arg_tensors = _flatten_tensors((args, kwargs), [])
         mut_vals = tuple(t._val for t in prog.mutated)
@@ -786,7 +845,10 @@ class StaticFunction:
             donate = not _donation_paused[0] and not any(
                 getattr(t, "_donate_unsafe", True) for t in prog.mutated)
             exec_fn = prog.jitted_donate if donate else prog.jitted
-            flat = exec_fn(mut_vals, ro_vals, arg_vals)
+            if not donate and prog.jitted_donate is not prog.jitted:
+                _count("undonated_launches")
+            flat = self._launch(prog, "donating" if donate else "plain",
+                                exec_fn, mut_vals, ro_vals, arg_vals)
             out_vals, new_state = flat[:n_outs], flat[n_outs:]
             for t, v in zip(prog.mutated, new_state):
                 t._val = v
@@ -836,7 +898,9 @@ class StaticFunction:
                                tuple(vals[n_mut:n_mut + n_ro]),
                                tuple(vals[n_mut + n_ro:]))
 
-        flat, vjp_fn = jax.vjp(closed, *[all_vals[i] for i in diff_idx])
+        _count("grad_path_launches")
+        flat, vjp_fn = self._launch(prog, "grad", jax.vjp, closed,
+                                    *[all_vals[i] for i in diff_idx])
         out_vals, new_state = flat[:n_outs], flat[n_outs:]
         for t, v in zip(prog.mutated, new_state):
             t._val = v

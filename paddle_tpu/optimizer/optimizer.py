@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from collections import defaultdict
 
+import jax
 import jax.numpy as jnp
 
 from ..core import autograd
@@ -146,6 +147,7 @@ class Optimizer:
         return out
 
     @autograd.no_grad()
+    @jax.named_scope("optimizer")   # clip, decay and every update, in the HLO
     def step(self):
         from ..core.selected_rows import SelectedRows
         pairs = self._collect_params_grads()

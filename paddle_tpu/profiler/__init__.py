@@ -4,8 +4,10 @@ python/paddle/utils/profiler, paddle.profiler v2 API).
 TPU-native: host spans recorded by a lightweight in-process recorder (chrome
 trace JSON export, ≈ profiler.proto timeline); device timeline comes from
 jax.profiler (XPlane/TensorBoard trace) — start_trace/stop_trace wrap it.
-RecordEvent also emits jax.profiler.TraceAnnotation so host spans align with
-device activity in the XPlane view.
+RecordEvent and the step-phase timer also emit jax.profiler.TraceAnnotation,
+always, so host spans sit beside device activity in whichever XPlane trace is
+being taken; the compiled step's own spans (`to_static.*`) live in
+jit/to_static.py and :mod:`paddle_tpu.profiler.compile_events`.
 
 Always-on metrics (queue depth, integrity cost, step-phase times) live in
 the companion registry (:mod:`paddle_tpu.profiler.metrics`): record_counter
@@ -102,47 +104,23 @@ class _HostEventRecorder:
 _recorder = _HostEventRecorder()
 
 
-# Native span recorder (csrc/profiler.cc) — the C++-side analog of the
-# reference's RecordEvent ring; spans recorded there too so native-runtime
-# internals (DataLoader workers, executors) share one timeline. Resolved
-# once in Profiler.start() (may compile csrc/ on first use); RecordEvent
-# only consults the cached value so the span hot path never blocks.
-_native_lib = None
-
-
-def _native():
-    return _native_lib
-
-
-def _resolve_native():
-    global _native_lib
-    if _native_lib is None:
-        from ..core import native
-        _native_lib = native.try_load()
-    return _native_lib
-
-
 class RecordEvent:
     """platform/profiler.h:216 RecordEvent parity (RAII span). Usable as a
-    context manager or decorator; nests into the jax XPlane via
-    TraceAnnotation."""
+    context manager or decorator. Every span is a jax TraceAnnotation too,
+    whether or not this module's recorder is on: a trace that
+    `jax.profiler.start_trace` (TensorBoard, benchmarks/run.py) takes holds
+    it on the host line, on the device operations' clock."""
 
     def __init__(self, name, event_type=None):
         self.name = name
         self.event_type = event_type  # chrome `cat`; filterable in summary()
         self._start = None
         self._jax_ann = None
-        self._native_pushed = False
 
     def begin(self):
         self._start = time.perf_counter_ns()
-        if _recorder.enabled:
-            self._jax_ann = jax.profiler.TraceAnnotation(self.name)
-            self._jax_ann.__enter__()
-            lib = _native()
-            if lib is not None:
-                lib.pt_prof_push(self.name.encode())
-                self._native_pushed = True
+        self._jax_ann = jax.profiler.TraceAnnotation(self.name)
+        self._jax_ann.__enter__()
 
     def end(self):
         if self._start is None:
@@ -150,17 +128,8 @@ class RecordEvent:
         dur_us = (time.perf_counter_ns() - self._start) / 1000.0
         _recorder.record(self.name, self._start / 1000.0, dur_us,
                          threading.get_ident(), self.event_type)
-        if self._jax_ann is not None:
-            self._jax_ann.__exit__(None, None, None)
-            self._jax_ann = None
-        if self._native_pushed:
-            # pop is honored even if profiling was disabled mid-span
-            # (csrc/profiler.cc records span-ends unconditionally) so B/E
-            # stay balanced in the chrome trace
-            self._native_pushed = False
-            lib = _native()
-            if lib is not None:
-                lib.pt_prof_pop()
+        self._jax_ann.__exit__(None, None, None)
+        self._jax_ann = None
         self._start = None
 
     def __enter__(self):
@@ -260,10 +229,6 @@ class Profiler:
         _recorder.enabled = True
         _recorder.clear()
         _metrics.get_registry().clear_samples()
-        lib = _resolve_native()  # may compile csrc/ once, before any spans
-        if lib is not None:
-            _drain_native(lib)  # discard stale events from prior sessions
-            lib.pt_prof_enable()
         if self._device_trace:
             import tempfile
             self._tmpdir = tempfile.mkdtemp(prefix="paddle_tpu_prof_")
@@ -278,9 +243,6 @@ class Profiler:
 
     def stop(self):
         _recorder.enabled = False
-        lib = _native()
-        if lib is not None:
-            lib.pt_prof_disable()
         if self._tmpdir is not None:
             try:
                 jax.profiler.stop_trace()
@@ -328,19 +290,6 @@ class Profiler:
         return self._tmpdir
 
 
-def _drain_native(lib):
-    """Dump-and-clear the native per-thread buffers; returns the native
-    chrome-trace events (possibly empty)."""
-    import ctypes
-    n = lib.pt_prof_dump_chrome(None, 0, 0)
-    buf = ctypes.create_string_buffer(int(n))
-    lib.pt_prof_dump_chrome(buf, n, 1)
-    try:
-        return json.loads(buf.value.decode())["traceEvents"]
-    except Exception:
-        return []
-
-
 def record_counter(name, value, ts_us=None):
     """Record a counter sample. ALWAYS lands in the metrics registry
     (:mod:`paddle_tpu.profiler.metrics` — production gauges must not vanish
@@ -386,10 +335,6 @@ def export_chrome_tracing(path, dir_name=None):
     if d:
         os.makedirs(d, exist_ok=True)
     trace = _recorder.chrome_trace()
-    lib = _native()
-    if lib is not None:
-        # merge native-runtime spans (csrc recorder) into the same timeline
-        trace["traceEvents"].extend(_drain_native(lib))
     trace.update(_trace_metadata())
     with open(path, "w") as f:
         json.dump(trace, f)

@@ -40,6 +40,8 @@ import os
 import threading
 import time
 
+import jax
+
 __all__ = [
     "MetricsRegistry", "MetricsExporter", "get_registry", "get_exporter",
     "reset_registry", "DEFAULT_BUCKETS_MS",
@@ -156,6 +158,7 @@ class MetricsRegistry:
         self._counters = {}      # guarded-by: _lock ((name, labels_key) -> float)
         self._gauges = {}        # guarded-by: _lock ((name, labels_key) -> float)
         self._gauge_fns = {}     # guarded-by: _lock (name -> callable() -> number)
+        self._counter_fns = {}   # guarded-by: _lock (name -> callable() -> number)
         self._histograms = {}    # guarded-by: _lock (name -> _Histogram)
         self._label_sets = {}    # guarded-by: _lock (name -> set of labels_key)
         self._dropped_label_sets = 0  # guarded-by: _lock
@@ -194,6 +197,17 @@ class MetricsRegistry:
         """Pull-style gauge: `fn()` is evaluated at snapshot/export time."""
         with self._lock:
             self._gauge_fns[name] = fn
+
+    def register_counter_fn(self, name, fn):
+        """Pull-style counter: `fn()` is a monotonic total that its owner
+        keeps as a plain integer, so a path paid on every eager op
+        (`dispatch.ops_total`) costs an add and no lock; read at snapshot
+        time and by :meth:`counter_value`. Everything rarer uses
+        :meth:`inc_counter`. The owner registers once, at import, and keeps
+        the number, so :meth:`reset` neither zeroes nor drops it (a gauge
+        fn's owner registers again when it is rebuilt)."""
+        with self._lock:
+            self._counter_fns[name] = fn
 
     def observe(self, name, value, buckets=None, exemplar=None):
         with self._lock:
@@ -240,7 +254,10 @@ class MetricsRegistry:
 
     def counter_value(self, name, labels=None):
         with self._lock:
-            return self._counters.get((name, _labels_key(labels)), 0.0)
+            fn = None if labels else self._counter_fns.get(name)
+            if fn is None:
+                return self._counters.get((name, _labels_key(labels)), 0.0)
+        return float(fn())
 
     def gauge_value(self, name, labels=None):
         with self._lock:
@@ -272,7 +289,10 @@ class MetricsRegistry:
             hists = {name: h.summary()
                      for name, h in self._histograms.items()}
             fns = dict(self._gauge_fns)
+            counter_fns = dict(self._counter_fns)
             dropped = self._dropped_label_sets
+        for name, fn in counter_fns.items():
+            counters[name] = float(fn())
         for name, fn in fns.items():
             try:
                 gauges[name] = float(fn())
@@ -431,7 +451,8 @@ class MetricsExporter:
     def export_once(self):
         """One snapshot → both files, atomically. Raises OSError on write
         failure (maybe_export swallows and counts it)."""
-        with self._export_lock:
+        with jax.profiler.TraceAnnotation("metrics.export"), \
+                self._export_lock:
             snap = self._registry.snapshot()
             snap["ts"] = time.time()
             snap["rank"] = self._rank_no()

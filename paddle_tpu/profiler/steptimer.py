@@ -30,7 +30,10 @@ instrumentation overhead <1% (self-measured in ``overhead_ms`` and asserted
 in tests/test_observability.py, same contract as ``integrity.check_ms``).
 
 Everything lands in the always-on metrics registry
-(``steptimer.<phase>_ms`` histograms) and — while the profiler is tracing —
+(``steptimer.<phase>_ms`` histograms); as a ``jax.profiler.TraceAnnotation``
+of the phase's name (the step as a ``StepTraceAnnotation``), so any XPlane
+trace holds the phases on the host line beside ``to_static.*`` and on the
+device operations' clock; and — while this package's profiler is tracing —
 as chrome spans with ``cat="step_phase"`` so ``tools/trace_merge.py`` can
 name the slowest rank per phase. See docs/observability.md.
 """
@@ -40,6 +43,8 @@ import collections
 import threading
 import time
 from contextlib import contextmanager
+
+import jax
 
 from . import metrics as _metrics
 
@@ -119,12 +124,17 @@ class StepTimer:
             stack = tls.stack = []
         frame = [name, 0.0, 0.0]  # [name, start, child wall time]
         stack.append(frame)
+        # the same span on the jax profiler's host line, on the device
+        # operations' clock, whoever started the trace
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
         frame[1] = self._clock()
         self._overhead_s += frame[1] - t_in
         try:
             yield
         finally:
             t1 = self._clock()
+            ann.__exit__(None, None, None)
             dur = t1 - frame[1]
             stack.pop()
             self_s = max(0.0, dur - frame[2])
@@ -168,12 +178,16 @@ class StepTimer:
                      and self._step_count % self.sync_interval == 0)
         step = self._tls.step = {"phase_s": {}, "n": n, "sync": sync_this,
                                  "device_wait_s": 0.0, "t0": 0.0}
+        ann = jax.profiler.StepTraceAnnotation("step",
+                                               step_num=self._step_count)
+        ann.__enter__()
         step["t0"] = self._clock()
         self._overhead_s += step["t0"] - t_in
         try:
             yield self
         finally:
             t1 = self._clock()
+            ann.__exit__(None, None, None)
             self._tls.step = None
             wall = t1 - step["t0"]
             rec = {"n": n, "wall_s": wall, "phase_s": step["phase_s"],
@@ -208,7 +222,6 @@ class StepTimer:
             return value
         t0 = self._clock()
         try:
-            import jax
             jax.block_until_ready(
                 value._val if hasattr(value, "_val") else value)
         except Exception:

@@ -41,21 +41,7 @@ class TestFlags:
         assert out["FLAGS_check_nan_inf"] is False
 
 
-class TestProfiler:
-    def test_span_roundtrip(self, lib):
-        lib.pt_prof_enable()
-        lib.pt_prof_push(b"op/matmul")
-        lib.pt_prof_pop()
-        lib.pt_prof_counter(b"mem", 123.0)
-        lib.pt_prof_disable()
-        n = lib.pt_prof_dump_chrome(None, 0, 0)
-        buf = ctypes.create_string_buffer(n)
-        lib.pt_prof_dump_chrome(buf, n, 1)
-        trace = json.loads(buf.value.decode())
-        names = [e.get("name") for e in trace["traceEvents"]]
-        assert "op/matmul" in names
-        assert "mem" in names
-
+class TestStats:
     def test_stats(self, lib):
         lib.pt_stat_add(b"STAT_test", 5)
         lib.pt_stat_add(b"STAT_test", 7)

@@ -41,6 +41,7 @@ SUBSYSTEMS = [
     "ckpt",          # zero-stall checkpointing (resilience/snapshot.py)
     "compiled_step", # whole-step compilation (jit/compiled_step.py)
     "decode",        # continuous-batching decode (serving/decode/)
+    "dispatch",      # the op dispatch seam (core/dispatch.py)
     "disagg",        # disaggregated prefill/decode (serving/disagg.py)
     "fusion_policy", # measured fusion decisions
     "integrity",     # SDC defense (checksum consensus, replay)
@@ -56,6 +57,7 @@ SUBSYSTEMS = [
     "steptime",      # per-rank step-time health beacons
     "steptimer",     # phase attribution (docs/observability.md)
     "straggler",     # straggler-quarantine ratios
+    "to_static",     # compiled-step launches and compiles (jit/to_static.py)
     "trace",         # request tracer health (profiler/tracing.py)
 ]
 
@@ -79,13 +81,14 @@ GRANDFATHERED = [
 # (name, value) pairs instead and is handled separately; ``_record`` is
 # autotune's local wrapper around record_counter.
 NAME_CALLS = {"record_counter", "record_sample", "_record",
-              "inc_counter", "set_gauge", "observe", "register_gauge_fn"}
+              "inc_counter", "set_gauge", "observe", "register_gauge_fn",
+              "register_counter_fn"}
 PAIRS_CALLS = {"observe_many"}
 # Of those, the registry methods are only linted when the receiver is
 # recognizably the metrics registry (get_registry(), self._registry, ...):
 # ``observe`` is far too common a method name to lint unconditionally.
 REGISTRY_ONLY = {"inc_counter", "set_gauge", "observe", "register_gauge_fn",
-                 "observe_many"}
+                 "register_counter_fn", "observe_many"}
 
 
 def _analysis():
