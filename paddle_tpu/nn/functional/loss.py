@@ -6,6 +6,8 @@ class weights, reductions.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -31,31 +33,63 @@ def _reduce(v, reduction):
     return v
 
 
+def _nll_of_softmax_fwd(logits, labels, axis):
+    acc = jnp.promote_types(logits.dtype, jnp.float32)
+    x = logits.astype(acc)
+    top = jnp.max(x, axis=axis, keepdims=True)
+    lse = jnp.log(jnp.sum(jnp.exp(x - top), axis=axis, keepdims=True)) + top
+    picked = jnp.take_along_axis(logits, jnp.expand_dims(labels, axis), axis=axis)
+    return jnp.squeeze(lse - picked.astype(acc), axis), (logits, labels, lse)
+
+
+def _nll_of_softmax_bwd(axis, residuals, g):
+    logits, labels, lse = residuals
+    classes = jax.lax.broadcasted_iota(jnp.int32, logits.shape, axis)
+    onehot = classes == jnp.expand_dims(labels, axis)
+    softmax = jnp.exp(logits.astype(lse.dtype) - lse)
+    grad = (softmax - onehot.astype(lse.dtype)) * jnp.expand_dims(g, axis)
+    return grad.astype(logits.dtype), None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _nll_of_softmax(logits, labels, axis):
+    """Per row, `logsumexp(logits) - logits[label]` over `axis` (not
+    negative; `labels` int32, in range, without that axis), accumulated in
+    float32 whatever the logits' dtype. Its own vjp so that nothing of the
+    logits' shape lives between forward and backward but the logits as given:
+    the upcast stays inside the two row reductions, the residuals are the
+    logits and one log-sum-exp per row, and the backward makes
+    `softmax - onehot` in the logits' dtype in one expression, which XLA fuses
+    into whatever reads it."""
+    return _nll_of_softmax_fwd(logits, labels, axis)[0]
+
+
+_nll_of_softmax.defvjp(_nll_of_softmax_fwd, _nll_of_softmax_bwd)
+
+
 def cross_entropy(input, label, weight=None, ignore_index=-100,  # noqa: A002
                   reduction="mean", soft_label=False, axis=-1,
                   use_softmax=True, name=None):
-    wv = unwrap(weight) if weight is not None else None
-
     def prim(logits, lab, *maybe_w):
         w = maybe_w[0] if maybe_w else None
+        if soft_label:
+            logp = (jax.nn.log_softmax(logits, axis=axis) if use_softmax
+                    else jnp.log(jnp.maximum(logits, 1e-30)))
+            return _reduce(-jnp.sum(lab * logp, axis=axis), reduction)
+        li = lab.astype(jnp.int32)
+        if li.ndim == logits.ndim:
+            li = jnp.squeeze(li, axis)
+        valid = li != ignore_index
+        li = jnp.maximum(li, 0)
         if use_softmax:
-            logp = jax.nn.log_softmax(logits, axis=axis)
+            per = _nll_of_softmax(logits, li, axis % logits.ndim)
         else:
             logp = jnp.log(jnp.maximum(logits, 1e-30))
-        if soft_label:
-            per = -jnp.sum(lab * logp, axis=axis)
-            if reduction == "mean":
-                return jnp.mean(per)
-            return _reduce(per, reduction)
-        li = lab.astype(jnp.int32)
-        li_exp = jnp.expand_dims(li, axis) if li.ndim == logp.ndim - 1 else li
-        picked = jnp.take_along_axis(logp, jnp.maximum(li_exp, 0), axis=axis)
-        per = -jnp.squeeze(picked, axis)
-        valid = (jnp.squeeze(li_exp, axis) != ignore_index)
+            per = -jnp.squeeze(jnp.take_along_axis(
+                logp, jnp.expand_dims(li, axis), axis=axis), axis)
         per = jnp.where(valid, per, 0.0)
         if w is not None:
-            wsel = jnp.take(w, jnp.maximum(jnp.squeeze(li_exp, axis), 0), axis=0)
-            wsel = jnp.where(valid, wsel, 0.0)
+            wsel = jnp.where(valid, jnp.take(w, li, axis=0), 0.0)
             per = per * wsel
             if reduction == "mean":
                 return jnp.sum(per) / jnp.maximum(jnp.sum(wsel), 1e-12)

@@ -112,8 +112,9 @@ class BertForSequenceClassification(nn.Layer):
         _, pooled = self.bert(input_ids, token_type_ids, attention_mask)
         logits = self.classifier(self.dropout(pooled))
         if labels is not None:
-            # f32 softmax-CE regardless of compute dtype: bf16 loss values
-            # quantize in ~0.004 steps, too coarse for loss-curve evidence,
-            # and the f32 logit upcast fuses into the softmax under XLA
-            return F.cross_entropy(logits.astype("float32"), labels)
+            # no upcast here: F.cross_entropy accumulates in float32 and
+            # returns a float32 loss whatever the logits' dtype (a bf16 loss
+            # quantizes in ~0.004 steps, too coarse for loss-curve evidence),
+            # and keeps no float32 copy of the logits for its backward
+            return F.cross_entropy(logits, labels)
         return logits

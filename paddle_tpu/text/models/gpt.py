@@ -286,10 +286,8 @@ class GPTForCausalLM(nn.Layer):
         h = self.gpt(input_ids)
         logits = F.linear(h, self.gpt.wte.weight.t())
         if labels is not None:
-            # f32 softmax-CE (standard TPU practice; see bert.py note)
-            loss = F.cross_entropy(
-                M.reshape(logits, [-1, self.config.vocab_size])
-                .astype("float32"),
+            # the logits as F.linear made them (see bert.py note)
+            return F.cross_entropy(
+                M.reshape(logits, [-1, self.config.vocab_size]),
                 M.reshape(labels, [-1]))
-            return loss
         return logits
