@@ -1,14 +1,24 @@
 """fleet.utils (fleet/utils/recompute.py:182 parity).
 
-TPU-native: recompute = jax.checkpoint (rematerialization) applied to the
-layer function — XLA re-executes the forward inside backward, trading FLOPs
-for HBM exactly like the reference's PyLayer-based rerun, with RNG handled by
-functional keys (no state juggling needed).
+TPU-native: recompute = rematerialization of the layer function — the
+forward keeps the region's inputs only and the backward re-executes it,
+trading FLOPs for HBM exactly like the reference's PyLayer-based rerun. The
+state the region reads without differentiating it (the RNG key, running
+statistics, a frozen parameter) enters as inputs too, so the backward's rerun
+sees the forward's values: the same dropout mask, whatever drew random
+numbers in between (preserve_rng_state parity). Written as a
+`jax.custom_vjp` whose backward differentiates the region again behind an
+optimization barrier (what `jax.checkpoint` does to keep XLA from merging the
+two forwards) rather than as `jax.checkpoint` itself, which puts
+`checkpoint/` in front of every instruction's `op_name`: the device trace
+then attributes a rematerialised block to the ops inside it, forward,
+recomputation and backward alike (docs/observability.md).
 
 Closure parameters (layer weights referenced inside `function`) are
 discovered with an abstract trace (jax.eval_shape + read hooks — no FLOPs)
 and passed to the checkpointed region as explicit differentiable inputs, so
-their gradients flow exactly as in the plain forward.
+their gradients flow exactly as in the plain forward; the same trace finds
+the state it only reads.
 """
 from __future__ import annotations
 
@@ -47,7 +57,10 @@ def recompute(function, *args, **kwargs):
         return rebuilt
 
     # -- discovery: which closure tensors does `function` read? -------------
-    closure_reads = []
+    # closure_reads are differentiated; state_reads (the generator's key,
+    # buffers, frozen parameters) only ride along, so that the backward's
+    # rerun reads what the forward read
+    closure_reads, state_reads = [], []
 
     def on_read(t):
         if id(t) in seen or t._trace_transparent:
@@ -55,6 +68,8 @@ def recompute(function, *args, **kwargs):
         seen.add(id(t))
         if not t.stop_gradient and jnp.issubdtype(t._val.dtype, jnp.inexact):
             closure_reads.append(t)
+        elif isinstance(t._val, jax.Array):
+            state_reads.append(t)
 
     # abstract-trace writes (RNG splits, BN stats) must not leak tracers
     # into real state: snapshot old values and restore after discovery
@@ -68,7 +83,8 @@ def recompute(function, *args, **kwargs):
     prev = (_TraceHooks.on_read, _TraceHooks.on_write, _TraceHooks.on_create)
     _TraceHooks.on_read = on_read
     _TraceHooks.on_write = on_write
-    _TraceHooks.on_create = None
+    # a tensor the body makes is no state of the closure
+    _TraceHooks.on_create = lambda t: seen.add(id(t))
     from ...ops import autotune as _autotune_disc
     _prev_dir = _autotune_disc._FORCE_DIRECTION[0]
     _autotune_disc._FORCE_DIRECTION[0] = "fwd_bwd"
@@ -87,10 +103,11 @@ def recompute(function, *args, **kwargs):
             t._val = old
 
     n_args = len(tensor_args)
+    bound = closure_reads + state_reads
 
     # traced-fn: checkpointed region body; write-seam: tracer rebind + restore
     def pure(*vals):
-        saved = [(t, t._val) for t in closure_reads]
+        saved = [(t, t._val) for t in bound]
         # writes during the traced run (BN running stats, RNG keys) would
         # store tracers into real state — snapshot and restore them, same as
         # the discovery pass. State updates inside a recompute block are
@@ -114,7 +131,7 @@ def recompute(function, *args, **kwargs):
         prev_dir = _autotune._FORCE_DIRECTION[0]
         _autotune._FORCE_DIRECTION[0] = "fwd_bwd"
         try:
-            for t, v in zip(closure_reads, vals[n_args:]):
+            for t, v in zip(bound, vals[n_args:]):
                 t._val = v
             # no_grad: inner per-op GradNodes are useless here (the outer
             # apply() differentiates the whole checkpointed region), and an
@@ -136,5 +153,28 @@ def recompute(function, *args, **kwargs):
             for t, v in saved:
                 t._val = v
 
-    ckpt = jax.checkpoint(pure)
-    return apply(ckpt, *tensor_args, *closure_reads, name="recompute")
+    return apply(_rematerialised(pure, n_args + len(closure_reads)),
+                 *tensor_args, *bound)
+
+
+def _rematerialised(pure, n_diff):
+    """`pure` with a backward that runs it again: nothing but its inputs
+    crosses from the forward pass to the backward pass. The first `n_diff`
+    inputs are differentiated; the rest are the state the region reads."""
+    @jax.custom_vjp
+    def region(*vals):
+        return pure(*vals)
+
+    def fwd(*vals):
+        return pure(*vals), vals
+
+    def bwd(vals, g):
+        # the barrier ties the second forward to the cotangent's arrival, so
+        # that XLA cannot serve it from the first one's intermediates
+        vals, g = jax.lax.optimization_barrier((vals, g))
+        state = vals[n_diff:]
+        grads = jax.vjp(lambda *diff: pure(*diff, *state), *vals[:n_diff])[1](g)
+        return (*grads, *(None for _ in state))
+
+    region.defvjp(fwd, bwd)
+    return region

@@ -10,14 +10,20 @@ the dispatch/combine einsums into all-to-alls over ICI.
 """
 from __future__ import annotations
 
+import functools
+import weakref
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import nn
 from ..core.dispatch import apply
+from ..core.tensor import Tensor
 from ..distributed.utils import combine_tokens, dispatch_tokens
+from ..profiler import metrics as _metrics
 
-__all__ = ["MoELayer"]
+__all__ = ["MoELayer", "DroplessMoELayer"]
 
 
 class MoELayer(nn.Layer):
@@ -143,3 +149,248 @@ class MoELayer(nn.Layer):
 
         return apply(prim, buf, self.w1, self.b1, self.w2, self.b2,
                      name="moe_experts")
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer
+
+def _route_plan(scores, bias, *, top_k, lookup, n_held, tm, rows):
+    """Which experts every token picks and where each picked (token, expert)
+    pair lands in a buffer of `rows` rows laid out for the grouped products:
+    the pairs of one held expert are contiguous, in token order, and start at
+    a multiple of `tm` (an expert nobody picked still owns one tile). All
+    integers; nothing here is differentiated.
+
+    Returns idx (N, k) the experts picked; pair_row (N, k) the buffer row of
+    each pair, `rows` where its expert is not held here; row_pair (rows,)
+    the pair a buffer row holds and row_valid whether it holds one;
+    tile_group (rows / tm,) the held expert of each tile; num_tiles the
+    tiles in use; counts (n_held,) the rows of each held expert."""
+    n = scores.shape[0]
+    pairs = n * top_k
+    _, idx = jax.lax.top_k(scores + bias.astype(scores.dtype), top_k)
+    local = jnp.asarray(lookup)[idx].reshape(-1)      # held expert's slot, or -1
+    held = local >= 0
+    key = jnp.where(held, local, n_held)
+    counts = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=key.dtype),
+                     axis=0, dtype=jnp.int32)
+    order = jnp.argsort(key, stable=True)             # sorted position -> pair
+    rank = jnp.argsort(order)                         # pair -> sorted position
+    tiles = jnp.maximum((counts + tm - 1) // tm, 1)
+    tile_end = jnp.cumsum(tiles)
+    row_start = (tile_end - tiles) * tm
+    sorted_start = jnp.cumsum(counts) - counts
+    slot = jnp.maximum(local, 0)
+    pair_row = jnp.where(held, row_start[slot] + rank - sorted_start[slot], rows)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(rows // tm, dtype=jnp.int32),
+                         side="right"), n_held - 1).astype(jnp.int32)
+    row_group = jnp.repeat(tile_group, tm, total_repeat_length=rows)
+    at = jnp.arange(rows, dtype=jnp.int32) - row_start[row_group]
+    row_valid = at < counts[row_group]
+    row_pair = jnp.where(
+        row_valid, order[jnp.clip(sorted_start[row_group] + at, 0, pairs - 1)], 0)
+    return (idx.astype(jnp.int32), pair_row.reshape(n, top_k).astype(jnp.int32),
+            row_pair.astype(jnp.int32), row_valid, tile_group,
+            tile_end[-1].astype(jnp.int32), counts)
+
+
+@jax.custom_vjp
+def _gather_rows(x, row_token, row_valid, pair_row):
+    """The buffer's rows from the tokens: row r holds x[row_token[r]], zero
+    where it holds no pair. Its transpose adds each token's rows back, and
+    is written as a gather over `pair_row` (a token's pairs and their rows)
+    because a scatter-add of thousands of rows runs one row at a time."""
+    return jnp.where(row_valid[:, None], x[row_token], 0)
+
+
+def _gather_rows_fwd(x, row_token, row_valid, pair_row):
+    return _gather_rows(x, row_token, row_valid, pair_row), pair_row
+
+
+def _gather_rows_bwd(pair_row, g):
+    back = jnp.take(g, pair_row, axis=0, mode="fill", fill_value=0)  # (N, k, H)
+    return (jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None, None)
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(y, w, pair_row, row_pair, row_valid):
+    """out[t] = sum_j w[t, j] * y[pair_row[t, j]], a pair whose expert is
+    not held adding nothing: float32 sum, y's dtype out. The transpose is a
+    gather too: row r gets w * the cotangent of the one token it serves."""
+    picked = jnp.take(y, pair_row, axis=0, mode="fill", fill_value=0)
+    return jnp.sum(picked.astype(jnp.float32) * w[..., None], axis=1).astype(y.dtype)
+
+
+def _combine_rows_fwd(y, w, pair_row, row_pair, row_valid):
+    return _combine_rows(y, w, pair_row, row_pair, row_valid), (
+        y, w, pair_row, row_pair, row_valid)
+
+
+def _combine_rows_bwd(res, g):
+    y, w, pair_row, row_pair, row_valid = res
+    k = w.shape[1]
+    row_w = w.reshape(-1)[row_pair]
+    dy = jnp.where(row_valid[:, None],
+                   g[row_pair // k].astype(jnp.float32) * row_w[:, None], 0)
+    picked = jnp.take(y, pair_row, axis=0, mode="fill", fill_value=0)
+    dw = jnp.sum(picked.astype(jnp.float32) * g[:, None, :].astype(jnp.float32),
+                 axis=-1)
+    return dy.astype(y.dtype), dw.astype(w.dtype), None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+# every live dropless layer, for the registry's pull-style readings below
+_LAYERS = weakref.WeakSet()
+
+
+def _layer_totals(name):
+    return [float(getattr(layer, name)._val) for layer in _LAYERS]
+
+
+# Routing counters kept on the device and fetched only when the registry is
+# asked (a snapshot, an export): a step pays three scalar adds and no host
+# round trip. Totals over every live layer since it was built.
+_metrics.get_registry().register_counter_fn(
+    "moe.rows_here_total", lambda: sum(_layer_totals("rows_total")))
+_metrics.get_registry().register_counter_fn(
+    "moe.layer_calls_total", lambda: sum(_layer_totals("calls_total")))
+_metrics.get_registry().register_gauge_fn("moe.live_layers_count", lambda: len(_LAYERS))
+_metrics.get_registry().register_gauge_fn(
+    "moe.load_max_over_mean_ratio", lambda: max(
+        (i / c for i, c in zip(_layer_totals("imbalance_total"),
+                               _layer_totals("calls_total")) if c), default=0.0))
+
+
+class DroplessMoELayer(nn.Layer):
+    """Sigmoid-routed, dropless expert layer that computes the part of the
+    result its own experts give (DeepSeek-V3-style routing, as the LFM2
+    mixture models use it).
+
+    Every token is scored over all `num_experts` (the published count):
+    s = sigmoid(x W_g) in float32. The `top_k` experts are the largest of
+    s + expert_bias (a parameter that takes no gradient; a balancing rule
+    outside this layer may move it); their weights are the un-biased scores
+    normalised over the k picked, times `routed_scaling_factor`. The layer
+    holds `held_experts` (ids among the published ones; all by default) as
+    stacked SwiGLU weights w1, w3 (held, d_model, d_hidden) and w2 (held,
+    d_hidden, d_model), and returns sum over the picked experts *held here*
+    of w_e * w2_e(silu(w1_e x) * w3_e x); the normalisation still runs over
+    all k. With every expert held that is the whole layer; with a share,
+    the shares' results add up to it (tests/test_dropless_moe.py), which is
+    what an expert-parallel rank computes before the exchange. There is no
+    capacity and no dropped pair: the (token, expert) pairs are sorted by
+    expert into a buffer sized for the worst routing (every pair held here),
+    each held expert's rows tile-aligned, and one grouped product per
+    projection (ops/pallas/grouped_matmul.py) runs over the tiles in use,
+    so the work follows the rows routed here. The gathers into and out of
+    the buffer are XLA's and cost the buffer's size.
+
+    Scopes: `moe_route` (scores, top-k, the plan, the gather), `moe_experts`
+    (the grouped products), `swiglu`, `moe_combine`. Counters, on the device,
+    moved by `record_load` with what `forward` returns beside the result
+    (`rows_total`, `calls_total`, `imbalance_total`; the registry's
+    `moe.rows_here_total`, `moe.layer_calls_total`, `moe.load_max_over_mean_ratio`).
+    """
+
+    def __init__(self, d_model, d_hidden, num_experts, top_k,
+                 held_experts=None, routed_scaling_factor=1.0,
+                 weight_attr=None):
+        super().__init__()
+        from ..ops.pallas.grouped_matmul import ROW_TILE
+        held = list(range(num_experts)) if held_experts is None \
+            else [int(e) for e in held_experts]
+        if len(set(held)) != len(held) or not all(0 <= e < num_experts for e in held):
+            from ..framework.errors import InvalidArgumentError
+            raise InvalidArgumentError(
+                f"held_experts {held} are not distinct ids below {num_experts}")
+        self.d_model, self.d_hidden = d_model, d_hidden
+        self.num_experts, self.top_k = num_experts, top_k
+        self.held_experts = held
+        self.routed_scaling_factor = routed_scaling_factor
+        self._row_tile = ROW_TILE
+        self._lookup = np.full(num_experts, -1, np.int32)
+        self._lookup[held] = np.arange(len(held), dtype=np.int32)
+        self.gate = nn.Linear(d_model, num_experts, weight_attr=weight_attr,
+                              bias_attr=False)
+        self.expert_bias = self.create_parameter(
+            [num_experts], attr=nn.ParamAttr(trainable=False),
+            dtype="float32", default_initializer=nn.initializer.Constant(0.0))
+        n = len(held)
+        self.w1 = self.create_parameter([n, d_model, d_hidden], attr=weight_attr)
+        self.w3 = self.create_parameter([n, d_model, d_hidden], attr=weight_attr)
+        self.w2 = self.create_parameter([n, d_hidden, d_model], attr=weight_attr)
+        for name in ("rows_total", "calls_total", "imbalance_total"):
+            self.register_buffer(name, Tensor(jnp.zeros((), jnp.float32)),
+                                 persistable=False)
+        _LAYERS.add(self)
+
+    def buffer_rows(self, n_tokens):
+        """Rows of the sorted buffer for `n_tokens` tokens: every pair held
+        here, each held expert's rows rounded up to a tile."""
+        tm = self._row_tile
+        pairs = n_tokens * self.top_k
+        return (pairs + tm - 1) // tm * tm + len(self.held_experts) * tm
+
+    def forward(self, x):
+        """(..., d_model) -> (the same shape, load): `load` is the rows each
+        held expert got, float32 (held,). The caller adds it to the counters
+        (`record_load`) outside any rematerialised region, where state writes
+        are dropped: the layer writes no state itself."""
+        from ..nn import functional as F
+        from ..ops.pallas.flash_attention import _interpret
+        from ..ops.pallas.grouped_matmul import grouped_matmul
+        shape = list(x.shape)
+        xf = x.reshape([-1, self.d_model])
+        n, k, tm = xf.shape[0], self.top_k, self._row_tile
+        rows = self.buffer_rows(n)
+        scale = self.routed_scaling_factor
+
+        def score(v, wg):
+            return jax.nn.sigmoid(jnp.matmul(
+                v, wg, preferred_element_type=jnp.float32))
+        scores = apply(score, xf, self.gate.weight, name="moe_route")
+        plan = functools.partial(
+            _route_plan, top_k=k, lookup=self._lookup,
+            n_held=len(self.held_experts), tm=tm, rows=rows)
+        (idx, pair_row, row_pair, row_valid, tile_group, num_tiles,
+         counts) = apply(plan, scores.detach(), self.expert_bias,
+                         name="moe_route")
+
+        def weigh(s, picked):
+            w = jnp.take_along_axis(s, picked, axis=1)
+            return w / (jnp.sum(w, axis=1, keepdims=True) + 1e-6) * scale
+        w = apply(weigh, scores, idx, name="moe_route")
+        xs = apply(lambda v, rp, rv, pr: _gather_rows(v, rp // k, rv, pr),
+                   xf, row_pair, row_valid, pair_row, name="moe_route")
+
+        interp = _interpret(xs._val)
+
+        def product(a, wts, tg, nt):
+            return grouped_matmul(a, wts, tg, nt, tm, interp)
+        h = F.swiglu(
+            apply(product, xs, self.w1, tile_group, num_tiles, name="moe_experts"),
+            apply(product, xs, self.w3, tile_group, num_tiles, name="moe_experts"))
+        y = apply(product, h, self.w2, tile_group, num_tiles, name="moe_experts")
+        out = apply(_combine_rows, y, w, pair_row, row_pair, row_valid,
+                    name="moe_combine").reshape(shape)
+        return out, apply(lambda c: c.astype(jnp.float32), counts, name="moe_route")
+
+    def record_load(self, load):
+        """Add one call's rows per held expert to the device counters."""
+        def add(rows, calls, imbalance, c):
+            total = jnp.sum(c)
+            return (rows + total, calls + 1.0, imbalance
+                    + jnp.max(c) * c.shape[0] / jnp.maximum(total, 1.0))
+        from ..core import autograd
+        with autograd.no_grad():
+            new = apply(add, self.rows_total, self.calls_total,
+                        self.imbalance_total, load, name="moe_route")
+        for t, v in zip((self.rows_total, self.calls_total,
+                         self.imbalance_total), new):
+            t._value = v._val

@@ -12,7 +12,7 @@ __all__ = [
     "hardsigmoid", "hardswish", "leaky_relu", "prelu", "rrelu", "softmax",
     "log_softmax", "softplus", "softshrink", "softsign", "swish", "silu",
     "elu_", "softmax_",
-    "mish", "maxout", "glu", "gumbel_softmax", "thresholded_relu",
+    "mish", "maxout", "glu", "swiglu", "gumbel_softmax", "thresholded_relu",
 ]
 
 
@@ -171,6 +171,19 @@ def maxout(x, groups, axis=1, name=None):
 
 def glu(x, axis=-1, name=None):
     return apply(lambda v: jax.nn.glu(v, axis=axis), x, name="glu")
+
+
+def swiglu(x, y=None, name=None):
+    """silu(x) * y (Shazeer 2020), the gate of a gated feed-forward; with
+    one argument its last axis is split in two halves, gate first
+    (paddle.incubate.nn.functional.swiglu). silu runs in float32."""
+    def prim(a, *b):
+        a, b = (a, b[0]) if b else jnp.split(a, 2, axis=-1)
+        return (jax.nn.silu(a.astype(jnp.float32)) * b.astype(jnp.float32)
+                ).astype(a.dtype)
+
+    args = [] if y is None else [y]
+    return apply(prim, x, *args, name="swiglu")
 
 
 def thresholded_relu(x, threshold=1.0, name=None):

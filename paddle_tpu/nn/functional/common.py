@@ -15,6 +15,7 @@ __all__ = [
     "embedding", "one_hot", "label_smooth", "pad", "interpolate", "upsample",
     "pixel_shuffle", "pixel_unshuffle", "channel_shuffle", "unfold", "fold",
     "cosine_similarity", "bilinear", "class_center_sample", "zeropad2d",
+    "rotary_position_embedding",
 ]
 
 
@@ -76,6 +77,31 @@ def alpha_dropout(x, p=0.5, training=True, name=None):
         return (jnp.where(keep, v, alpha_p) * a + b).astype(v.dtype)
 
     return apply(prim, x, kd, name="alpha_dropout")
+
+
+def rotary_position_embedding(q, k, theta=10000.0, position_offset=0,
+                              name=None):
+    """Rotary positions (Su et al. 2021) on `q` and `k`, each (batch, seq,
+    heads, head_dim), in the rotate-half convention over the whole head:
+    entry i pairs with entry i + head_dim/2 and the pair at position t turns
+    by t * theta^(-2i/head_dim). Angles and the rotation are float32; the
+    results keep their dtypes. `position_offset` is the position of the
+    first row (a cached decode step)."""
+    def prim(qv, kv):
+        d, s = qv.shape[-1], qv.shape[1]
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        t = jnp.arange(position_offset, position_offset + s, dtype=jnp.float32)
+        angle = jnp.concatenate([t[:, None] * inv[None, :]] * 2, axis=-1)
+        cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+
+        def turn(v):
+            f = v.astype(jnp.float32)
+            half = jnp.concatenate([-f[..., d // 2:], f[..., :d // 2]], axis=-1)
+            return (f * cos + half * sin).astype(v.dtype)
+
+        return turn(qv), turn(kv)
+
+    return apply(prim, q, k, name="rope")
 
 
 def embedding(x, weight, padding_idx=None, sparse=False, name=None):

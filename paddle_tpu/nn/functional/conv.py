@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from ...core.dispatch import apply
 
 __all__ = ["conv1d", "conv2d", "conv3d", "conv1d_transpose", "conv2d_transpose",
-           "conv3d_transpose"]
+           "conv3d_transpose", "short_conv"]
 
 
 def _norm_tuple(v, n):
@@ -178,3 +178,25 @@ def conv3d_transpose(x, weight, bias=None, stride=1, padding=0,
                      output_size=None, data_format="NCDHW", name=None):
     return _conv_transpose(3, x, weight, bias, stride, padding, output_padding,
                            dilation, groups, data_format, output_size)
+
+
+def short_conv(bcx, weight, name=None):
+    """The gated short convolution of the LFM2 models (Liquid AI 2025):
+    `bcx` (batch, seq, 3 * channels) is split into B, C and x along its last
+    axis; y_t = C_t * sum_j weight[:, j] * (B * x)_{t - (K-1) + j}, a causal
+    depthwise convolution of kernel K with one tap per channel and step,
+    zero before the sequence's start, between two multiplicative gates.
+    `weight` is (channels, K) with the last tap on the current step, as a
+    depthwise Conv1D holds it. K shifted multiply-adds in float32: at K = 3
+    a convolution call would only hide them."""
+    def prim(v, w):
+        b, c, x = jnp.split(v.astype(jnp.float32), 3, axis=-1)
+        u = b * x
+        taps = w.astype(jnp.float32)
+        k = taps.shape[1]
+        seq = u.shape[1]
+        padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
+        acc = sum(padded[:, j:j + seq] * taps[:, j] for j in range(k))
+        return (c * acc).astype(v.dtype)
+
+    return apply(prim, bcx, weight, name="short_conv")

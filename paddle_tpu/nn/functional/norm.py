@@ -12,8 +12,8 @@ import jax.numpy as jnp
 from ...core.dispatch import apply, unwrap
 from ...core.tensor import Tensor
 
-__all__ = ["batch_norm", "layer_norm", "instance_norm", "group_norm",
-           "local_response_norm", "normalize"]
+__all__ = ["batch_norm", "layer_norm", "rms_norm", "instance_norm",
+           "group_norm", "local_response_norm", "normalize"]
 
 
 def batch_norm(x, running_mean, running_var, weight=None, bias=None,
@@ -161,6 +161,21 @@ def group_norm(x, num_groups, epsilon=1e-05, weight=None, bias=None,
 
     args = [a for a in (weight, bias) if a is not None]
     return apply(prim, x, *args, name="group_norm")
+
+
+def rms_norm(x, weight=None, epsilon=1e-05, name=None):
+    """x / sqrt(mean(x^2) + epsilon) over the last axis, times a learned
+    gain (Zhang & Sennrich 2019). The statistics are float32 whatever the
+    input's dtype; the result is cast back before the gain is applied in
+    that dtype, as the public Llama-style models do."""
+    def prim(v, *w):
+        f = v.astype(jnp.float32)
+        out = (f * jax.lax.rsqrt(jnp.mean(jnp.square(f), axis=-1, keepdims=True)
+                                 + epsilon)).astype(v.dtype)
+        return out * w[0] if w else out
+
+    args = [] if weight is None else [weight]
+    return apply(prim, x, *args, name="rms_norm")
 
 
 def local_response_norm(x, size, alpha=1e-4, beta=0.75, k=1.0,

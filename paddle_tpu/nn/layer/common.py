@@ -12,7 +12,7 @@ __all__ = ["Linear", "Dropout", "Dropout2D", "Dropout3D", "AlphaDropout",
            "UpsamplingBilinear2D", "Pad1D", "Pad2D", "Pad3D", "ZeroPad2D",
            "CosineSimilarity", "Bilinear", "Identity", "Unfold", "Fold",
            "PixelShuffle", "PixelUnshuffle", "ChannelShuffle",
-           "PairwiseDistance", "MaxUnPool2D"]
+           "PairwiseDistance", "MaxUnPool2D", "SwiGLUFFN"]
 
 
 class Identity(Layer):
@@ -42,6 +42,24 @@ class Linear(Layer):
 
     def extra_repr(self):
         return f"in_features={self._in_features}, out_features={self._out_features}"
+
+
+class SwiGLUFFN(Layer):
+    """Gated feed-forward w2(silu(w1 x) * w3 x) (Shazeer 2020), no bias
+    unless `bias_attr` is given; the names are the Llama-style ones."""
+
+    def __init__(self, hidden_size, intermediate_size, weight_attr=None,
+                 bias_attr=False):
+        super().__init__()
+        self.w1 = Linear(hidden_size, intermediate_size,
+                         weight_attr=weight_attr, bias_attr=bias_attr)
+        self.w3 = Linear(hidden_size, intermediate_size,
+                         weight_attr=weight_attr, bias_attr=bias_attr)
+        self.w2 = Linear(intermediate_size, hidden_size,
+                         weight_attr=weight_attr, bias_attr=bias_attr)
+
+    def forward(self, x):
+        return self.w2(F.swiglu(self.w1(x), self.w3(x)))
 
 
 class Dropout(Layer):

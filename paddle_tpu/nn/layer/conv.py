@@ -8,7 +8,7 @@ from .. import initializer as I
 from .layers import Layer
 
 __all__ = ["Conv1D", "Conv2D", "Conv3D", "Conv1DTranspose", "Conv2DTranspose",
-           "Conv3DTranspose"]
+           "Conv3DTranspose", "ShortConv"]
 
 
 def _ntuple(v, n):
@@ -137,3 +137,31 @@ class Conv3DTranspose(_ConvNd):
                                   self._padding, self._output_padding,
                                   self._groups, self._dilation, output_size,
                                   self._data_format)
+
+
+class ShortConv(Layer):
+    """The gated short convolution block of the LFM2 models: an input
+    projection to 3 x hidden (B, C, x), a causal depthwise convolution of
+    `kernel_size` taps over B * x gated by C (F.short_conv), and an output
+    projection. No bias unless `bias_attr` is given. The taps are held as
+    (hidden, kernel_size), the last on the current step."""
+
+    def __init__(self, hidden_size, kernel_size=3, weight_attr=None,
+                 bias_attr=False):
+        super().__init__()
+        from .common import Linear
+        self._kernel_size = kernel_size
+        self.in_proj = Linear(hidden_size, 3 * hidden_size,
+                              weight_attr=weight_attr, bias_attr=bias_attr)
+        self.weight = self.create_parameter(
+            shape=[hidden_size, kernel_size], attr=weight_attr,
+            default_initializer=I.Uniform(-1.0 / np.sqrt(kernel_size),
+                                          1.0 / np.sqrt(kernel_size)))
+        self.out_proj = Linear(hidden_size, hidden_size,
+                               weight_attr=weight_attr, bias_attr=bias_attr)
+
+    def forward(self, x):
+        return self.out_proj(F.short_conv(self.in_proj(x), self.weight))
+
+    def extra_repr(self):
+        return f"{self.weight.shape[0]}, kernel_size={self._kernel_size}"

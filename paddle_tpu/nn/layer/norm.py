@@ -9,7 +9,7 @@ from .. import initializer as I
 from .layers import Layer
 
 __all__ = ["SpectralNorm", "BatchNorm", "BatchNorm1D", "BatchNorm2D", "BatchNorm3D",
-           "SyncBatchNorm", "LayerNorm", "GroupNorm", "InstanceNorm1D",
+           "SyncBatchNorm", "LayerNorm", "RMSNorm", "GroupNorm", "InstanceNorm1D",
            "InstanceNorm2D", "InstanceNorm3D", "LocalResponseNorm"]
 
 
@@ -112,6 +112,25 @@ class LayerNorm(Layer):
 
     def extra_repr(self):
         return f"normalized_shape={self._normalized_shape}"
+
+
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis with a learned gain and no
+    bias (F.rms_norm)."""
+
+    def __init__(self, hidden_size, epsilon=1e-05, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self._epsilon = epsilon
+        self.weight = self.create_parameter(
+            shape=[hidden_size], attr=weight_attr,
+            default_initializer=I.Constant(1.0))
+
+    def forward(self, x):
+        return F.rms_norm(x, self.weight, self._epsilon)
+
+    def extra_repr(self):
+        return f"hidden_size={self.weight.shape[0]}, epsilon={self._epsilon}"
 
 
 class GroupNorm(Layer):

@@ -13,10 +13,20 @@ import jax
 import jax.numpy as jnp
 
 from ..core.dispatch import apply, unwrap
+from ..profiler import metrics as _metrics
+
+# the measured probe of the XLA side holds its float32 scores, their softmax
+# and both gradients at once: about four score tensors
+PROBE_SCORE_TENSORS = 4
 
 
 def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, dropout_key):
     # q,k,v: (B, S, H, D) paddle layout -> compute in (B, H, S, D)
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        # grouped-query attention: query head h reads key/value head
+        # h // group, here by a repeat that XLA materialises
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     q = jnp.swapaxes(q, 1, 2)
     k = jnp.swapaxes(k, 1, 2)
     v = jnp.swapaxes(v, 1, 2)
@@ -41,7 +51,16 @@ def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, dropout_key):
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, use_pallas=None, scale=None):
+    """(batch, seq, heads, head_dim) attention. `key` and `value` may hold
+    fewer heads than `query`, a divisor of its count (grouped-query
+    attention): query head h reads key/value head h // (heads / kv_heads).
+    The path a call took is counted: `attention.flash_total`,
+    `attention.xla_total` (docs/observability.md)."""
     qv = unwrap(query)
+    if qv.shape[2] % unwrap(key).shape[2]:
+        raise ValueError(
+            f"{qv.shape[2]} query heads do not divide over "
+            f"{unwrap(key).shape[2]} key/value heads")
     head_dim = qv.shape[-1]
     if scale is None:
         scale = 1.0 / (head_dim ** 0.5)
@@ -70,13 +89,15 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             pol = autotune.fusion_policy()
             if pol == "never":
                 use_pallas = False
-            elif pol == "auto":
+            elif pol == "auto" and _scores_fit_a_probe(qv, unwrap(key)):
                 use_pallas = _flash_wins(qv, unwrap(key), unwrap(value),
                                          is_causal, scale)
     elif use_pallas and (attn_mask is not None or dropout_p > 0.0):
         raise ValueError(
             "use_pallas=True is incompatible with attn_mask/dropout_p: the "
             "flash kernel computes plain (optionally causal) attention")
+    _metrics.get_registry().inc_counter(
+        "attention.flash_total" if use_pallas else "attention.xla_total")
     if use_pallas:
         return apply(_flash_prim(qv, is_causal, scale), query, key, value,
                      name="flash_attention")
@@ -177,6 +198,26 @@ def _flash_wins(qv, kv, vv, is_causal, scale):
         "flash_attention", prim_flash, prim_xla, (qv, kv, vv),
         module="paddle_tpu.ops.pallas.flash_attention")
     return choice == "fused"
+
+
+def _scores_fit_a_probe(qv, kv):
+    """Whether the XLA candidate can be measured at all: its float32 score
+    tensor (batch, heads, seq_q, seq_k), four times over for the probe's
+    forward and backward, has to fit in half the device's memory beside
+    whatever the program already holds. Where it does not, the flash kernel
+    is the only candidate that runs and is taken unmeasured
+    (`attention.probe_skipped_total`)."""
+    scores = 4 * qv.shape[0] * qv.shape[2] * qv.shape[1] * kv.shape[1]
+    fits = PROBE_SCORE_TENSORS * scores <= _device_memory_bytes() // 2
+    if not fits:
+        _metrics.get_registry().inc_counter("attention.probe_skipped_total")
+    return fits
+
+
+@functools.lru_cache(maxsize=1)
+def _device_memory_bytes():
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit", 16 * 2 ** 30)
 
 
 def _pallas_supports(query, key):

@@ -17,6 +17,13 @@ atomics. Head dims of 64 are supported (VMEM pads the lane dim; the
 s^2-materializing XLA fallback costs far more than the padding).
 
 Layout: inputs (B, S, H, D) paddle convention; kernels work on (B*H, S, D).
+
+Grouped-query attention: k and v may hold H / group heads. Row b*H + h of q
+then reads row (b*H + h) // group of k and v, which the block specs' index
+maps say, so no repeated copy of k or v is ever written. The dkv pass still
+runs per query head and writes float32 partial dk, dv of (B*H, S, D), which
+one XLA reduction adds over each group: 2 x B*H*S*D*4 bytes written and read
+once more, for a kernel that stays free of cross-instance accumulation.
 """
 from __future__ import annotations
 
@@ -148,9 +155,10 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, scale, causal,
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret"))
 def _flash_fwd_bh(q, k, v, causal, scale, block_q, block_k, interpret):
-    # q,k,v: (BH, S, D) -> out (BH, S, D), lse (BH, S)
+    # q: (BH, S, D), k,v: (BH / group, S, D) -> out (BH, S, D), lse (BH, S)
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
+    group = bh // k.shape[0]
     grid = (bh, seq_q // block_q)
     out, lse = pl.pallas_call(
         functools.partial(_attn_fwd_kernel, scale=scale, causal=causal,
@@ -159,8 +167,8 @@ def _flash_fwd_bh(q, k, v, causal, scale, block_q, block_k, interpret):
         interpret=interpret,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, seq_k, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, seq_k, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, seq_k, d), lambda b, i: (b // group, 0, 0)),
+            pl.BlockSpec((None, seq_k, d), lambda b, i: (b // group, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
@@ -272,11 +280,14 @@ def _attn_bwd_dq_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref, dq_ref,
     "block_k_dq", "interpret"))
 def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q_dkv,
                   block_k_dkv, block_q_dq, block_k_dq, interpret):
-    # all (BH, S, D) except lse (BH, S); returns dq, dk, dv. The dkv and dq
-    # passes tile different sequence axes, so each takes its own
-    # (block_q, block_k) pair.
+    # all (BH, S, D) except k, v (BH / group, S, D) and lse (BH, S); returns
+    # dq, dk, dv. The dkv and dq passes tile different sequence axes, so each
+    # takes its own (block_q, block_k) pair.
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
+    group = bh // k.shape[0]
+    # per query head; float32 where a group's heads are still to be added
+    dkv_dtype = (k.dtype, v.dtype) if group == 1 else (jnp.float32,) * 2
     # D = rowsum(dO * O): one fused elementwise+reduce pass, reads dO/O once.
     # lse/delta ride in (bh, seq, 128) lane-broadcast form (Mosaic block
     # constraint — see _attn_fwd_kernel note).
@@ -294,20 +305,25 @@ def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q_dkv,
             pl.BlockSpec((None, seq_q, d), lambda b, j: (b, 0, 0)),    # do
             pl.BlockSpec((None, seq_q, 128), lambda b, j: (b, 0, 0)),  # lse
             pl.BlockSpec((None, seq_q, 128), lambda b, j: (b, 0, 0)),  # delta
-            pl.BlockSpec((None, block_k_dkv, d), lambda b, j: (b, j, 0)),  # k
-            pl.BlockSpec((None, block_k_dkv, d), lambda b, j: (b, j, 0)),  # v
+            pl.BlockSpec((None, block_k_dkv, d),
+                         lambda b, j: (b // group, j, 0)),              # k
+            pl.BlockSpec((None, block_k_dkv, d),
+                         lambda b, j: (b // group, j, 0)),              # v
         ],
         out_specs=[
             pl.BlockSpec((None, block_k_dkv, d), lambda b, j: (b, j, 0)),
             pl.BlockSpec((None, block_k_dkv, d), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq_k, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, seq_k, d), dkv_dtype[0]),
+            jax.ShapeDtypeStruct((bh, seq_k, d), dkv_dtype[1]),
         ],
         **_tpu_params(interpret, 2),
     )(q, do, lse3, delta3, k, v)
     dk, dv = dkv
+    if group > 1:
+        dk = dk.reshape(bh // group, group, seq_k, d).sum(1).astype(k.dtype)
+        dv = dv.reshape(bh // group, group, seq_k, d).sum(1).astype(v.dtype)
 
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel, scale=scale, causal=causal,
@@ -321,8 +337,10 @@ def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q_dkv,
                          lambda b, i: (b, i, 0)),                       # lse
             pl.BlockSpec((None, block_q_dq, 128),
                          lambda b, i: (b, i, 0)),                       # dlt
-            pl.BlockSpec((None, seq_k, d), lambda b, i: (b, 0, 0)),     # k
-            pl.BlockSpec((None, seq_k, d), lambda b, i: (b, 0, 0)),     # v
+            pl.BlockSpec((None, seq_k, d),
+                         lambda b, i: (b // group, 0, 0)),              # k
+            pl.BlockSpec((None, seq_k, d),
+                         lambda b, i: (b // group, 0, 0)),              # v
         ],
         out_specs=pl.BlockSpec((None, block_q_dq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
@@ -339,7 +357,7 @@ def supports(q_shape, k_shape):
     b, s_q, h, d = q_shape
     s_k = k_shape[1]
     return (s_q % 128 == 0 and s_k % 128 == 0
-            and d % 64 == 0 and s_q == s_k)
+            and d % 64 == 0 and s_q == s_k and h % k_shape[2] == 0)
 
 
 def _clamp(block, seq):
@@ -377,7 +395,13 @@ def _synth_bh(shapes, dtypes):
     return out
 
 
-def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp):
+def _group_tag(group):
+    """A signature's suffix for grouped-query shapes; none for plain
+    multi-head attention, whose cached configurations stay valid."""
+    return "" if group == 1 else "|g%d" % group
+
+
+def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
     """(block_q, block_k) for the forward kernel: deterministic defaults
     under interpret/CPU, autotuned (and cached) on TPU."""
     fallback = (_clamp(DEFAULT_BLOCK_Q, s_q), _clamp(DEFAULT_BLOCK_K, s_k))
@@ -390,7 +414,8 @@ def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp):
     if len(cands) == 1:
         return cands[0]
     sig = "fwd|bh%d|s%dx%d|d%d|%s|c%d" % (
-        shape_bucket((bh,))[0], s_q, s_k, d, short_dtype(dtype), int(causal))
+        shape_bucket((bh,))[0], s_q, s_k, d, short_dtype(dtype), int(causal)
+    ) + _group_tag(group)
 
     def build(cand):
         return functools.partial(
@@ -398,8 +423,8 @@ def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp):
             block_q=cand[0], block_k=cand[1], interpret=False)
 
     def make_args():
-        return _synth_bh([(bh, s_q, d), (bh, s_k, d), (bh, s_k, d)],
-                         [dtype] * 3)
+        return _synth_bh([(bh, s_q, d), (bh // group, s_k, d),
+                          (bh // group, s_k, d)], [dtype] * 3)
 
     return get_tuner().get(
         "flash_attention", sig, candidates=cands, build=build,
@@ -407,7 +432,7 @@ def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp):
         version=source_version(__name__))
 
 
-def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp):
+def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
     """(block_q_dkv, block_k_dkv, block_q_dq, block_k_dq) for the backward
     pair: bf16-aware deterministic defaults under interpret/CPU, autotuned
     (and cached) on TPU."""
@@ -423,7 +448,8 @@ def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp):
     if len(cands) == 1:
         return cands[0]
     sig = "bwd|bh%d|s%dx%d|d%d|%s|c%d" % (
-        shape_bucket((bh,))[0], s_q, s_k, d, short_dtype(dtype), int(causal))
+        shape_bucket((bh,))[0], s_q, s_k, d, short_dtype(dtype), int(causal)
+    ) + _group_tag(group)
 
     def build(cand):
         return functools.partial(
@@ -433,8 +459,8 @@ def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp):
 
     def make_args():
         args = _synth_bh(
-            [(bh, s_q, d), (bh, s_k, d), (bh, s_k, d), (bh, s_q, d)],
-            [dtype] * 4)
+            [(bh, s_q, d), (bh // group, s_k, d), (bh // group, s_k, d),
+             (bh, s_q, d)], [dtype] * 4)
         lse = jnp.zeros((bh, s_q), jnp.float32)
         do = _synth_bh([(bh, s_q, d)], [dtype])[0]
         return args + [lse, do]
@@ -467,7 +493,8 @@ def flash_attention_fwd(q, k, v, causal=False, scale=1.0,
     s_k = k.shape[1]
     interp = _interpret(q) if interpret is None else interpret
     if block_q is None and block_k is None:
-        bq, bk = _tuned_fwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp)
+        bq, bk = _tuned_fwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp,
+                                   group=h // k.shape[2])
     else:
         bq = _clamp(block_q or DEFAULT_BLOCK_Q, s)
         bk = _clamp(block_k or DEFAULT_BLOCK_K, s_k)
@@ -486,7 +513,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=1.0,
     s_k = k.shape[1]
     interp = _interpret(q) if interpret is None else interpret
     if block_q is None and block_k is None:
-        blocks = _tuned_bwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp)
+        blocks = _tuned_bwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp,
+                                   group=h // k.shape[2])
     else:
         bq = block_q or DEFAULT_BLOCK_Q
         bk = block_k or DEFAULT_BLOCK_K
@@ -497,4 +525,5 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=1.0,
         _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(out),
         lse.reshape(b * h, s), _to_bh(do), causal, scale,
         blocks[0], blocks[1], blocks[2], blocks[3], interp)
-    return (_from_bh(dq, b, h), _from_bh(dk, b, h), _from_bh(dv, b, h))
+    h_kv = k.shape[2]
+    return (_from_bh(dq, b, h), _from_bh(dk, b, h_kv), _from_bh(dv, b, h_kv))

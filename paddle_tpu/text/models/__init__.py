@@ -3,7 +3,9 @@ from .ernie import (  # noqa: F401
     ErnieConfig, ErnieForSequenceClassification, ErnieModel,
 )
 from .gpt import GPTForCausalLM, GPTModel  # noqa: F401
+from .lfm2 import LFM2Config, LFM2ForCausalLM, LFM2Model  # noqa: F401
 
 __all__ = ["BertModel", "BertForSequenceClassification", "GPTModel",
            "GPTForCausalLM", "ErnieConfig", "ErnieModel",
-           "ErnieForSequenceClassification"]
+           "ErnieForSequenceClassification", "LFM2Config", "LFM2Model",
+           "LFM2ForCausalLM"]

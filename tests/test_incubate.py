@@ -185,6 +185,59 @@ class TestRecompute:
         g_ckpt = np.asarray(lin.weight.grad._value)
         np.testing.assert_allclose(g_plain, g_ckpt, rtol=1e-5)
 
+    @staticmethod
+    def _dropout_region():
+        """y = dropout(x W + b) with W the identity: the bias gradient of
+        sum(y) is the kept entries of each column times 1 / (1 - p)."""
+        lin = paddle.nn.Linear(8, 8)
+        lin.weight.set_value(np.eye(8, dtype="float32"))
+        return lin, lambda t: F.dropout(lin(t) + 1.0, p=0.5, training=True)
+
+    def test_rerun_draws_the_forwards_dropout_mask(self):
+        # a random op between the region's forward and its backward moves
+        # the generator's key; the rerun must still see the forward's
+        from paddle_tpu.distributed.fleet.utils import recompute
+        paddle.seed(3)
+        lin, region = self._dropout_region()
+        y = recompute(region, paddle.zeros([16, 8]))
+        kept = np.asarray(y._value) != 0
+        F.dropout(y, p=0.5, training=True)
+        paddle.rand([3])
+        y.sum().backward()
+        assert 0 < kept.sum() < kept.size
+        np.testing.assert_allclose(np.asarray(lin.bias.grad._value),
+                                   2.0 * kept.sum(0))
+
+    def test_rerun_draws_the_forwards_mask_in_a_compiled_step(self):
+        from paddle_tpu.distributed.fleet.utils import recompute
+        paddle.seed(4)
+        lin, region = self._dropout_region()
+
+        @paddle.jit.to_static
+        def step(x):
+            y = recompute(region, x)
+            loss = (F.dropout(y, p=0.5, training=True) * 0.0 + y).sum()
+            loss.backward()
+            grad = lin.bias.grad + 0.0
+            lin.clear_gradients()
+            return y, grad
+
+        for _ in range(4):       # discovery, both compiles, a steady call
+            y, grad = step(paddle.zeros([16, 8]))
+            kept = np.asarray(y._value) != 0
+            np.testing.assert_allclose(np.asarray(grad._value), 2.0 * kept.sum(0))
+
+    def test_rerun_reads_the_state_the_forward_read(self):
+        # a frozen tensor the region reads, changed before the backward
+        from paddle_tpu.distributed.fleet.utils import recompute
+        lin = paddle.nn.Linear(4, 4)
+        scale = paddle.to_tensor(np.full([4], 2.0, "float32"))   # stop_gradient
+        x = paddle.to_tensor(np.ones([2, 4], "float32"))
+        y = recompute(lambda t: lin(t) * scale, x)
+        scale.set_value(np.full([4], 5.0, "float32"))
+        y.sum().backward()
+        np.testing.assert_allclose(np.asarray(lin.bias.grad._value), 2 * 2.0)
+
 
 class TestFusedSoftmaxMask:
     def test_softmax_mask_fuse_matches_numpy(self):

@@ -179,3 +179,65 @@ def test_flash_under_a_dp2_mp2_mesh_compiles(topo):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
     assert " all-gather(" not in text and " all-reduce(" not in text
+
+
+# ---------------------------------------------------------------------------
+# LFM2-24B-A2B widths: grouped heads at 4096 positions, and the expert
+# layer's grouped products over one chip's 8 experts
+
+def test_grouped_head_vjp_pair_compiles_at_lfm2_widths(one_chip):
+    """(b 2, s 4096, 32 query heads over 8 key/value heads, d 64) bf16
+    causal, differentiated: k and v are read through the index maps, so the
+    program holds no repeated copy of them, and dk, dv come back with 8 heads."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.attention import _flash_attention_diff
+
+    def loss(q, k, v):
+        out = _flash_attention_diff(q, k, v, True, 64 ** -0.5, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = _sds((2, 4096, 32, 64), jnp.bfloat16, one_chip)
+    kv = _sds((2, 4096, 8, 64), jnp.bfloat16, one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    _assert_kernel(compiled, 3)
+    assert [o.shape for o in compiled.out_info] == [
+        (2, 4096, 32, 64), (2, 4096, 8, 64), (2, 4096, 8, 64)]
+
+
+@pytest.mark.parametrize("cand", range(5))
+def test_every_candidate_compiles_at_grouped_heads_and_4096(one_chip, cand):
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    q = _sds((64, 4096, 64), jnp.bfloat16, one_chip)
+    kv = _sds((16, 4096, 64), jnp.bfloat16, one_chip)
+    lse = _sds((64, 4096), jnp.float32, one_chip)
+    bq, bk = fa._FWD_CANDIDATES[cand]
+    _assert_kernel(fa._flash_fwd_bh.lower(
+        q, kv, kv, causal=True, scale=0.125, block_q=bq, block_k=bk,
+        interpret=False).compile(), 1)
+    b = fa._BWD_CANDIDATES[cand]
+    _assert_kernel(fa._flash_bwd_bh.lower(
+        q, kv, kv, q, lse, q, causal=True, scale=0.125, block_q_dkv=b[0],
+        block_k_dkv=b[1], block_q_dq=b[2], block_k_dq=b[3],
+        interpret=False).compile(), 2)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)],
+                         ids=["up-2048x1536", "down-1536x2048"])
+def test_grouped_products_compile_at_lfm2_widths(one_chip, k, n):
+    """The three kernels a projection of the expert layer runs in a train
+    step (product, product against the transposed weights, weight gradient),
+    over the worst-case buffer of 2 x 4096 tokens x 4 experts, with the grid
+    a run-time value."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    rows, groups = 2 * 4096 * 4 + 8 * gm.ROW_TILE, 8
+    x = _sds((rows, k), jnp.bfloat16, one_chip)
+    dy = _sds((rows, n), jnp.bfloat16, one_chip)
+    w = _sds((groups, k, n), jnp.bfloat16, one_chip)
+    tiles = _sds((rows // gm.ROW_TILE,), jnp.int32, one_chip)
+    used = _sds((), jnp.int32, one_chip)
+    _assert_kernel(gm.gmm.lower(x, w, tiles, used).compile(), 1)
+    _assert_kernel(gm.gmm.lower(dy, w, tiles, used, transpose_w=True).compile(), 1)
+    _assert_kernel(gm.tgmm.lower(x, dy, tiles, used, groups=groups).compile(), 1)

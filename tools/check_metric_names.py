@@ -36,6 +36,7 @@ SCAN = ["paddle_tpu", "bench.py"]
 # until it is added here — the review of that one-line diff is the naming
 # review.
 SUBSYSTEMS = [
+    "attention",     # the path scaled_dot_product_attention took (ops/attention.py)
     "autotune",      # kernel-tier block autotuning
     "campaign",      # chaos-campaign engine (resilience/campaign.py)
     "ckpt",          # zero-stall checkpointing (resilience/snapshot.py)
@@ -47,7 +48,8 @@ SUBSYSTEMS = [
     "integrity",     # SDC defense (checksum consensus, replay)
     "io",            # input pipeline / data workers
     "metrics",       # the registry/exporter's own health
-    "moe",           # elastic expert parallelism (fleet/expert_parallel.py)
+    "moe",           # expert layers: elastic expert parallelism
+                     # (fleet/expert_parallel.py), routing load (incubate/moe.py)
     "prefix",        # prefix-sharing KV cache (serving/decode/prefix.py)
     "profiler",      # profiler-internal (samples/sec, ...)
     "rollout",       # live model rollout (serving/rollout.py)
