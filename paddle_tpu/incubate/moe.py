@@ -166,81 +166,161 @@ def _route_plan(scores, bias, *, top_k, lookup, n_held, tm, rows):
     the pair a buffer row holds and row_valid whether it holds one;
     tile_group (rows / tm,) the held expert of each tile; num_tiles the
     tiles in use; counts (n_held,) the rows of each held expert."""
+    return _plan(scores, bias, top_k=top_k, lookup=tuple(int(e) for e in lookup),
+                 n_held=n_held, tm=tm, rows=rows)
+
+
+# one program where it runs eagerly (to_static's discovery pass), not one an
+# operation: the plan is some eighty of them
+@functools.partial(jax.jit, static_argnames=("top_k", "lookup", "n_held", "tm", "rows"))
+def _plan(scores, bias, *, top_k, lookup, n_held, tm, rows):
     n = scores.shape[0]
     pairs = n * top_k
     _, idx = jax.lax.top_k(scores + bias.astype(scores.dtype), top_k)
-    local = jnp.asarray(lookup)[idx].reshape(-1)      # held expert's slot, or -1
+    local = jnp.asarray(lookup, jnp.int32)[idx].reshape(-1)   # held expert's slot, or -1
     held = local >= 0
     key = jnp.where(held, local, n_held)
-    counts = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=key.dtype),
-                     axis=0, dtype=jnp.int32)
-    order = jnp.argsort(key, stable=True)             # sorted position -> pair
-    rank = jnp.argsort(order)                         # pair -> sorted position
+    # The key takes n_held + 1 values, so a pair's place in a stable sort by it
+    # is a count and a running sum, and every table below has n_held entries:
+    # read through `of` (a compare and a sum), never by a gather of thousands
+    # of indices, which XLA runs one index at a time.
+    of = key[:, None] == jnp.arange(n_held, dtype=key.dtype)       # (pairs, held)
+    counts = jnp.sum(of, axis=0, dtype=jnp.int32)
     tiles = jnp.maximum((counts + tm - 1) // tm, 1)
     tile_end = jnp.cumsum(tiles)
     row_start = (tile_end - tiles) * tm
     sorted_start = jnp.cumsum(counts) - counts
-    slot = jnp.maximum(local, 0)
-    pair_row = jnp.where(held, row_start[slot] + rank - sorted_start[slot], rows)
+    # the pairs of its own expert before a pair, in pair order
+    before = jnp.sum(jnp.where(of, jnp.cumsum(of, axis=0, dtype=jnp.int32) - 1, 0),
+                     axis=1)
+    pair_row = jnp.where(held, jnp.sum(jnp.where(of, row_start, 0), axis=1) + before,
+                         rows)
     tile_group = jnp.minimum(
         jnp.searchsorted(tile_end, jnp.arange(rows // tm, dtype=jnp.int32),
                          side="right"), n_held - 1).astype(jnp.int32)
-    row_group = jnp.repeat(tile_group, tm, total_repeat_length=rows)
-    at = jnp.arange(rows, dtype=jnp.int32) - row_start[row_group]
-    row_valid = at < counts[row_group]
-    row_pair = jnp.where(
-        row_valid, order[jnp.clip(sorted_start[row_group] + at, 0, pairs - 1)], 0)
+    # An expert's rows are a run of the sorted pairs moved down by its tiles'
+    # padding: one roll an expert, kept where the row is one of its own.
+    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
+    mine = (row >= row_start) & (row < row_start + counts)          # (rows, held)
+    row_valid = jnp.any(mine, axis=1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)         # sorted position -> pair
+    order = jnp.concatenate([order, jnp.zeros((rows - pairs,), jnp.int32)])
+    row_pair = jnp.zeros((rows,), jnp.int32)
+    for e in range(n_held):
+        row_pair = jnp.where(mine[:, e], jnp.roll(order, row_start[e] - sorted_start[e]),
+                             row_pair)
     return (idx.astype(jnp.int32), pair_row.reshape(n, top_k).astype(jnp.int32),
             row_pair.astype(jnp.int32), row_valid, tile_group,
             tile_end[-1].astype(jnp.int32), counts)
 
 
-@jax.custom_vjp
-def _gather_rows(x, row_token, row_valid, pair_row):
-    """The buffer's rows from the tokens: row r holds x[row_token[r]], zero
-    where it holds no pair. Its transpose adds each token's rows back, and
-    is written as a gather over `pair_row` (a token's pairs and their rows)
-    because a scatter-add of thousands of rows runs one row at a time."""
-    return jnp.where(row_valid[:, None], x[row_token], 0)
+# The five moves of rows between the tokens and the sorted buffer: the buffer
+# from the tokens and that move's transpose (_gather_rows), the tokens from the
+# buffer's weighted rows and that move's two backward moves (_combine_rows).
+# Each has two paths with one meaning. `kernel` false: the jnp rules below, XLA
+# gathers over the whole buffer; the path off the TPU and the oracle of the
+# other. `kernel` true: ops/pallas/row_moves.py, one copy a row that is there,
+# under run-time bounds; rows of tiles at or past `num_tiles` are then never
+# written (the grouped products never read them). `held` is what
+# row_moves.held_pairs makes of pair_row, () on the jnp path.
+
+def _count_moves(kernel, n=1):
+    """The path `n` traced row moves took, in the registry."""
+    _metrics.get_registry().inc_counter(
+        "moe.row_kernel_total" if kernel else "moe.row_xla_total", n)
 
 
-def _gather_rows_fwd(x, row_token, row_valid, pair_row):
-    return _gather_rows(x, row_token, row_valid, pair_row), pair_row
+def _tile_rows(row_valid, tm):
+    return jnp.sum(row_valid.reshape(-1, tm), axis=1, dtype=jnp.int32)
 
 
-def _gather_rows_bwd(pair_row, g):
-    back = jnp.take(g, pair_row, axis=0, mode="fill", fill_value=0)  # (N, k, H)
-    return (jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype),
-            None, None, None)
+def _gather(x, row_pair, row_valid, pair_row, num_tiles, tm, kernel):
+    _count_moves(kernel)
+    if not kernel:
+        return jnp.where(row_valid[:, None], x[row_pair // pair_row.shape[1]], 0)
+    from ..ops.pallas import row_moves
+    return row_moves.rows_from_tokens(
+        row_moves.pack_rows(x, tm=tm), row_pair, _tile_rows(row_valid, tm),
+        num_tiles, k=pair_row.shape[1], h=x.shape[1], dtype=x.dtype, tm=tm)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _gather_rows(x, row_pair, row_valid, pair_row, held, num_tiles, tm, kernel):
+    """The buffer's rows from the tokens: row r holds the token of the pair
+    row_pair[r], zero where it holds no pair. Its transpose adds each token's
+    rows back, and is written as a gather over `pair_row` (a token's pairs and
+    their rows) because a scatter-add of thousands of rows runs one row at a
+    time."""
+    return _gather(x, row_pair, row_valid, pair_row, num_tiles, tm, kernel)
+
+
+def _gather_rows_fwd(x, row_pair, row_valid, pair_row, held, num_tiles, tm, kernel):
+    return (_gather(x, row_pair, row_valid, pair_row, num_tiles, tm, kernel),
+            (pair_row, held, num_tiles))
+
+
+def _gather_rows_bwd(tm, kernel, res, g):
+    pair_row, held, num_tiles = res
+    _count_moves(kernel)
+    if kernel:
+        from ..ops.pallas import row_moves
+        dx = row_moves.tokens_from_rows(
+            row_moves.pack_rows(g, num_tiles, tm=tm), pair_row, held,
+            h=g.shape[1], dtype=g.dtype)
+    else:
+        back = jnp.take(g, pair_row, axis=0, mode="fill", fill_value=0)  # (N, k, H)
+        dx = jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype)
+    return dx, None, None, None, None, None
 
 
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 
 
-@jax.custom_vjp
-def _combine_rows(y, w, pair_row, row_pair, row_valid):
+def _combine(y, w, pair_row, held, num_tiles, tm, kernel):
+    """(out, what the backward pass keeps of y): y itself, or its packed rows."""
+    _count_moves(kernel)
+    if not kernel:
+        picked = jnp.take(y, pair_row, axis=0, mode="fill", fill_value=0)
+        return jnp.sum(picked.astype(jnp.float32) * w[..., None],
+                       axis=1).astype(y.dtype), y
+    from ..ops.pallas import row_moves
+    packed = row_moves.pack_rows(y, num_tiles, tm=tm)
+    return row_moves.tokens_from_rows(packed, pair_row, held, w, h=y.shape[1],
+                                      dtype=y.dtype), packed
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _combine_rows(y, w, pair_row, row_pair, row_valid, held, num_tiles, tm, kernel):
     """out[t] = sum_j w[t, j] * y[pair_row[t, j]], a pair whose expert is
     not held adding nothing: float32 sum, y's dtype out. The transpose is a
     gather too: row r gets w * the cotangent of the one token it serves."""
-    picked = jnp.take(y, pair_row, axis=0, mode="fill", fill_value=0)
-    return jnp.sum(picked.astype(jnp.float32) * w[..., None], axis=1).astype(y.dtype)
+    return _combine(y, w, pair_row, held, num_tiles, tm, kernel)[0]
 
 
-def _combine_rows_fwd(y, w, pair_row, row_pair, row_valid):
-    return _combine_rows(y, w, pair_row, row_pair, row_valid), (
-        y, w, pair_row, row_pair, row_valid)
+def _combine_rows_fwd(y, w, pair_row, row_pair, row_valid, held, num_tiles, tm, kernel):
+    out, kept = _combine(y, w, pair_row, held, num_tiles, tm, kernel)
+    return out, (kept, w, pair_row, row_pair, row_valid, held, num_tiles)
 
 
-def _combine_rows_bwd(res, g):
-    y, w, pair_row, row_pair, row_valid = res
+def _combine_rows_bwd(tm, kernel, res, g):
+    y, w, pair_row, row_pair, row_valid, held, num_tiles = res
     k = w.shape[1]
-    row_w = w.reshape(-1)[row_pair]
-    dy = jnp.where(row_valid[:, None],
-                   g[row_pair // k].astype(jnp.float32) * row_w[:, None], 0)
-    picked = jnp.take(y, pair_row, axis=0, mode="fill", fill_value=0)
-    dw = jnp.sum(picked.astype(jnp.float32) * g[:, None, :].astype(jnp.float32),
-                 axis=-1)
-    return dy.astype(y.dtype), dw.astype(w.dtype), None, None, None
+    _count_moves(kernel, 2)
+    if kernel:
+        from ..ops.pallas import row_moves
+        dy = row_moves.rows_from_tokens(
+            row_moves.pack_rows(g, tm=tm), row_pair, _tile_rows(row_valid, tm),
+            num_tiles, w, k=k, h=g.shape[1], dtype=g.dtype, tm=tm)
+        dw = row_moves.pair_dots(y, pair_row, held, g)
+    else:
+        row_w = w.reshape(-1)[row_pair]
+        dy = jnp.where(row_valid[:, None],
+                       g[row_pair // k].astype(jnp.float32) * row_w[:, None],
+                       0).astype(y.dtype)
+        picked = jnp.take(y, pair_row, axis=0, mode="fill", fill_value=0)
+        dw = jnp.sum(picked.astype(jnp.float32)
+                     * g[:, None, :].astype(jnp.float32), axis=-1)
+    return dy, dw.astype(w.dtype), None, None, None, None, None
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
@@ -288,8 +368,11 @@ class DroplessMoELayer(nn.Layer):
     expert into a buffer sized for the worst routing (every pair held here),
     each held expert's rows tile-aligned, and one grouped product per
     projection (ops/pallas/grouped_matmul.py) runs over the tiles in use,
-    so the work follows the rows routed here. The gathers into and out of
-    the buffer are XLA's and cost the buffer's size.
+    so the work follows the rows routed here. So do the moves of rows into
+    and out of the buffer on a TPU (ops/pallas/row_moves.py: one copy a row
+    that is there); off it they are XLA's gathers and cost the buffer's size
+    (`moe.row_kernel_total`, `moe.row_xla_total` count the moves traced by
+    the path they took).
 
     Scopes: `moe_route` (scores, top-k, the plan, the gather), `moe_experts`
     (the grouped products), `swiglu`, `moe_combine`. Counters, on the device,
@@ -343,6 +426,7 @@ class DroplessMoELayer(nn.Layer):
         (`record_load`) outside any rematerialised region, where state writes
         are dropped: the layer writes no state itself."""
         from ..nn import functional as F
+        from ..ops.pallas import row_moves
         from ..ops.pallas.flash_attention import _interpret
         from ..ops.pallas.grouped_matmul import grouped_matmul
         shape = list(x.shape)
@@ -350,26 +434,35 @@ class DroplessMoELayer(nn.Layer):
         n, k, tm = xf.shape[0], self.top_k, self._row_tile
         rows = self.buffer_rows(n)
         scale = self.routed_scaling_factor
+        # off the TPU the grouped products run interpreted and the row moves
+        # are XLA's; on it both are kernels, where the moves take the width
+        interp = _interpret(xf._val)
+        kernel = not interp and bool(row_moves.words(self.d_model, xf._val.dtype))
 
         def score(v, wg):
             return jax.nn.sigmoid(jnp.matmul(
                 v, wg, preferred_element_type=jnp.float32))
         scores = apply(score, xf, self.gate.weight, name="moe_route")
-        plan = functools.partial(
-            _route_plan, top_k=k, lookup=self._lookup,
-            n_held=len(self.held_experts), tm=tm, rows=rows)
+
+        def plan(s, bias):
+            out = _route_plan(s, bias, top_k=k, lookup=self._lookup,
+                              n_held=len(self.held_experts), tm=tm, rows=rows)
+            return out + (row_moves.held_pairs(out[1], rows) if kernel else ())
         (idx, pair_row, row_pair, row_valid, tile_group, num_tiles,
-         counts) = apply(plan, scores.detach(), self.expert_bias,
-                         name="moe_route")
+         counts, *held) = apply(plan, scores.detach(), self.expert_bias,
+                                name="moe_route")
 
         def weigh(s, picked):
-            w = jnp.take_along_axis(s, picked, axis=1)
+            # s[t, picked[t, j]] read through a compare and a sum over the
+            # experts (one term is not zero: exact), as the plan reads its
+            # tables; its transpose is then no scatter either
+            chosen = picked[:, :, None] == jnp.arange(s.shape[1], dtype=picked.dtype)
+            w = jnp.sum(jnp.where(chosen, s[:, None, :], 0), axis=2)
             return w / (jnp.sum(w, axis=1, keepdims=True) + 1e-6) * scale
         w = apply(weigh, scores, idx, name="moe_route")
-        xs = apply(lambda v, rp, rv, pr: _gather_rows(v, rp // k, rv, pr),
-                   xf, row_pair, row_valid, pair_row, name="moe_route")
-
-        interp = _interpret(xs._val)
+        xs = apply(lambda v, rp, rv, pr, nt, *hp: _gather_rows(
+            v, rp, rv, pr, hp, nt, tm, kernel),
+            xf, row_pair, row_valid, pair_row, num_tiles, *held, name="moe_route")
 
         def product(a, wts, tg, nt):
             return grouped_matmul(a, wts, tg, nt, tm, interp)
@@ -377,8 +470,10 @@ class DroplessMoELayer(nn.Layer):
             apply(product, xs, self.w1, tile_group, num_tiles, name="moe_experts"),
             apply(product, xs, self.w3, tile_group, num_tiles, name="moe_experts"))
         y = apply(product, h, self.w2, tile_group, num_tiles, name="moe_experts")
-        out = apply(_combine_rows, y, w, pair_row, row_pair, row_valid,
-                    name="moe_combine").reshape(shape)
+        out = apply(lambda yv, wv, pr, rp, rv, nt, *hp: _combine_rows(
+            yv, wv, pr, rp, rv, hp, nt, tm, kernel),
+            y, w, pair_row, row_pair, row_valid, num_tiles, *held,
+            name="moe_combine").reshape(shape)
         return out, apply(lambda c: c.astype(jnp.float32), counts, name="moe_route")
 
     def record_load(self, load):

@@ -241,3 +241,40 @@ def test_grouped_products_compile_at_lfm2_widths(one_chip, k, n):
     _assert_kernel(gm.gmm.lower(x, w, tiles, used).compile(), 1)
     _assert_kernel(gm.gmm.lower(dy, w, tiles, used, transpose_w=True).compile(), 1)
     _assert_kernel(gm.tgmm.lower(x, dy, tiles, used, groups=groups).compile(), 1)
+
+
+@pytest.mark.parametrize("move", ["pack", "gather", "weighted-rows", "add-back",
+                                  "combine", "pair-dots"])
+def test_row_moves_compile_at_lfm2_widths(one_chip, move):
+    """The expert layer's row moves at the cell's shapes: a buffer of 34,816
+    rows of 2048 bf16, 8192 tokens, 4 picks, tiles of 256; the grid or the
+    loop bound a run-time value. A refusal by Mosaic (a slice off the tiling,
+    scalar memory, VMEM) fails here and not on the chip."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import row_moves as rm
+    n, k, h, dt = 2 * 4096, 4, 2048, jnp.bfloat16
+    rows = n * k + 8 * gm.ROW_TILE
+    per_row = rm.words(h, dt) // 128
+    i32 = jnp.int32
+    tokens3, rows3 = (_sds((r * per_row, 128), jnp.uint32, one_chip) for r in (n, rows))
+    row_pair, tile_rows = _sds((rows,), i32, one_chip), _sds((rows // gm.ROW_TILE,), i32, one_chip)
+    used, pair_row = _sds((), i32, one_chip), _sds((n, k), i32, one_chip)
+    w = _sds((n, k), jnp.float32, one_chip)
+    held = (_sds((n * k,), i32, one_chip), _sds((n * k,), i32, one_chip),
+            _sds((n // rm.TOKEN_BLOCK + 1,), i32, one_chip))
+    lowered = {
+        "pack": lambda: rm.pack_rows.lower(_sds((rows, h), dt, one_chip), used),
+        "gather": lambda: rm.rows_from_tokens.lower(
+            tokens3, row_pair, tile_rows, used, k=k, h=h, dtype=dt),
+        "weighted-rows": lambda: rm.rows_from_tokens.lower(
+            tokens3, row_pair, tile_rows, used, w, k=k, h=h, dtype=dt),
+        "add-back": lambda: rm.tokens_from_rows.lower(rows3, pair_row, held, h=h, dtype=dt),
+        "combine": lambda: rm.tokens_from_rows.lower(rows3, pair_row, held, w, h=h, dtype=dt),
+        "pair-dots": lambda: rm.pair_dots.lower(rows3, pair_row, held, _sds((n, h), dt, one_chip)),
+    }[move]()
+    compiled = lowered.compile()
+    _assert_kernel(compiled, 1)
+    want = {"pack": (rows * per_row, 128), "gather": (rows, h), "weighted-rows": (rows, h),
+            "add-back": (n, h), "combine": (n, h), "pair-dots": (n, k)}[move]
+    assert compiled.out_info.shape == want
