@@ -168,9 +168,11 @@ def phase_static_executor(paddle):
 def phase_train(paddle, seed, cache_events):
     import jax
     from paddle_tpu.ops import autotune
+    from paddle_tpu.profiler import metrics
     from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
     paddle.seed(seed)
+    attention_before = metrics.get_registry().snapshot()["counters"]
     cfg = GPTConfig.gpt3_1p3b(dropout=0.0, max_position_embeddings=SEQ)
     cfg.num_layers = ONE_CHIP_LAYERS
     model = GPTForCausalLM(cfg)
@@ -211,31 +213,30 @@ def phase_train(paddle, seed, cache_events):
          step_seconds=float(np.median(seconds[3:])),
          step_seconds_min=min(seconds[3:]), step_seconds_max=max(seconds[3:]))
 
-    # which attention the compiled step holds: the measured fusion policy
-    # (ops/autotune.py) timed flash against the XLA reference on this chip;
-    # flash may lose that measurement, but it may not fail, and the compiled
-    # text must agree with the decision
+    # which attention the compiled step holds: ops/attention.takes_flash
+    # chooses from the shapes (XLA's attention under FLASH_MIN_SEQ_K keys),
+    # the counters say which path the traces took, and the compiled text
+    # must agree with them
     text, mem = step_program(step, x, y)
     kernels = text.count("tpu_custom_call")
+    counters = metrics.get_registry().snapshot()["counters"]
+    took = {name: counters.get("attention.%s_total" % name, 0)
+            - attention_before.get("attention.%s_total" % name, 0)
+            for name in ("flash", "xla")}
+    check((took["flash"] > 0) != (took["xla"] > 0),
+          f"the step's attention took both paths or none: {took}")
+    path = "flash" if took["flash"] else "xla"
     tuner = autotune.get_tuner()
-    decisions = {k: v for k, v in tuner.decisions().items()
-                 if "flash_attention" in k}
-    choice = {v for k, v in decisions.items()
-              if k.startswith("fusion.flash_attention|")}
-    check(len(choice) == 1, f"attention met the fusion policy as {decisions}")
-    (choice,) = choice
-    emit("attention_path", choice=choice,
-         path={"fused": "pallas flash attention",
-               "unfused": "xla reference (flash measured slower)"}[choice],
+    emit("attention_path", path=path, calls_traced=took,
          tpu_custom_calls_in_compiled_step=kernels,
-         flash_kernels_if_fused=3 * cfg.num_layers,
-         autotune_decisions=decisions,
+         flash_kernels_if_flash=3 * cfg.num_layers,
+         autotune_decisions=tuner.decisions(),
          autotune_times_seconds=tuner.last_times,
          autotune_counters=autotune.counters(),
          autotune_first_failure=tuner.first_failure,
          autotune_cache_dir=autotune.default_cache_dir())
-    check(kernels == (3 * cfg.num_layers if choice == "fused" else 0),
-          f"policy chose {choice} but the compiled step holds {kernels} kernels")
+    check(kernels == (3 * cfg.num_layers if path == "flash" else 0),
+          f"attention took the {path} path but the compiled step holds {kernels} kernels")
     check(autotune.counters()["candidate_failures"] == 0,
           f"an autotune candidate failed on the chip: {tuner.first_failure}")
     stats = jax.devices()[0].memory_stats()

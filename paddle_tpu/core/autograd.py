@@ -184,8 +184,7 @@ def backward(tensors, grad_tensors=None, retain_graph=False,
         cots = []
         for i, meta in enumerate(node.out_meta):
             if meta is None:
-                # None output slot (empty pytree leaf, e.g. GPTBlock's
-                # carried residual before the first layer): its cotangent
+                # None output slot (empty pytree leaf): its cotangent
                 # is None to match the forward's output structure
                 cots.append(None)
                 continue

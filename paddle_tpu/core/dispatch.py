@@ -177,9 +177,9 @@ def _apply_impl(prim, args, kwargs, name):
     # no node to record
     if not any(_is_diff_value(o) for o in outs):
         return _wrap_outputs(out, stop_gradient=True)
-    # None outputs (jax treats None as an empty pytree subtree — e.g.
-    # GPTBlock's (stream, pending=None) carried-residual form under
-    # recompute) pass through: no meta, no Tensor, None cotangent slot
+    # None outputs (jax treats None as an empty pytree subtree — e.g. a
+    # block under recompute that returns (stream, None)) pass through: no
+    # meta, no Tensor, None cotangent slot
     out_meta = [None if o is None else (o.shape, o.dtype) for o in outs]
     node = GradNode(
         vjp_fn=vjp_fn,

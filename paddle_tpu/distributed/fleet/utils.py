@@ -85,9 +85,6 @@ def recompute(function, *args, **kwargs):
     _TraceHooks.on_write = on_write
     # a tensor the body makes is no state of the closure
     _TraceHooks.on_create = lambda t: seen.add(id(t))
-    from ...ops import autotune as _autotune_disc
-    _prev_dir = _autotune_disc._FORCE_DIRECTION[0]
-    _autotune_disc._FORCE_DIRECTION[0] = "fwd_bwd"
     try:
         with _autograd.no_grad():
             jax.eval_shape(
@@ -96,7 +93,6 @@ def recompute(function, *args, **kwargs):
                 *[jax.ShapeDtypeStruct(t._val.shape, t._val.dtype)
                   for t in tensor_args])
     finally:
-        _autotune_disc._FORCE_DIRECTION[0] = _prev_dir
         (_TraceHooks.on_read, _TraceHooks.on_write,
          _TraceHooks.on_create) = prev
         for t, old in written.values():
@@ -123,13 +119,6 @@ def recompute(function, *args, **kwargs):
                 prev_write(t, new_value)
 
         _TraceHooks.on_write = on_write
-        # the body runs under no_grad yet the region IS differentiated (the
-        # outer apply wraps the checkpoint in jax.vjp), so tell the fusion
-        # policy this is fwd+bwd — grad-mode inspection alone would
-        # misclassify it as inference and pick fwd-tuned paths
-        from ...ops import autotune as _autotune
-        prev_dir = _autotune._FORCE_DIRECTION[0]
-        _autotune._FORCE_DIRECTION[0] = "fwd_bwd"
         try:
             for t, v in zip(bound, vals[n_args:]):
                 t._val = v
@@ -141,12 +130,10 @@ def recompute(function, *args, **kwargs):
             # intact, remat uses its rule as designed
             with _autograd.no_grad():
                 out = function(*rebuild(vals[:n_args]), **kwargs)
-            # tuple-returning blocks (e.g. GPTBlock's carried-residual
-            # (stream, pending) form) unwrap leaf-wise; jax.checkpoint and
-            # apply() both handle pytree outputs
+            # tuple-returning blocks unwrap leaf-wise; apply() handles
+            # pytree outputs, a None leaf among them
             return jax.tree_util.tree_map(unwrap, out)
         finally:
-            _autotune._FORCE_DIRECTION[0] = prev_dir
             _TraceHooks.on_write = prev_write
             for t, old in written.values():
                 t._val = old

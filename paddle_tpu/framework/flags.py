@@ -42,13 +42,9 @@ _FLAGS: dict[str, Any] = {
     # step/h2d). The loader's exact-resume cursor only advances when a batch
     # is actually consumed, so checkpoint/resume stays exact.
     "FLAGS_input_prefetch": True,
-    # kernel tier (paddle_tpu/ops/autotune.py, docs/kernels.md):
-    # measured fusion policy — auto dispatches whichever of fused/unfused
-    # measured faster per (shape-bucket, dtype, direction, placement);
-    # always/never force one side for debugging and A/B runs
-    "FLAGS_fusion_policy": "auto",
-    # master switch for the Pallas block-size / fusion search; off-device
-    # runs never search regardless (deterministic fallback table)
+    # kernel tier (paddle_tpu/ops/autotune.py, docs/kernels.md): master
+    # switch for the Pallas block-size search; off-device runs never search
+    # regardless (each kernel's deterministic fallback tiles)
     "FLAGS_autotune": True,
     # resilience subsystem (paddle_tpu/resilience, docs/resilience.md)
     # fault-injection spec, e.g. "fs.upload:0.3,collective.all_reduce:0.1"

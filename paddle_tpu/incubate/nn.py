@@ -192,10 +192,7 @@ class FusedMultiHeadAttention(nn.Layer):
         out = self.dropout(self.out_proj(out))
         if self.normalize_before:
             return residual + out
-        # post-LN residual write through the fused residual+LN op (same
-        # wiring as nn.TransformerEncoderLayer)
-        from ..ops.fused_residual_ln import post_residual_ln
-        return post_residual_ln(residual, out, self.norm)
+        return self.norm(residual + out)
 
 
 class FusedFeedForward(nn.Layer):

@@ -83,17 +83,23 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
     ndim_norm = len(tuple(normalized_shape))
 
     def prim(v, *wb):
+        # statistics and the affine in float32 whatever the input's type
+        # (the reference kernel's LayerNormParamType; bf16 statistics lose
+        # three decimal digits over a 2048-element row), the output in the
+        # input's type
         axes = tuple(range(v.ndim - ndim_norm, v.ndim))
-        mean = jnp.mean(v, axis=axes, keepdims=True)
-        var = jnp.var(v, axis=axes, keepdims=True)
-        out = (v - mean) * jax.lax.rsqrt(var + epsilon)
+        f = jnp.promote_types(v.dtype, jnp.float32)
+        vf = v.astype(f)
+        mean = jnp.mean(vf, axis=axes, keepdims=True)
+        var = jnp.var(vf, axis=axes, keepdims=True)
+        out = (vf - mean) * jax.lax.rsqrt(var + epsilon)
         i = 0
         if weight is not None:
-            out = out * wb[i]
+            out = out * wb[i].astype(f)
             i += 1
         if bias is not None:
-            out = out + wb[i]
-        return out
+            out = out + wb[i].astype(f)
+        return out.astype(v.dtype)
 
     args = [a for a in (weight, bias) if a is not None]
     return apply(prim, x, *args, name="layer_norm")

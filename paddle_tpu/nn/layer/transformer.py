@@ -23,17 +23,6 @@ __all__ = ["MultiHeadAttention", "TransformerEncoderLayer",
            "TransformerDecoder", "Transformer"]
 
 
-def _post_residual_ln(residual, sub, norm):
-    """Post-LN residual write through the fused residual+LN op (backward
-    recovers x_hat from the LN output, so the summed pre-norm tensor never
-    crosses the fwd->bwd boundary; reference analog
-    operators/fused/fused_bias_dropout_residual_layer_norm_op.cu). Shared
-    by the encoder AND decoder layers; PADDLE_TPU_FUSED_RESIDUAL_LN=0
-    falls back to the plain composition (ops/fused_residual_ln.py)."""
-    from ...ops.fused_residual_ln import post_residual_ln
-    return post_residual_ln(residual, sub, norm)
-
-
 def _convert_attn_mask(attn_mask, dtype):
     if attn_mask is None:
         return None
@@ -155,8 +144,7 @@ class TransformerEncoderLayer(Layer):
         if self.normalize_before:
             src = residual + self.dropout1(src)
         else:
-            src = _post_residual_ln(residual, self.dropout1(src),
-                                    self.norm1)
+            src = self.norm1(residual + self.dropout1(src))
         residual = src
         if self.normalize_before:
             src = self.norm2(src)
@@ -164,8 +152,7 @@ class TransformerEncoderLayer(Layer):
         if self.normalize_before:
             src = residual + self.dropout2(src)
         else:
-            src = _post_residual_ln(residual, self.dropout2(src),
-                                    self.norm2)
+            src = self.norm2(residual + self.dropout2(src))
         return src if cache is None else (src, cache)
 
     def gen_cache(self, src):
@@ -256,7 +243,7 @@ class TransformerDecoderLayer(Layer):
         if self.normalize_before:
             tgt = residual + self.dropout1(tgt)
         else:
-            tgt = _post_residual_ln(residual, self.dropout1(tgt), self.norm1)
+            tgt = self.norm1(residual + self.dropout1(tgt))
         residual = tgt
         if self.normalize_before:
             tgt = self.norm2(tgt)
@@ -269,7 +256,7 @@ class TransformerDecoderLayer(Layer):
         if self.normalize_before:
             tgt = residual + self.dropout2(tgt)
         else:
-            tgt = _post_residual_ln(residual, self.dropout2(tgt), self.norm2)
+            tgt = self.norm2(residual + self.dropout2(tgt))
         residual = tgt
         if self.normalize_before:
             tgt = self.norm3(tgt)
@@ -277,7 +264,7 @@ class TransformerDecoderLayer(Layer):
         if self.normalize_before:
             tgt = residual + self.dropout3(tgt)
         else:
-            tgt = _post_residual_ln(residual, self.dropout3(tgt), self.norm3)
+            tgt = self.norm3(residual + self.dropout3(tgt))
         if cache is None:
             return tgt
         return tgt, (incremental_cache, static_cache)

@@ -1,9 +1,12 @@
 """Attention kernels.
 
 Reference parity: operators/fused/fused_attention_op.cu + fmha_ref.h. TPU-native
-design: one XLA attention path (softmax fused by XLA) + a Pallas
-flash-attention kernel (ops/pallas/flash_attention.py) selected for TPU when
-shapes allow; both behind one functional entry point.
+design: one XLA attention path (softmax fused by XLA) and a Pallas
+flash-attention kernel pair (ops/pallas/flash_attention.py) behind one
+functional entry point. Which of the two a call runs is `takes_flash`, a
+rule of the operands' shapes, the mask, the dropout and the platform and of
+nothing measured at run time: the same code on the same shapes stages the
+same program in every process (docs/kernels.md, "Which kernel runs").
 """
 from __future__ import annotations
 
@@ -15,9 +18,17 @@ import jax.numpy as jnp
 from ..core.dispatch import apply, unwrap
 from ..profiler import metrics as _metrics
 
-# the measured probe of the XLA side holds its float32 scores, their softmax
-# and both gradients at once: about four score tensors
-PROBE_SCORE_TENSORS = 4
+# Key length from which the flash pair is taken: the smallest of 1024, 2048
+# and 4096 at which its device time, forward and backward, was under XLA's
+# attention by more than the runs' spread (docs/kernels.md has the table).
+FLASH_MIN_SEQ_K = 2048
+# under this many query positions the s^2 buffers are small and XLA's fused
+# softmax attention is the faster (v5e: BERT s=128 151k -> 121k tok/s under
+# flash)
+FLASH_MIN_SEQ_Q = 256
+# XLA's attention holds its float32 scores, their softmax and both gradients
+# at once: about four score tensors
+XLA_SCORE_TENSORS = 4
 
 
 def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, dropout_key):
@@ -54,7 +65,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     """(batch, seq, heads, head_dim) attention. `key` and `value` may hold
     fewer heads than `query`, a divisor of its count (grouped-query
     attention): query head h reads key/value head h // (heads / kv_heads).
-    The path a call took is counted: `attention.flash_total`,
+    `use_pallas=None` lets `takes_flash` choose the path from the shapes;
+    True or False is the caller's own choice (True with a mask or dropout
+    raises). The path a call took is counted: `attention.flash_total`,
     `attention.xla_total` (docs/observability.md)."""
     qv = unwrap(query)
     if qv.shape[2] % unwrap(key).shape[2]:
@@ -72,26 +85,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         dropout_p = 0.0
 
     if use_pallas is None:
-        # auto-select flash only where it wins: at s<=128 the s^2 buffers
-        # are small, XLA's fused softmax attention is faster than the tiled
-        # kernel (measured on v5e: BERT s=128 151k -> 121k tok/s under
-        # flash; GPT s=1024 37.1k -> 45.6k under flash)
-        use_pallas = (_pallas_available() and attn_mask is None
-                      and dropout_p == 0.0
-                      and qv.shape[1] >= 256
-                      and _pallas_supports(query, key))
-        if use_pallas:
-            # measured fusion policy: flash is the "fused" candidate, the
-            # XLA softmax path the "unfused" one. never forces XLA; auto
-            # keeps flash only while it measures faster for this signature
-            # (docs/kernels.md)
-            from . import autotune
-            pol = autotune.fusion_policy()
-            if pol == "never":
-                use_pallas = False
-            elif pol == "auto" and _scores_fit_a_probe(qv, unwrap(key)):
-                use_pallas = _flash_wins(qv, unwrap(key), unwrap(value),
-                                         is_causal, scale)
+        use_pallas = takes_flash(qv.shape, unwrap(key).shape, qv.dtype,
+                                 attn_mask is not None, dropout_p,
+                                 _platform())
     elif use_pallas and (attn_mask is not None or dropout_p > 0.0):
         raise ValueError(
             "use_pallas=True is incompatible with attn_mask/dropout_p: the "
@@ -182,36 +178,29 @@ def _flash_bwd(is_causal, scale, interpret, res, g):
 _flash_attention_diff.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_wins(qv, kv, vv, is_causal, scale):
-    """Measured fusion-policy decision for flash attention: probe the Pallas
-    kernel pair against the XLA softmax path for this (shape-bucket, dtype,
-    direction) signature. The checked-in fallback table keeps flash for all
-    benched signatures (every OPBENCH flash row is >1x), so off-device this
-    is a no-op 'fused' answer."""
-    from . import autotune
-    prim_flash = _flash_prim(qv, is_causal, scale)
-
-    def prim_xla(q, k, v):
-        return _xla_attention(q, k, v, None, scale, is_causal, 0.0, None)
-
-    _, choice = autotune.choose_fused(
-        "flash_attention", prim_flash, prim_xla, (qv, kv, vv),
-        module="paddle_tpu.ops.pallas.flash_attention")
-    return choice == "fused"
+def takes_flash(q_shape, k_shape, dtype, masked, dropout_p, platform):
+    """Whether attention over operands of these (batch, seq, heads, head_dim)
+    shapes runs the flash kernel pair: on a TPU, plain or causal attention
+    (no mask, no dropout) of a floating type over shapes the kernels tile,
+    from FLASH_MIN_SEQ_Q query positions, where the keys are at least
+    FLASH_MIN_SEQ_K long or XLA's attention could not hold its scores.
+    Everything else runs XLA's attention."""
+    if (platform != "tpu" or masked or dropout_p > 0.0
+            or q_shape[1] < FLASH_MIN_SEQ_Q
+            or not jnp.issubdtype(dtype, jnp.floating)
+            or not _pallas_supports(q_shape, k_shape)):
+        return False
+    return k_shape[1] >= FLASH_MIN_SEQ_K or not _xla_holds_its_scores(
+        q_shape, k_shape)
 
 
-def _scores_fit_a_probe(qv, kv):
-    """Whether the XLA candidate can be measured at all: its float32 score
-    tensor (batch, heads, seq_q, seq_k), four times over for the probe's
-    forward and backward, has to fit in half the device's memory beside
-    whatever the program already holds. Where it does not, the flash kernel
-    is the only candidate that runs and is taken unmeasured
-    (`attention.probe_skipped_total`)."""
-    scores = 4 * qv.shape[0] * qv.shape[2] * qv.shape[1] * kv.shape[1]
-    fits = PROBE_SCORE_TENSORS * scores <= _device_memory_bytes() // 2
-    if not fits:
-        _metrics.get_registry().inc_counter("attention.probe_skipped_total")
-    return fits
+def _xla_holds_its_scores(q_shape, k_shape):
+    """Whether the XLA path's float32 score tensor (batch, heads, seq_q,
+    seq_k), XLA_SCORE_TENSORS times over for its forward and backward, fits
+    in half the device's memory beside whatever the program already holds
+    (LFM2's 2 x 32 heads x 4096^2 does not: 4.3 GB a tensor)."""
+    scores = 4 * q_shape[0] * q_shape[2] * q_shape[1] * k_shape[1]
+    return XLA_SCORE_TENSORS * scores <= _device_memory_bytes() // 2
 
 
 @functools.lru_cache(maxsize=1)
@@ -220,11 +209,11 @@ def _device_memory_bytes():
     return stats.get("bytes_limit", 16 * 2 ** 30)
 
 
-def _pallas_supports(query, key):
+def _pallas_supports(q_shape, k_shape):
     from .pallas.flash_attention import supports
-    return supports(tuple(query.shape), tuple(key.shape))
+    return supports(tuple(q_shape), tuple(k_shape))
 
 
 @functools.lru_cache(maxsize=1)
-def _pallas_available():
-    return jax.devices()[0].platform == "tpu"
+def _platform():
+    return jax.devices()[0].platform

@@ -1,30 +1,23 @@
-"""Block-size autotuning and a measured fusion policy for the kernel tier.
+"""Block-size autotuning for the kernel tier.
 
-Two services for the Pallas/fused-op layer (ISSUE 5 tentpole):
+``Autotuner`` is a per-(op, signature) candidate search (ISSUE 5 tentpole).
+Candidates are timed on device with ``jax.block_until_ready`` (warmup
+excluded) and the winner is memoised in-process and persisted to an on-disk
+cache (``PADDLE_TPU_AUTOTUNE_CACHE``, default ``<checkout>/.autotune_cache``;
+atomic tmp+``os.replace`` writes like ``FileStore.put``) so steady-state runs
+pay zero search cost.  Cache keys carry a kernel-source hash so editing a
+kernel invalidates its stale tuned configs.  On CPU/interpret (tier-1 tests)
+the search never runs: callers get a deterministic fallback and the disk
+cache is left untouched.
 
-* ``Autotuner`` — a per-(op, signature) candidate search.  Candidates are
-  timed on device with ``jax.block_until_ready`` (warmup excluded) and the
-  winner is memoised in-process and persisted to an on-disk cache
-  (``PADDLE_TPU_AUTOTUNE_CACHE``, default ``<checkout>/.autotune_cache``;
-  atomic tmp+``os.replace`` writes like ``FileStore.put``) so steady-state runs pay zero search cost.  Cache keys
-  carry a kernel-source hash so editing a kernel invalidates its stale tuned
-  configs.  On CPU/interpret (tier-1 tests) the search never runs: callers
-  get a deterministic fallback and the disk cache is left untouched.
+Its one user is the Pallas kernels' tile search (ops/pallas/flash_attention.py).
+Which kernel an op runs is not searched: that is a rule of shapes and
+platform, stated where the op is (docs/kernels.md, "Which kernel runs").
 
-* A *measured fusion policy* — each fused op registers its fused and unfused
-  candidates through :func:`choose_fused`; under ``FLAGS_fusion_policy=auto``
-  the dispatcher runs whichever side measured faster for the live
-  (shape-bucket, dtype, direction, placement) signature.  A fused path that
-  loses (e.g. fused_ffn bf16 fwd, 0.551x in OPBENCH r5) automatically falls
-  back to the unfused XLA composition.  Off-device the decision comes from
-  ``_POLICY_FALLBACK``, seeded with the checked-in OPBENCH.json losers, so
-  CPU behaviour is deterministic and matches what auto would pick on TPU.
-
-Searches are driven from op entry points *before* ``dispatch.apply`` wraps
-everything in ``jax.vjp`` tracing: when the incoming values are tracers
-(to_static / recompute) the probe synthesises concrete arrays of the same
-shape/dtype, so tuning still happens exactly once per signature even for
-fully staged programs.
+Searches are reached from inside traces too (to_static, recompute): the
+caller's ``make_args`` builds concrete probe arrays and the search runs on a
+fresh thread, so tuning happens once per signature for staged programs as
+well.
 """
 from __future__ import annotations
 
@@ -38,7 +31,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 # ---------------------------------------------------------------------------
 # counters (test/observability seam; profiler counter events ride on top)
@@ -50,8 +42,6 @@ _COUNTERS = {
     "disk_hits": 0,      # persistent-cache hits (zero-search steady state)
     "fallbacks": 0,      # unsearchable placements served the fallback table
     "cache_errors": 0,   # corrupt/torn cache files ignored and rebuilt
-    "policy_fused": 0,   # fusion-policy decisions that kept the fused path
-    "policy_unfused": 0,  # fusion-policy decisions that fell back to unfused
 }
 
 
@@ -194,36 +184,13 @@ def measure(fn, args, warmup=1, reps=3):
     return best
 
 
-def _synth_args(raw_args):
-    """Concrete stand-ins for a probe run: tracers (to_static / recompute /
-    vjp staging) are replaced by fixed-seed host-generated arrays of the same
-    shape/dtype; already-concrete operands pass through untouched."""
-    rng = np.random.default_rng(0)
-    out = []
-    for a in raw_args:
-        shape = getattr(a, "shape", None)
-        dtype = getattr(a, "dtype", None)
-        if shape is None or dtype is None:
-            out.append(a)
-            continue
-        if not isinstance(a, jax.core.Tracer):
-            out.append(jnp.asarray(a))
-            continue
-        if jnp.issubdtype(dtype, jnp.inexact):
-            host = rng.standard_normal(shape, dtype=np.float32)
-            out.append(jnp.asarray(host).astype(dtype))
-        else:
-            out.append(jnp.zeros(shape, dtype))
-    return out
-
-
 # ---------------------------------------------------------------------------
 # the tuner
 
 def _outside_any_trace(fn):
     """Run fn() where no jax trace is active. Searches are reached from
-    inside traces too (the fused probe is a jit whose trace asks for the
-    kernel's blocks), and there every call on concrete arrays would only be
+    inside traces too (a to_static step's trace asks for the kernel's
+    blocks), and there every call on concrete arrays would only be
     staged — the "timing" would be the time to stage it. Trace state is per
     thread, so a fresh thread executes for real."""
     with concurrent.futures.ThreadPoolExecutor(max_workers=1) as pool:
@@ -342,120 +309,3 @@ def set_tuner(tuner):
     old = _TUNER[0]
     _TUNER[0] = tuner
     return old
-
-
-# ---------------------------------------------------------------------------
-# measured fusion policy
-
-# Deterministic decisions for unsearchable placements (CPU / interpret /
-# tier-1), seeded from the checked-in OPBENCH.json (TPU v5 lite, r5): every
-# (op, dtype, direction) whose fused path measured *slower* than the unfused
-# XLA composition routes unfused; everything else stays fused.
-_POLICY_FALLBACK = {
-    ("fused_ffn", "bf16", "fwd"): "unfused",           # 0.551x
-    ("fused_ffn", "f32", "fwd_bwd"): "unfused",        # 0.939x
-    ("fused_conv_bn", "bf16", "fwd"): "unfused",       # 0.995x
-    ("fused_conv_bn", "bf16", "fwd_bwd"): "unfused",   # 0.995x
-    ("fused_conv_bn", "f32", "fwd_bwd"): "unfused",    # 1.000x wash, strictly slower
-    ("fused_residual_ln", "bf16", "fwd_bwd"): "unfused",  # 0.975x
-}
-
-# Ambient direction hint: recompute() differentiates its region even though
-# the traced body runs under no_grad(), so grad-mode inspection alone would
-# misclassify it as inference. fleet.utils.recompute sets this to "fwd_bwd"
-# around the traced call.
-_FORCE_DIRECTION = [None]
-
-
-def fusion_policy():
-    from ..framework.flags import get_flag
-    pol = str(get_flag("FLAGS_fusion_policy", "auto") or "auto").lower()
-    if pol not in ("auto", "always", "never"):
-        raise ValueError(
-            "FLAGS_fusion_policy must be auto|always|never, got %r" % pol)
-    return pol
-
-
-def auto_winner(fused_ms, unfused_ms):
-    """Strict measured winner: fused dispatches only when it is not slower."""
-    return "fused" if fused_ms <= unfused_ms else "unfused"
-
-
-def policy_table_choice(op, dtype_short, direction):
-    return _POLICY_FALLBACK.get((op, dtype_short, direction), "fused")
-
-
-def current_direction():
-    if _FORCE_DIRECTION[0] is not None:
-        return _FORCE_DIRECTION[0]
-    from ..core import autograd
-    return "fwd_bwd" if autograd.is_grad_enabled() else "fwd"
-
-
-def _grad_probe(fn, raw_args):
-    """Jitted fwd+bwd probe: grad of a scalar reduction of fn's outputs with
-    respect to every inexact operand — what the op costs inside a train
-    step, which is the regime the policy is choosing for."""
-    argnums = tuple(
-        i for i, a in enumerate(raw_args)
-        if getattr(a, "dtype", None) is not None
-        and jnp.issubdtype(a.dtype, jnp.inexact))
-
-    def loss(*args):
-        outs = fn(*args)
-        return sum(jnp.sum(o.astype(jnp.float32))
-                   for o in jax.tree_util.tree_leaves(outs))
-
-    if not argnums:
-        return jax.jit(fn)
-    return jax.jit(jax.grad(loss, argnums=argnums))
-
-
-def choose_fused(op, fused_prim, unfused_prim, raw_args, *, module=None):
-    """Pick the fused or unfused primitive for this call.
-
-    raw_args are the unwrapped (jax-level) operands — possibly tracers.
-    Returns (prim, choice) where choice is "fused" | "unfused". The decision
-    is recorded as a fusion_policy/<op> profiler counter (1 = fused).
-    """
-    pol = fusion_policy()
-    if pol == "always":
-        choice = "fused"
-    elif pol == "never":
-        choice = "unfused"
-    else:
-        choice = _auto_choice(op, fused_prim, unfused_prim, raw_args, module)
-    _COUNTERS["policy_fused" if choice == "fused" else "policy_unfused"] += 1
-    _record("fusion_policy/%s" % op, 1.0 if choice == "fused" else 0.0)
-    return (fused_prim if choice == "fused" else unfused_prim), choice
-
-
-def _auto_choice(op, fused_prim, unfused_prim, raw_args, module):
-    lead = raw_args[0]
-    dt = short_dtype(lead.dtype)
-    direction = current_direction()
-    fallback = policy_table_choice(op, dt, direction)
-    tuner = get_tuner()
-    if not tuner.searchable():
-        # skip signature/string assembly on the hot eager path off-device
-        _COUNTERS["fallbacks"] += 1
-        return fallback
-    bucket = "x".join(str(d) for d in shape_bucket(lead.shape))
-    sig = "%s|%s|%s|%s" % (bucket, dt, direction,
-                           device_platform(*raw_args))
-    version = source_version(module) if module else ""
-
-    def build(cand):
-        fn = fused_prim if cand == "fused" else unfused_prim
-        if direction == "fwd_bwd":
-            return _grad_probe(fn, raw_args)
-        return jax.jit(fn)
-
-    def make_args():
-        return _synth_args(raw_args)
-
-    # the fused side must build and run where a search is possible: losing a
-    # measurement is fine, failing is not an answer of "unfused"
-    return tuner.get("fusion.%s" % op, sig, candidates=("fused", "unfused"),
-                     build=build, make_args=make_args, fallback=fallback,
-                     version=version, required=("fused",))

@@ -213,21 +213,10 @@ def fused_conv_bn(x, weight, bn_weight, bn_bias, running_mean=None,
         return _fused_conv_bn_diff(xv, wv, gv, bv, stride_t, pad_n,
                                    dil_t, groups, dn, epsilon, act_input)
 
-    if _gamma_degenerate(bn_weight):
-        # zero/near-zero gamma channels: plain autodiff through the same
-        # forward math (saves the conv output z as a residual, but keeps
-        # dgamma exact where the custom backward would freeze it)
-        prim = prim_plain
-    else:
-        # measured fusion policy (ops/autotune.py): plain autodiff of the
-        # identical forward is the unfused candidate
-        from ..core.dispatch import unwrap
-        from . import autotune
-        prim, _ = autotune.choose_fused(
-            "fused_conv_bn", prim_fused, prim_plain,
-            (unwrap(x), unwrap(weight), unwrap(bn_weight), unwrap(bn_bias)),
-            module="paddle_tpu.ops.fused_conv_bn")
-
+    # zero/near-zero gamma channels: plain autodiff through the same
+    # forward math (saves the conv output z as a residual, but keeps
+    # dgamma exact where the custom backward would freeze it)
+    prim = prim_plain if _gamma_degenerate(bn_weight) else prim_fused
     out, mean_t, var_t = apply(prim, x, weight, bn_weight, bn_bias,
                                name="fused_conv_bn")
 

@@ -22,8 +22,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..core.dispatch import apply, unwrap
-from . import autotune
+from ..core.dispatch import apply
 
 __all__ = ["fused_ffn"]
 
@@ -108,22 +107,8 @@ def fused_ffn(x, w1, b1, w2, b2, activation="gelu"):
 
     x: (..., d_model); w1: (d_model, d_ff); w2: (d_ff, d_model);
     activation: gelu | gelu_tanh | relu.
-
-    The measured fusion policy (ops/autotune.py, FLAGS_fusion_policy) picks
-    between this custom-vjp path and the plain composition per signature —
-    OPBENCH r5 measured the fused path 0.551x in bf16 fwd, so auto routes
-    that signature unfused.
     """
-    def prim_fused(xv, w1v, b1v, w2v, b2v):
+    def prim(xv, w1v, b1v, w2v, b2v):
         return _fused_ffn_diff(xv, w1v, b1v, w2v, b2v, activation)
 
-    def prim_unfused(xv, w1v, b1v, w2v, b2v):
-        # same math, per-op autodiff residual plan (saves a = f(h))
-        f, _ = _act_fns(activation)
-        return jnp.dot(f(jnp.dot(xv, w1v) + b1v), w2v) + b2v
-
-    prim, _ = autotune.choose_fused(
-        "fused_ffn", prim_fused, prim_unfused,
-        (unwrap(x), unwrap(w1), unwrap(b1), unwrap(w2), unwrap(b2)),
-        module="paddle_tpu.ops.fused_ffn")
     return apply(prim, x, w1, b1, w2, b2, name="fused_ffn")

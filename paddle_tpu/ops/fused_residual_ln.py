@@ -28,6 +28,12 @@ tol band, it routes through plain autodiff of the identical forward math
 abstract and the custom path runs — compile zero-LN-scale recipes with
 this in mind (both branches return identical shapes, so a recompute
 discovery/trace disagreement cannot change program structure).
+
+No model in the tree calls this op: in the GPT cell the plain composition
+(`x + y`, then `nn.LayerNorm`) measured 0.45% more tokens a second and no
+more memory than this backward (PERF.md, PR 30), so GPT and the
+transformer layers take that. The op stays for callers of its own;
+ROADMAP D4 has its deletion.
 """
 from __future__ import annotations
 
@@ -38,32 +44,9 @@ import jax.numpy as jnp
 
 from ..core.dispatch import apply
 
-__all__ = ["fused_residual_ln", "fuse_enabled", "post_residual_ln"]
-
-
-def post_residual_ln(residual, sub, norm):
-    """Post-LN residual write: norm(residual + sub) through the fused op —
-    the public seam the transformer layers (nn + incubate) share. Falls
-    back to the plain composition when the norm has no affine params or
-    the fusion is disabled (fuse_enabled)."""
-    if norm.weight is None or norm.bias is None or not fuse_enabled():
-        return norm(residual + sub)
-    return fused_residual_ln(residual, sub, norm.weight, norm.bias,
-                             epsilon=norm._epsilon)
+__all__ = ["fused_residual_ln"]
 
 _W_TOL = 1e-6
-
-
-def fuse_enabled():
-    """Escape hatch for the op's hot-path wirings (GPTBlock,
-    TransformerEncoderLayer post-LN): PADDLE_TPU_FUSED_RESIDUAL_LN=0 routes
-    them through the plain residual+norm composition — the regime for
-    zero-init LN-scale recipes compiled under jit, where the eager
-    degenerate-weight guard cannot inspect the traced weight (same
-    contract as fused_conv_bn's PADDLE_TPU_FUSED_CONV_BN=0). Read at
-    trace time, baked into the compiled program."""
-    import os
-    return os.environ.get("PADDLE_TPU_FUSED_RESIDUAL_LN", "1") == "1"
 
 
 def _stats(zf, eps):
@@ -162,18 +145,8 @@ def fused_residual_ln(x, y, weight, bias, epsilon=1e-5,
         return _fused_residual_ln_diff(xv, yv, wv, bv, epsilon,
                                        return_residual, stream_dtype)
 
-    if _weight_degenerate(weight):
-        # zero/near-zero LN weight channels: plain autodiff through the
-        # IDENTICAL forward (saves z, keeps dw exact where the custom
-        # backward's x_hat reconstruction would freeze it)
-        prim = prim_plain
-    else:
-        # measured fusion policy (ops/autotune.py): the plain composition is
-        # the unfused candidate — same math, per-op autodiff residual plan
-        from . import autotune
-        prim, _ = autotune.choose_fused(
-            "fused_residual_ln", prim_fused, prim_plain,
-            (unwrap(x), unwrap(y), unwrap(weight), unwrap(bias)),
-            module="paddle_tpu.ops.fused_residual_ln")
-
+    # zero/near-zero LN weight channels: plain autodiff through the
+    # IDENTICAL forward (saves z, keeps dw exact where the custom
+    # backward's x_hat reconstruction would freeze it)
+    prim = prim_plain if _weight_degenerate(weight) else prim_fused
     return apply(prim, x, y, weight, bias, name="fused_residual_ln")

@@ -163,51 +163,19 @@ class GPTBlock(nn.Layer):
         self.mlp = GPTMLP(cfg)
         self.dropout = nn.Dropout(cfg.dropout)
 
-    def forward(self, x, pending=None, cache=None):
-        """Carried-residual form: the stream value entering this block is
-        x + pending (pending = the previous block's MLP branch output, not
-        yet added). Each residual add is materialized inside
-        ops/fused_residual_ln.py together with the LayerNorm that consumes
-        it, so the summed (b, s, h) stream tensors never cross the
-        fwd->bwd boundary (reference analog: the residual+LN epilogues of
-        operators/fused/fused_attention_op.cu /
-        fused_bias_dropout_residual_layer_norm_op.cu). Returns
-        (stream, pending_mlp_out) — GPTModel folds the last pending into
-        ln_f the same way. PADDLE_TPU_FUSED_RESIDUAL_LN=0 restores the
-        plain composition (zero-init LN-scale recipes under jit — see
-        ops/fused_residual_ln.fuse_enabled).
-
-        With ``cache`` (incremental decode) the return grows to
-        (stream, pending, new_cache); the 2-tuple arity is unchanged for
-        every existing caller."""
-        from ...ops.fused_residual_ln import fused_residual_ln, fuse_enabled
-        has_cache = cache is not None
-        if not fuse_enabled():
-            if pending is not None:
-                x = x + pending
-            a = self.attn(self.ln1(x), cache=cache)
-            if has_cache:
-                a, cache = a
-            x = x + self.dropout(a)
-            x = x + self.mlp(self.ln2(x))
-            return (x, None, cache) if has_cache else (x, None)
-        if pending is None:
-            x1, h1 = x, self.ln1(x)
-        else:
-            x1, h1 = fused_residual_ln(x, pending, self.ln1.weight,
-                                       self.ln1.bias,
-                                       epsilon=self.ln1._epsilon,
-                                       return_residual=True)
-        a = self.attn(h1, cache=cache)
-        if has_cache:
+    def forward(self, x, cache=None):
+        """Pre-LN block in the plain composition: LayerNorm, attention and
+        the residual add, LayerNorm, MLP and the residual add, each an op
+        of its own that XLA fuses into the matmul beside it (PERF.md, PR
+        30: faster in cell 1 than the fused residual+LN op and no more
+        memory). With ``cache`` (incremental decode) returns
+        (stream, new_cache)."""
+        a = self.attn(self.ln1(x), cache=cache)
+        if cache is not None:
             a, cache = a
-        a = self.dropout(a)
-        x2, h2 = fused_residual_ln(x1, a, self.ln2.weight, self.ln2.bias,
-                                   epsilon=self.ln2._epsilon,
-                                   return_residual=True)
-        if has_cache:
-            return x2, self.mlp(h2), cache
-        return x2, self.mlp(h2)
+        x = x + self.dropout(a)
+        x = x + self.mlp(self.ln2(x))
+        return x if cache is None else (x, cache)
 
 
 class GPTModel(nn.Layer):
@@ -248,25 +216,19 @@ class GPTModel(nn.Layer):
                 jnp.arange(past, past + s, dtype=jnp.int32)[None, :])
         x = self.wte(input_ids) + self.wpe(position_ids)
         x = self.drop(x)
-        pending = None
         if caches is not None:
             new_caches = []
             for block, c in zip(self.h, caches):
-                x, pending, c = block(x, pending, cache=c)
+                x, c = block(x, cache=c)
                 new_caches.append(c)
         elif self.config.recompute and self.training:
             from ...distributed.fleet.utils import recompute as _ckpt
             for block in self.h:
-                x, pending = _ckpt(block, x, pending)
+                x = _ckpt(block, x)
         else:
             for block in self.h:
-                x, pending = block(x, pending)
-        if pending is None:
-            h = self.ln_f(x)
-        else:
-            from ...ops.fused_residual_ln import fused_residual_ln
-            h = fused_residual_ln(x, pending, self.ln_f.weight,
-                                  self.ln_f.bias, epsilon=self.ln_f._epsilon)
+                x = block(x)
+        h = self.ln_f(x)
         if caches is not None:
             return h, new_caches
         return h

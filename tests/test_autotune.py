@@ -1,22 +1,18 @@
-"""Autotune cache + measured fusion policy (ISSUE 5 tentpole + satellite).
+"""Autotune cache (ISSUE 5 tentpole).
 
 Covers: search picks the measured winner and persists it; a warm cache
 (second tuner = second process) performs ZERO timed searches; corrupt/torn
 cache files are ignored and rebuilt; a kernel-source-hash bump invalidates
 stale entries; unsearchable placements (CPU/interpret — this suite) get the
 deterministic fallback without timing anything; on a searchable placement a
-failing candidate is counted, an all-fail search and a failing fused
-candidate raise; FLAGS_fusion_policy
-auto/always/never routing and the profiler counter event.
+failing candidate is counted, an all-fail search and a failing required
+candidate raise. Which kernel an op runs is tests/test_kernel_choice.py.
 """
 import json
-import os
 
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.framework.flags import set_flags
 from paddle_tpu.ops import autotune
 from paddle_tpu.ops.autotune import AutotuneError, Autotuner
 
@@ -237,138 +233,6 @@ class TestSignatureHelpers:
         v1 = autotune.source_version("paddle_tpu.ops.pallas.flash_attention")
         v2 = autotune.source_version("paddle_tpu.ops.pallas.flash_attention")
         assert v1 == v2 and v1 != "unknown" and len(v1) == 12
-
-
-class TestFusionPolicy:
-    @pytest.fixture(autouse=True)
-    def _restore_policy(self):
-        yield
-        set_flags({"FLAGS_fusion_policy": "auto"})
-
-    def _ffn_args(self, dtype="float32"):
-        rng = np.random.RandomState(0)
-        mk = lambda shape: paddle.to_tensor(
-            rng.randn(*shape).astype("float32")).astype(dtype)
-        return (mk((4, 8)), mk((8, 16)), mk((16,)), mk((16, 8)),
-                mk((8,)))
-
-    def test_auto_cpu_uses_fallback_table(self):
-        from paddle_tpu.core import autograd
-        from paddle_tpu.ops.fused_ffn import fused_ffn
-        with autograd.no_grad():  # direction = fwd
-            y32 = fused_ffn(*self._ffn_args("float32"))
-            c_after_f32 = autotune.counters()
-            assert c_after_f32["policy_fused"] == 1  # f32 fwd stays fused
-            ybf = fused_ffn(*self._ffn_args("bfloat16"))
-        c = autotune.counters()
-        assert c["policy_unfused"] == 1  # bf16 fwd: the 0.551x loser
-        assert y32.shape == [4, 8] and ybf.shape == [4, 8]
-
-    def test_auto_direction_split(self):
-        # bf16 fused_ffn: fwd routes unfused (0.551x), fwd_bwd stays fused
-        # (1.007x) — same op+dtype, different direction
-        from paddle_tpu.ops.fused_ffn import fused_ffn
-        args = self._ffn_args("bfloat16")
-        for a in args[1:]:
-            a.stop_gradient = False
-        y = fused_ffn(*args)  # grad enabled -> fwd_bwd
-        assert autotune.counters()["policy_fused"] == 1
-        y.astype("float32").sum().backward()
-        assert args[1].grad is not None
-
-    def test_always_and_never_force(self):
-        from paddle_tpu.core import autograd
-        from paddle_tpu.ops.fused_ffn import fused_ffn
-        set_flags({"FLAGS_fusion_policy": "always"})
-        with autograd.no_grad():
-            fused_ffn(*self._ffn_args("bfloat16"))
-        assert autotune.counters()["policy_fused"] == 1
-        set_flags({"FLAGS_fusion_policy": "never"})
-        with autograd.no_grad():
-            fused_ffn(*self._ffn_args("float32"))
-        assert autotune.counters()["policy_unfused"] == 1
-
-    def test_policy_parity_fused_vs_unfused(self):
-        # both candidates compute the same math: forcing either side gives
-        # the same numbers (the policy can never change results)
-        from paddle_tpu.ops.fused_ffn import fused_ffn
-        outs = {}
-        for pol in ("always", "never"):
-            set_flags({"FLAGS_fusion_policy": pol})
-            outs[pol] = np.asarray(fused_ffn(*self._ffn_args())._value)
-        np.testing.assert_allclose(outs["always"], outs["never"],
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_failing_fused_candidate_raises_on_searchable_placement(
-            self, tmp_path):
-        """A fused path the compiler refuses must not become the answer
-        "unfused": on a searchable placement choose_fused raises."""
-        import jax.numpy as jnp
-
-        def fused(x):
-            raise RuntimeError("Mosaic: kernel refused")
-
-        def unfused(x):
-            return x * 2
-
-        old = autotune.set_tuner(Autotuner(
-            cache_dir=str(tmp_path), searchable=lambda: True,
-            measure_fn=lambda fn, args: (fn(*args), 1.0)[1]))
-        try:
-            with pytest.raises(AutotuneError, match="required candidate "
-                                                    "'fused'"):
-                autotune.choose_fused("toy_op", fused, unfused,
-                                      (jnp.ones((4, 4)),))
-            assert autotune.counters()["candidate_failures"] == 1
-            assert autotune.counters()["policy_unfused"] == 0
-        finally:
-            autotune.set_tuner(old)
-
-    def test_measured_slower_fused_may_still_lose(self, tmp_path):
-        import jax.numpy as jnp
-        times = iter([2.0, 1.0])   # fused, unfused
-        old = autotune.set_tuner(Autotuner(
-            cache_dir=str(tmp_path), searchable=lambda: True,
-            measure_fn=lambda fn, args: next(times)))
-        try:
-            _, choice = autotune.choose_fused(
-                "toy_op", lambda x: x + 1, lambda x: x + 1,
-                (jnp.ones((4, 4)),))
-            assert choice == "unfused"
-            (rec,) = autotune.get_tuner().last_times.values()
-            assert rec == {"'fused'": 2.0, "'unfused'": 1.0}
-        finally:
-            autotune.set_tuner(old)
-
-    def test_invalid_policy_raises(self):
-        set_flags({"FLAGS_fusion_policy": "sometimes"})
-        with pytest.raises(ValueError):
-            autotune.fusion_policy()
-
-    def test_decision_recorded_as_profiler_counter(self, monkeypatch):
-        from paddle_tpu import profiler
-        from paddle_tpu.core import autograd
-        from paddle_tpu.ops.fused_ffn import fused_ffn
-        events = []
-        monkeypatch.setattr(profiler, "record_counter",
-                            lambda name, value, ts_us=None:
-                            events.append((name, value)))
-        with autograd.no_grad():
-            fused_ffn(*self._ffn_args("bfloat16"))
-        assert ("fusion_policy/fused_ffn", 0.0) in events
-
-    def test_recompute_direction_hint(self):
-        # inside recompute the body runs under no_grad yet _FORCE_DIRECTION
-        # makes policy decisions use fwd_bwd (the region IS differentiated)
-        assert autotune.current_direction() in ("fwd", "fwd_bwd")
-        prev = autotune._FORCE_DIRECTION[0]
-        autotune._FORCE_DIRECTION[0] = "fwd_bwd"
-        try:
-            from paddle_tpu.core import autograd
-            with autograd.no_grad():
-                assert autotune.current_direction() == "fwd_bwd"
-        finally:
-            autotune._FORCE_DIRECTION[0] = prev
 
 
 class TestFlashBlockFallbacks:

@@ -207,9 +207,9 @@ def test_amp_keeps_stream_dtype_promotes_norm_only():
     assert str(z.dtype).endswith("bfloat16"), z.dtype
 
 
-def test_gpt_block_carried_residual_matches_composition():
-    """GPTBlock's (stream, pending) form must equal the plain
-    x + attn(ln1(x)); x + mlp(ln2(x)) composition."""
+def test_gpt_block_matches_the_plain_composition():
+    """GPTBlock is the plain x + attn(ln1(x)); x + mlp(ln2(x)) composition,
+    and its layer norms are nn.LayerNorm's own op."""
     from paddle_tpu.text.models.gpt import GPTBlock, GPTConfig
 
     paddle.seed(0)
@@ -219,20 +219,17 @@ def test_gpt_block_carried_residual_matches_composition():
     block = GPTBlock(cfg)
     rng = np.random.RandomState(0)
     x = paddle.to_tensor(rng.randn(2, 8, 64).astype("float32"))
-    p = paddle.to_tensor(rng.randn(2, 8, 64).astype("float32"))
 
-    stream, pending = block(x, p)
-    got = (stream + pending).numpy()
+    got = block(x).numpy()
 
-    z = x + p
-    h = z + block.dropout(block.attn(block.ln1(z)))
+    h = x + block.dropout(block.attn(block.ln1(x)))
     ref = (h + block.mlp(block.ln2(h))).numpy()
     np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
 
 
 def test_gpt_model_trains_and_recompute_matches():
-    """End-to-end GPT fwd/bwd with the fused stream; recompute=True (the
-    carried pair flows through jax.checkpoint) must match recompute=False."""
+    """End-to-end GPT fwd/bwd; recompute=True (each block a rematerialised
+    region) must match recompute=False."""
     from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
     rng = np.random.RandomState(0)
@@ -258,32 +255,40 @@ def test_gpt_model_trains_and_recompute_matches():
     np.testing.assert_allclose(g0, g1, rtol=1e-4, atol=1e-6)
 
 
-def test_kill_switch_restores_plain_composition(monkeypatch):
-    """PADDLE_TPU_FUSED_RESIDUAL_LN=0 must route GPTBlock and the post-LN
-    encoder through the plain residual+norm composition (the documented
-    regime for zero-init LN-scale recipes under jit)."""
-    from paddle_tpu.text.models.gpt import GPTBlock, GPTConfig
+def test_models_never_reach_the_fused_op(monkeypatch):
+    """No switch is left to throw: GPT and the post-LN encoder layer run
+    the plain residual+norm composition and never reach the fused op,
+    whatever PADDLE_TPU_FUSED_RESIDUAL_LN says."""
+    import paddle_tpu.nn as nn
+    from paddle_tpu.ops import fused_residual_ln as op
+    from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
-    monkeypatch.setenv("PADDLE_TPU_FUSED_RESIDUAL_LN", "0")
-    paddle.seed(0)
-    cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
-                    num_heads=2, max_position_embeddings=16, dropout=0.0,
-                    use_flash_attention=False)
-    block = GPTBlock(cfg)
-    rng = np.random.RandomState(0)
-    x = paddle.to_tensor(rng.randn(2, 4, 32).astype("float32"))
-    p = paddle.to_tensor(rng.randn(2, 4, 32).astype("float32"))
-    stream, pending = block(x, p)
-    assert pending is None  # plain composition returns the folded stream
-    z = x + p
-    h = z + block.dropout(block.attn(block.ln1(z)))
-    ref = (h + block.mlp(block.ln2(h))).numpy()
-    np.testing.assert_allclose(stream.numpy(), ref, rtol=2e-5, atol=2e-5)
+    def never(*args, **kwargs):
+        raise AssertionError("a model called ops.fused_residual_ln")
+    monkeypatch.setattr(op, "fused_residual_ln", never)
+    monkeypatch.setattr(op, "_fused_residual_ln_diff", never)
+    ids = paddle.to_tensor(
+        np.random.RandomState(0).randint(0, 64, (2, 8)).astype("int32"))
+    x = paddle.to_tensor(
+        np.random.RandomState(1).randn(2, 4, 32).astype("float32"))
+    losses = []
+    for setting in ("0", "1"):
+        monkeypatch.setenv("PADDLE_TPU_FUSED_RESIDUAL_LN", setting)
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
+            max_position_embeddings=16, dropout=0.0))
+        loss = model(ids, labels=ids)
+        loss.backward()
+        encoder = nn.TransformerEncoderLayer(32, 2, 64, dropout=0.0)
+        losses.append((float(loss.numpy()), encoder(x).numpy()))
+    assert losses[0][0] == losses[1][0]
+    np.testing.assert_array_equal(losses[0][1], losses[1][1])
 
 
 def test_decoder_layer_post_ln_matches_manual():
-    """TransformerDecoderLayer's three post-LN residual writes through the
-    fused op equal the manual composition."""
+    """TransformerDecoderLayer's three post-LN residual writes equal the
+    manual composition."""
     import paddle_tpu.nn as nn
 
     paddle.seed(0)

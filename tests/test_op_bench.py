@@ -88,42 +88,13 @@ class TestOpbenchDiff:
             capture_output=True, text=True, timeout=240)
 
     def test_checked_in_artifact_passes(self):
-        # acceptance: under auto, no measured-slower path is dispatched in
-        # the committed OPBENCH.json
+        # with no older artifact to compare with, the gate reads the rows
+        # and finds nothing to fail
         p = self._run(REPO / "OPBENCH.json")
         assert p.returncode == 0, p.stdout + p.stderr
         report = json.loads(p.stdout)
-        assert report["status"] == "ok" and report["policy_failures"] == []
+        assert report["status"] == "ok" and report["regressions"] == []
         assert report["rows"] >= 16
-
-    def test_dispatched_loser_fails(self, tmp_path):
-        doc = json.loads((REPO / "OPBENCH.json").read_text())
-        for row in doc["ops"]:
-            if row["op"] == "fused_ffn" and row["speedup"] < 1.0:
-                row["policy_choice"] = "fused"  # the regression class
-        bad = tmp_path / "BAD.json"
-        bad.write_text(json.dumps(doc))
-        p = self._run(bad)
-        assert p.returncode == 1
-        report = json.loads(p.stdout)
-        assert report["policy_failures"]
-        assert {f["op"] for f in report["policy_failures"]} == {"fused_ffn"}
-
-    def test_always_policy_pins_losers_and_fails(self, tmp_path):
-        # legacy rows (no policy_choice) + FLAGS_fusion_policy=always:
-        # the gate derives the pinned-fused choice and flags every loser
-        doc = json.loads((REPO / "OPBENCH.json").read_text())
-        for row in doc["ops"]:
-            row.pop("policy_choice", None)
-        legacy = tmp_path / "LEGACY.json"
-        legacy.write_text(json.dumps(doc))
-        env = {**__import__("os").environ,
-               "FLAGS_fusion_policy": "always", "JAX_PLATFORMS": "cpu"}
-        p = subprocess.run(
-            [sys.executable, str(REPO / "tools/opbench_diff.py"), str(legacy)],
-            capture_output=True, text=True, timeout=240, env=env)
-        assert p.returncode == 1
-        assert json.loads(p.stdout)["policy_failures"]
 
     def test_regression_vs_old_fails(self, tmp_path):
         doc = json.loads((REPO / "OPBENCH.json").read_text())
@@ -134,12 +105,13 @@ class TestOpbenchDiff:
         p = self._run(REPO / "OPBENCH.json", old)
         assert p.returncode == 1
         report = json.loads(p.stdout)
-        assert report["regressions"] and not report["policy_failures"]
+        assert report["status"] == "fail" and report["regressions"]
 
 
 def test_cli_smoke_mode_records_policy(tmp_path):
-    """--smoke: CI-sized one-iteration sweep; rows carry the policy columns
-    and the artifact passes its own gate."""
+    """--smoke: CI-sized one-iteration sweep; rows carry both sides' times
+    and their ratio, no column of a choice, and the artifact passes its own
+    gate."""
     out = tmp_path / "SMOKE.json"
     p = subprocess.run(
         [sys.executable, str(REPO / "tools/op_bench.py"), "--smoke",
@@ -150,9 +122,10 @@ def test_cli_smoke_mode_records_policy(tmp_path):
     assert doc["smoke"] is True
     assert len(doc["ops"]) == 2
     for row in doc["ops"]:
-        assert row["policy_choice"] in ("fused", "unfused")
-        assert row["chosen_ms"] > 0
-        assert row["effective_speedup"] >= 1.0  # auto never picks a loser
+        assert row["fused_ms"] > 0 and row["unfused_ms"] > 0
+        assert row["speedup"] == pytest.approx(
+            row["unfused_ms"] / row["fused_ms"], rel=0.01, abs=2e-3)
+        assert not {"policy_choice", "chosen_ms", "effective_speedup"} & set(row)
     p = subprocess.run(
         [sys.executable, str(REPO / "tools/opbench_diff.py"), str(out)],
         capture_output=True, text=True, timeout=240)

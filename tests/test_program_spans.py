@@ -129,7 +129,7 @@ def step_text(warm):
 
 
 @pytest.mark.parametrize("scope", ["linear", "sdpa", "layer_norm",
-                                   "fused_residual_ln", "embedding"])
+                                   "fused_ffn", "embedding"])
 def test_forward_and_backward_instructions_carry_the_op_scope(step_text, scope):
     assert f"/jvp({scope})/" in step_text
     assert f"/transpose(jvp({scope}))/" in step_text

@@ -378,15 +378,15 @@ class TestReviewRound2Fixes:
 
 class TestKernelTierAdviceR5:
     """ADVICE r5 regressions riding on the kernel-tier pass (ISSUE 5):
-    None outputs through the dispatch seam (GPTBlock's unfused branch under
-    recompute), and degen-cache invalidation on checkpoint-style writes."""
+    None outputs through the dispatch seam (a region under recompute that
+    returns one), and degen-cache invalidation on checkpoint-style writes."""
 
-    def test_gpt_recompute_with_unfused_residual_ln_trains(self, monkeypatch):
-        # high: recompute traces GPTBlock through dispatch.apply; the unfused
-        # branch returns (x, None) and out_meta used to call None.shape
+    def test_gpt_recompute_with_unfused_residual_ln_trains(self):
+        # high: recompute traces GPTBlock, the plain residual+norm
+        # composition, through dispatch.apply (its (x, None) form of the
+        # time crashed on None.shape; the seam's own test is the next)
         from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
-        monkeypatch.setenv("PADDLE_TPU_FUSED_RESIDUAL_LN", "0")
         paddle.seed(0)
         cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
                         num_heads=2, max_position_embeddings=16, dropout=0.0,

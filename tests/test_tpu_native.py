@@ -317,26 +317,6 @@ class TestFlashAttentionUnderMesh:
             np.testing.assert_allclose(np.asarray(o._val), ref, rtol=1e-5,
                                        atol=1e-6)
 
-    def test_fusion_probe_times_the_mapped_kernel(self, tmp_path):
-        """Where the policy can search (a TPU), the fused probe it times
-        must be the mesh-mapped kernel too — the bare one cannot be
-        partitioned and would fail as a required candidate."""
-        from paddle_tpu.ops import attention, autotune
-        mesh, sharding = self._mesh_sharding()
-        q, k, v = (t._val for t in self._qkv(sharding))
-        old = autotune.set_tuner(autotune.Autotuner(
-            cache_dir=str(tmp_path), searchable=lambda: True, warmup=0,
-            reps=1))
-        try:
-            attention._flash_wins(q, k, v, True, 0.125)
-            (times,) = [t for key, t in
-                        autotune.get_tuner().last_times.items()
-                        if key.startswith("fusion.flash_attention|")]
-            assert set(times) == {"'fused'", "'unfused'"}
-            assert autotune.counters()["candidate_failures"] == 0
-        finally:
-            autotune.set_tuner(old)
-
     def test_single_device_program_sees_no_mesh(self):
         from paddle_tpu.distributed.mesh import operand_mesh, trace_mesh
         import jax

@@ -44,7 +44,6 @@ SUBSYSTEMS = [
     "decode",        # continuous-batching decode (serving/decode/)
     "dispatch",      # the op dispatch seam (core/dispatch.py)
     "disagg",        # disaggregated prefill/decode (serving/disagg.py)
-    "fusion_policy", # measured fusion decisions
     "integrity",     # SDC defense (checksum consensus, replay)
     "io",            # input pipeline / data workers
     "metrics",       # the registry/exporter's own health
@@ -72,7 +71,6 @@ UNITS = ["bytes", "count", "ms", "per_sec", "ratio", "sec", "total", "us"]
 # must pass the pattern instead.
 GRANDFATHERED = [
     "autotune.search/{}",   # per-op search counter (slash-namespaced)
-    "fusion_policy/{}",     # per-op fused/unfused decision
     "straggler.rank{}",     # value is a ratio; name predates unit suffixes
     "{}.{}",                # serving export_to_profiler re-emits snapshot
                             # keys under a caller prefix; the source names

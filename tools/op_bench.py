@@ -289,18 +289,6 @@ CASES = {
 }
 
 
-def _policy_choice(fused_ms, unfused_ms):
-    """Which side the measured fusion policy (paddle_tpu/ops/autotune.py)
-    would dispatch for this row under the current FLAGS_fusion_policy."""
-    from paddle_tpu.ops.autotune import auto_winner, fusion_policy
-    pol = fusion_policy()
-    if pol == "always":
-        return "fused"
-    if pol == "never":
-        return "unfused"
-    return auto_winner(fused_ms, unfused_ms)
-
-
 def run(filter_=None, dtypes=("bf16", "f32"), small=False, iters=5,
         inner=10):
     import jax
@@ -321,24 +309,16 @@ def run(filter_=None, dtypes=("bf16", "f32"), small=False, iters=5,
                 unfused_ms = max(_timed(unfused_fn, args, iters, inner),
                                  1e-6)
                 speedup = unfused_ms / fused_ms
-                choice = _policy_choice(fused_ms, unfused_ms)
-                chosen_ms = fused_ms if choice == "fused" else unfused_ms
                 rows.append({
                     "op": name, "dtype": dtype, "direction": direction,
                     "shape": case["shape"],
                     "fused_ms": round(fused_ms, 6),
                     "unfused_ms": round(unfused_ms, 6),
                     "speedup": round(speedup, 3),
-                    "policy_choice": choice,
-                    "chosen_ms": round(chosen_ms, 6),
-                    # what the dispatcher actually delivers vs the unfused
-                    # baseline once the policy picks this row's winner
-                    "effective_speedup": round(unfused_ms / chosen_ms, 3),
                 })
                 print(f"[op_bench] {name:18s} {dtype:4s} {direction:7s} "
                       f"fused {fused_ms:8.3f} ms  unfused {unfused_ms:8.3f} "
-                      f"ms  x{speedup:.2f}  -> {choice}", file=sys.stderr,
-                      flush=True)
+                      f"ms  x{speedup:.2f}", file=sys.stderr, flush=True)
     return {"device": jax.devices()[0].device_kind,
             "small": small, "ops": rows}
 
