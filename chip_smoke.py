@@ -229,13 +229,13 @@ def phase_train(paddle, seed, cache_events):
     tuner = autotune.get_tuner()
     emit("attention_path", path=path, calls_traced=took,
          tpu_custom_calls_in_compiled_step=kernels,
-         flash_kernels_if_flash=3 * cfg.num_layers,
+         flash_kernels_if_flash=2 * cfg.num_layers,
          autotune_decisions=tuner.decisions(),
          autotune_times_seconds=tuner.last_times,
          autotune_counters=autotune.counters(),
          autotune_first_failure=tuner.first_failure,
          autotune_cache_dir=autotune.default_cache_dir())
-    check(kernels == (3 * cfg.num_layers if path == "flash" else 0),
+    check(kernels == (2 * cfg.num_layers if path == "flash" else 0),
           f"attention took the {path} path but the compiled step holds {kernels} kernels")
     check(autotune.counters()["candidate_failures"] == 0,
           f"an autotune candidate failed on the chip: {tuner.first_failure}")

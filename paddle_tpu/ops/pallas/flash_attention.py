@@ -1,33 +1,66 @@
-"""Flash attention (Pallas/TPU) — forward AND backward kernels.
+"""Flash attention (Pallas/TPU): one forward kernel and one backward kernel.
 
 Reference analog: operators/fused/fused_attention_op.cu + fmha_ref.h (cuDNN
-FMHA fwd/bwd). TPU-native: online-softmax tiled attention in VMEM — O(S)
-memory instead of the O(S^2) probability matrix; the MXU does the q@k^T and
-p@v matmuls per tile. Causal masking skips fully-masked k-tiles via the grid.
+FMHA fwd/bwd). TPU-native: online-softmax tiled attention in VMEM, O(S)
+memory instead of the O(S^2) probability matrix; the MXU does every product
+of a tile pair. Tiles the causal mask zeroes are never visited, and only the
+tile pairs the diagonal crosses build the mask: the pairs wholly under it run
+a body with no iota, compare or select.
 
-Backward follows the FlashAttention-2 recompute scheme: the forward saves
-only the per-row logsumexp L; the backward re-forms each P tile from
-(q, k, L) in VMEM and accumulates
-    dV_j += P_ij^T dO_i
-    dS_ij = P_ij * (dO_i V_j^T - D_i),   D = rowsum(dO * O)
-    dK_j += dS_ij^T (q_i * scale)
-    dQ_i += dS_ij (k_j * scale)
-in two kernels (dkv over k-tiles, dq over q-tiles) so no tile ever needs
-atomics. Head dims of 64 are supported (VMEM pads the lane dim; the
-s^2-materializing XLA fallback costs far more than the padding).
+Both kernels form every tile TRANSPOSED, keys down the sublanes and queries
+along the lanes. The per-row statistics (the running maximum and sum, the
+logsumexp L, D = rowsum(dO * O)) are then lane-dense rows: they enter and
+leave HBM with the sequence in the lane dimension ((B*H, 1, S) float32; no
+128-lane copy of them is ever written), their maxima and sums run down the
+sublanes, and they broadcast over a tile for nothing. The products whose
+result is d wide are formed transposed too, (d, block) = x^T @ tile from the
+transposed key or value tile, so no (block_k, block_q) tile is ever
+transposed on the chip; XLA transposes k, v tile by tile on the way in and
+the output and dq on the way out, inside the (B, S, H, D) <-> (B*H, S, D)
+copies it already makes.
+
+Forward, for query block i over the key tiles j at or under the diagonal:
+    S^T = k_j q_i^T * scale,  online softmax down the sublanes,
+    O_i^T = sum_j v_j^T P_ij^T / l_i,   L_i = m_i + log l_i
+
+Backward follows the FlashAttention-2 recompute scheme in ONE pass: the
+forward saves only L; for key tile j the backward loops over the query heads
+that read it and over their query blocks i at or under the diagonal,
+re-forms each tile once and adds
+    P^T  = exp(k_j q_i^T * scale - L_i)
+    dV_j += P^T dO_i
+    dS^T = P^T * (v_j dO_i^T - D_i)
+    dK_j += dS^T q_i * scale
+    dQ_i^T += k_j^T dS^T * scale
+five products a tile pair. dK_j and dV_j accumulate in float32 over the loop
+and over the group's query heads and are written once, in k's and v's dtype.
+dQ^T accumulates in a float32 VMEM scratch that stays resident while the key
+tiles go by (the key-tile grid axis is sequential) and is written after the
+last one. Operands reach the MXU in the input dtype and accumulate in
+float32; P and dS are cast to the input dtype just before their products;
+maxima, sums, the exponential, L, D and every accumulator are float32. The
+scale is folded into the key tile (into q in the forward) where that is
+exact, a power of two, and multiplies S in float32 otherwise.
 
 Layout: inputs (B, S, H, D) paddle convention; kernels work on (B*H, S, D).
+Head dims of 64 are supported (VMEM pads the lane dim of a (block, 64) tile;
+every product then fills half of the 128 x 128 MXU: docs/kernels.md).
 
-Grouped-query attention: k and v may hold H / group heads. Row b*H + h of q
-then reads row (b*H + h) // group of k and v, which the block specs' index
-maps say, so no repeated copy of k or v is ever written. The dkv pass still
-runs per query head and writes float32 partial dk, dv of (B*H, S, D), which
-one XLA reduction adds over each group: 2 x B*H*S*D*4 bytes written and read
-once more, for a kernel that stays free of cross-instance accumulation.
+Grouped-query attention: k and v may hold H / group heads. Rows
+r * group ... r * group + group - 1 of q read row r of k and v, which the
+block specs say, so no repeated copy of k or v is ever written and dk, dv
+come out at (B*H / group, S, D).
+
+How much of dQ is resident is a rule of shapes (`_bwd_q_span`): where a
+group's q, dO and dQ over the whole sequence pass VMEM_RESIDENT_BYTES, the
+query range is cut into spans, a grid axis outside the key tiles; each span
+then writes float32 partial dk, dv of (spans, B*H / group, S, D) that one XLA
+sum adds. The cell's and the models' shapes hold one span.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,8 +71,8 @@ from jax.experimental.pallas import tpu as pltpu
 # ms/iter fwd+bwd at b4/s1024/h16/d64): bigger MXU matmuls, fewer inner-loop
 # trips. Public entry points clamp to the sequence length, so short-seq
 # callers (BERT s=128) degrade gracefully to seq-sized blocks. These are the
-# f32 deterministic fallbacks; on TPU the autotuner (ops/autotune.py)
-# searches the candidate grids below and caches the winner per signature.
+# deterministic fallbacks; on TPU the autotuner (ops/autotune.py) searches
+# the candidate grids below and caches the winner per signature.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
@@ -48,29 +81,19 @@ _FWD_CANDIDATES = (
     (512, 512), (256, 512), (512, 256), (256, 256), (1024, 512),
 )
 
-# Bwd candidates: (block_q_dkv, block_k_dkv, block_q_dq, block_k_dq) — the
-# dkv pass tiles k (parallel) and loops q (reduction); the dq pass tiles q
-# and loops k. The two passes have different working sets, so their blocks
-# tune independently (ISSUE 5 tentpole).
-_BWD_CANDIDATES = (
-    (512, 512, 512, 512),
-    (256, 512, 512, 256),
-    (512, 256, 256, 512),
-    (256, 256, 256, 256),
-    (128, 512, 512, 128),
-)
+# Bwd candidates: (block_q, block_k) of the one-pass kernel, which tiles k
+# over the grid and loops q. The same grid until a measurement parts them.
+_BWD_CANDIDATES = _FWD_CANDIDATES
 
+# What a group's q, dO (double-buffered), dQ block and float32 dQ scratch may
+# take of a v5e's 128 MiB of VMEM before the query range is cut into spans;
+# the rest is the key tiles, the (block_k, block_q) float32 intermediates and
+# the compiler's own.
+VMEM_RESIDENT_BYTES = 48 * 2 ** 20
+VMEM_LIMIT_CAP = 100 * 2 ** 20
 
-def _bwd_default_blocks(dtype):
-    """bf16-aware deterministic fallback for the backward blocks. The f32
-    P/dS intermediates of shape (block_q, block_k) dominate backward VMEM
-    and do NOT shrink with bf16 inputs, so for bf16 we halve the
-    reduction-loop tile of each pass (q for dkv, k for dq) while keeping
-    the parallel-axis tile at 512 for MXU depth. f32 keeps the measured
-    512/512 blocks."""
-    if jnp.dtype(dtype) == jnp.bfloat16:
-        return (256, 512, 512, 256)
-    return (512, 512, 512, 512)
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_MASKED = -1e30
 
 
 def _interpret(x=None):
@@ -85,71 +108,106 @@ def _interpret(x=None):
     return jax.default_backend() != "tpu"
 
 
-def _tpu_params(interpret, n_grid):
-    """Mosaic compiler params marking every grid axis parallel — each grid
-    instance writes its own output tile with no cross-instance dependency,
-    so the (bh, tiles) axes can be scheduled freely. Skipped under the
-    interpreter (no Mosaic)."""
+def _tpu_params(interpret, semantics, vmem_bytes=None):
+    """Mosaic compiler params: the grid axes' semantics ("parallel" where
+    each instance writes its own output tile, "arbitrary" for an axis an
+    accumulator stays resident over) and, where the kernel's blocks pass the
+    default scoped limit, a VMEM limit that follows the shapes. Skipped under
+    the interpreter (no Mosaic)."""
     if interpret:
         return {}
+    limit = None
+    if vmem_bytes is not None and vmem_bytes > 12 * 2 ** 20:
+        limit = int(min(VMEM_LIMIT_CAP, vmem_bytes + 16 * 2 ** 20))
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * n_grid)}
+        dimension_semantics=tuple(semantics), vmem_limit_bytes=limit)}
+
+
+def _scale_folds(scale):
+    """Whether multiplying an operand by `scale` is exact in any floating
+    type: a power of two (0.125 at heads of 64; not 128 ** -0.5)."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _lanes(d):
+    return -(-d // 128) * 128
+
+
+def _tiles_transposed(x, block):
+    """(rows, S, D) -> (rows, S / block, D, block): each tile of `block`
+    positions transposed, the positions in the lane dimension. XLA's copy,
+    beside the (B, S, H, D) -> (B*H, S, D) one it already makes."""
+    rows, seq, d = x.shape
+    return jnp.swapaxes(x.reshape(rows, seq // block, block, d), 2, 3)
+
+
+def _tiles_restored(x_t):
+    """`_tiles_transposed` undone: (rows, tiles, D, block) -> (rows, S, D)."""
+    rows, tiles, d, block = x_t.shape
+    return jnp.swapaxes(x_t, 2, 3).reshape(rows, tiles * block, d)
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, scale, causal,
-                     block_k, seq_k):
-    # q_ref: (block_q, d); k_ref/v_ref: (seq_k, d); o_ref: (block_q, d);
-    # l_ref: (block_q, 128) logsumexp rows broadcast across the lane dim —
-    # Mosaic requires the last two block dims to be (8k, 128), so per-row
-    # scalars ride in a 128-wide lane (the official TPU flash kernels use
-    # the same MIN_BLOCK_SIZE padding)
-    block_q = q_ref.shape[0]
-    d = q_ref.shape[1]
+def _attn_fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, l_ref, *, scale, causal,
+                     block_k):
+    # q_ref: (block_q, d); k_ref: (seq_k, d); vt_ref: (seq_k / block_k, d,
+    # block_k), each value tile transposed; ot_ref: (d, block_q), the output
+    # tile transposed; l_ref: (1, block_q), the logsumexp rows lane-dense.
+    # Every tile is formed transposed, keys down the sublanes and queries
+    # along the lanes: the row statistics are lane-dense rows, their maxima
+    # and sums run down the sublanes, and they broadcast for nothing.
+    block_q, d = q_ref.shape
     q_idx = pl.program_id(1)
-    q = q_ref[:].astype(jnp.float32) * scale
+    fold = _scale_folds(scale)
+    q = q_ref[...] * scale if fold else q_ref[...]
+    num_k_blocks = vt_ref.shape[0]
 
-    m0 = jnp.full((block_q,), -1e30, dtype=jnp.float32)
-    l0 = jnp.zeros((block_q,), dtype=jnp.float32)
-    acc0 = jnp.zeros((block_q, d), dtype=jnp.float32)
-
-    num_k_blocks = seq_k // block_k
-
-    def body(kb, carry):
+    def step(kb, carry, masked):
         m_prev, l_prev, acc = carry
-        k_tile = k_ref[pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        v_tile = v_ref[pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k_tile.T, preferred_element_type=jnp.float32)
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
+        k_tile = k_ref[pl.ds(pl.multiple_of(kb * block_k, block_k), block_k), :]
+        vt_tile = vt_ref[kb]
+        s_t = jax.lax.dot_general(k_tile, q, _NT,
+                                  preferred_element_type=jnp.float32)
+        if not fold:
+            s_t = s_t * scale
+        if masked:
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -1e30)
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
+                jnp.int32, (block_k, block_q), 0)
+            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            s_t = jnp.where(q_pos >= k_pos, s_t, _MASKED)
+        m_new = jnp.maximum(m_prev, jnp.max(s_t, axis=0, keepdims=True))
+        p_t = jnp.exp(s_t - m_new)                      # (block_k, block_q)
         correction = jnp.exp(m_prev - m_new)
-        l_new = l_prev * correction + jnp.sum(p, axis=1)
-        acc = acc * correction[:, None] + jnp.dot(
-            p, v_tile, preferred_element_type=jnp.float32)
+        l_new = l_prev * correction + jnp.sum(p_t, axis=0, keepdims=True)
+        acc = acc * correction + jnp.dot(
+            vt_tile, p_t.astype(vt_tile.dtype),
+            preferred_element_type=jnp.float32)         # (d, block_q)
         return m_new, l_new, acc
 
+    carry = (jnp.full((1, block_q), _MASKED, jnp.float32),
+             jnp.zeros((1, block_q), jnp.float32),
+             jnp.zeros((d, block_q), jnp.float32))
     if causal:
-        # skip k-blocks strictly above the diagonal for this q-block
-        last_kb = jnp.minimum(
-            ((q_idx + 1) * block_q + block_k - 1) // block_k, num_k_blocks)
-        m, l, acc = jax.lax.fori_loop(0, last_kb, body, (m0, l0, acc0))
+        # k-tiles wholly under the diagonal, then the ones it crosses; the
+        # tiles wholly above it are never visited
+        clear = jnp.minimum((q_idx * block_q + 1) // block_k, num_k_blocks)
+        last = jnp.minimum(((q_idx + 1) * block_q + block_k - 1) // block_k,
+                           num_k_blocks)
+        carry = jax.lax.fori_loop(
+            0, clear, lambda kb, c: step(kb, c, False), carry)
+        carry = jax.lax.fori_loop(
+            clear, last, lambda kb, c: step(kb, c, True), carry)
     else:
-        m, l, acc = jax.lax.fori_loop(0, num_k_blocks, body, (m0, l0, acc0))
-
+        carry = jax.lax.fori_loop(
+            0, num_k_blocks, lambda kb, c: step(kb, c, False), carry)
+    m, l, acc = carry
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[:] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse = m + jnp.log(l_safe)
-    l_ref[:] = jnp.broadcast_to(lse[:, None], (block_q, 128))
+    ot_ref[...] = (acc / l_safe).astype(ot_ref.dtype)
+    l_ref[...] = m + jnp.log(l_safe)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
@@ -159,194 +217,199 @@ def _flash_fwd_bh(q, k, v, causal, scale, block_q, block_k, interpret):
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
     group = bh // k.shape[0]
-    grid = (bh, seq_q // block_q)
-    out, lse = pl.pallas_call(
+    tiles_q, tiles_k = seq_q // block_q, seq_k // block_k
+    vmem = (2 * seq_k * (_lanes(d) + d) * k.dtype.itemsize
+            + 4 * block_q * block_k * 4)
+    out_t, lse = pl.pallas_call(
         functools.partial(_attn_fwd_kernel, scale=scale, causal=causal,
-                          block_k=block_k, seq_k=seq_k),
-        grid=grid,
+                          block_k=block_k),
+        grid=(bh, tiles_q),
         interpret=interpret,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, seq_k, d), lambda b, i: (b // group, 0, 0)),
-            pl.BlockSpec((None, seq_k, d), lambda b, i: (b // group, 0, 0)),
+            pl.BlockSpec((None, tiles_k, d, block_k),
+                         lambda b, i: (b // group, 0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 128), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, None, d, block_q), lambda b, i: (b, i, 0, 0)),
+            pl.BlockSpec((None, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, seq_q, 128), jnp.float32),
+            jax.ShapeDtypeStruct((bh, tiles_q, d, block_q), q.dtype),
+            jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
-        **_tpu_params(interpret, 2),
-    )(q, k, v)
-    return out, lse[:, :, 0]
+        **_tpu_params(interpret, ("parallel", "parallel"), vmem),
+    )(q, k, _tiles_transposed(v, block_k))
+    return _tiles_restored(out_t), lse.reshape(bh, seq_q)
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 
-def _attn_bwd_dkv_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref,
-                         dk_ref, dv_ref, *, scale, causal, block_q, seq_q):
-    # k_ref/v_ref: (block_k, d) this k-tile; q_ref/do_ref: (seq_q, d);
-    # l_ref/dd_ref: (seq_q, 128) lane-broadcast rows; dk/dv: (block_k, d)
+def _attn_bwd_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref, kt_ref,
+                     dqt_ref, dk_ref, dv_ref, dqt_acc, *, scale, causal,
+                     block_q):
+    # one key tile j of one key/value head, against one span of the group's
+    # query rows. q_ref, do_ref: (group, span, d); l_ref, dd_ref: (group,
+    # span / block_q, block_q) float32, a query block a row; k_ref, v_ref,
+    # dk_ref, dv_ref: (block_k, d); kt_ref: (d, block_k), the key tile
+    # transposed; dqt_ref: (group, span / block_q, d, block_q), dq a query
+    # block transposed; dqt_acc: the same in float32, resident over the key
+    # tiles
+    group, span, d = q_ref.shape
     block_k = k_ref.shape[0]
-    d = k_ref.shape[1]
-    k_idx = pl.program_id(1)
-    k_tile = k_ref[:].astype(jnp.float32)
-    v_tile = v_ref[:].astype(jnp.float32)
+    q_off = pl.program_id(1) * span
+    j = pl.program_id(2)
+    num_q_blocks = span // block_q
+    fold = _scale_folds(scale)
+    k_tile = k_ref[...] * scale if fold else k_ref[...]
+    v_tile = v_ref[...]
+    kt_tile = kt_ref[...] * scale if fold else kt_ref[...]
 
-    dk0 = jnp.zeros((block_k, d), dtype=jnp.float32)
-    dv0 = jnp.zeros((block_k, d), dtype=jnp.float32)
-    num_q_blocks = seq_q // block_q
+    def for_each_block(fn):
+        def head(h, carry):
+            def block(i, carry):
+                fn(h, i)
+                return carry
+            return jax.lax.fori_loop(0, num_q_blocks, block, carry)
+        jax.lax.fori_loop(0, group, head, 0)
 
-    def body(qb, carry):
+    @pl.when(j == 0)
+    def _():
+        def zero(h, i):
+            dqt_acc[h, i] = jnp.zeros((d, block_q), jnp.float32)
+        for_each_block(zero)
+
+    def pair(h, i, carry, masked):
         dk, dv = carry
-        q_tile = (q_ref[pl.dslice(qb * block_q, block_q), :]
-                  .astype(jnp.float32) * scale)
-        do_tile = do_ref[pl.dslice(qb * block_q, block_q), :].astype(
-            jnp.float32)
-        l_col = l_ref[pl.dslice(qb * block_q, block_q), :][:, :1]
-        d_col = dd_ref[pl.dslice(qb * block_q, block_q), :][:, :1]
-        s = jnp.dot(q_tile, k_tile.T, preferred_element_type=jnp.float32)
-        if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -1e30)
-        p = jnp.exp(s - l_col)  # (block_q, block_k)
-        dv = dv + jnp.dot(p.T, do_tile, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do_tile, v_tile.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - d_col)
-        dk = dk + jnp.dot(ds.T, q_tile, preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q, do = q_ref[h, rows, :], do_ref[h, rows, :]
+        lse, delta = l_ref[h, pl.ds(i, 1), :], dd_ref[h, pl.ds(i, 1), :]
+        s_t = jax.lax.dot_general(k_tile, q, _NT,
+                                  preferred_element_type=jnp.float32)
+        if not fold:
+            s_t = s_t * scale
+        if masked:
+            k_pos = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            q_pos = q_off + i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            s_t = jnp.where(q_pos >= k_pos, s_t, _MASKED)
+        p_t = jnp.exp(s_t - lse)                        # (block_k, block_q)
+        dv = dv + jnp.dot(p_t.astype(do.dtype), do,
+                          preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(v_tile, do, _NT,
+                                   preferred_element_type=jnp.float32)
+        ds_t = (p_t * (dp_t - delta)).astype(q.dtype)
+        dk = dk + jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
+        dqt_acc[h, i] += jnp.dot(kt_tile, ds_t,
+                                 preferred_element_type=jnp.float32)
         return dk, dv
 
     if causal:
-        # only q-blocks at/below the diagonal see this k-tile
-        start_qb = (k_idx * block_k) // block_q
-        dk, dv = jax.lax.fori_loop(start_qb, num_q_blocks, body, (dk0, dv0))
+        # the query blocks that see this key tile: first the ones the
+        # diagonal crosses, then the ones wholly under it
+        first = jnp.minimum(
+            jnp.maximum(j * block_k - q_off, 0) // block_q, num_q_blocks)
+        clear = jnp.clip(
+            (jnp.maximum((j + 1) * block_k - 1 - q_off, 0) + block_q - 1)
+            // block_q, first, num_q_blocks)
     else:
-        dk, dv = jax.lax.fori_loop(0, num_q_blocks, body, (dk0, dv0))
+        first = clear = 0
 
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+    def head(h, carry):
+        carry = jax.lax.fori_loop(
+            first, clear, lambda i, c: pair(h, i, c, True), carry)
+        return jax.lax.fori_loop(
+            clear, num_q_blocks, lambda i, c: pair(h, i, c, False), carry)
+
+    zeros = jnp.zeros((block_k, d), jnp.float32)
+    dk, dv = jax.lax.fori_loop(0, group, head, (zeros, zeros))
+    dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        def write(h, i):
+            dq_t = dqt_acc[h, i]
+            dqt_ref[h, i] = (dq_t if fold else dq_t * scale).astype(
+                dqt_ref.dtype)
+        for_each_block(write)
 
 
-def _attn_bwd_dq_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref, dq_ref,
-                        *, scale, causal, block_k, seq_k):
-    # q_ref/do_ref/dq_ref: (block_q, d); k_ref/v_ref: (seq_k, d);
-    # l_ref/dd_ref: (block_q, 128) lane-broadcast rows
-    block_q = q_ref.shape[0]
-    d = q_ref.shape[1]
-    q_idx = pl.program_id(1)
-    q_tile = q_ref[:].astype(jnp.float32) * scale
-    do_tile = do_ref[:].astype(jnp.float32)
-    l_col = l_ref[:][:, :1]
-    d_col = dd_ref[:][:, :1]
+def _bwd_resident_bytes(group, rows, d, itemsize):
+    """VMEM a grid step of the backward holds for `rows` query rows of a
+    group: q and dO double-buffered (a row of d pads to whole 128-lane
+    tiles), the transposed dQ block double-buffered, its float32 scratch."""
+    return group * rows * (4 * _lanes(d) * itemsize + d * (2 * itemsize + 4))
 
-    dq0 = jnp.zeros((block_q, d), dtype=jnp.float32)
-    num_k_blocks = seq_k // block_k
 
-    def body(kb, dq):
-        k_tile = k_ref[pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        v_tile = v_ref[pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q_tile, k_tile.T, preferred_element_type=jnp.float32)
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -1e30)
-        p = jnp.exp(s - l_col)
-        dp = jnp.dot(do_tile, v_tile.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - d_col)
-        return dq + jnp.dot(ds, k_tile, preferred_element_type=jnp.float32)
-
-    if causal:
-        last_kb = jnp.minimum(
-            ((q_idx + 1) * block_q + block_k - 1) // block_k, num_k_blocks)
-        dq = jax.lax.fori_loop(0, last_kb, body, dq0)
-    else:
-        dq = jax.lax.fori_loop(0, num_k_blocks, body, dq0)
-
-    # dS was formed against q*scale, so the q cotangent carries the scale
-    dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
+def _bwd_q_span(group, seq_q, d, itemsize, block_q):
+    """Query rows a grid step of the backward holds resident: the whole
+    sequence where that fits VMEM_RESIDENT_BYTES, else the largest whole
+    number of query blocks that divides the sequence and fits."""
+    spans = 1
+    while (seq_q // spans > block_q and _bwd_resident_bytes(
+            group, seq_q // spans, d, itemsize) > VMEM_RESIDENT_BYTES):
+        spans += 1
+        while seq_q % (spans * block_q):
+            spans += 1
+    return seq_q // spans
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q_dkv", "block_k_dkv", "block_q_dq",
-    "block_k_dq", "interpret"))
-def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q_dkv,
-                  block_k_dkv, block_q_dq, block_k_dq, interpret):
+    "causal", "scale", "block_q", "block_k", "interpret", "q_span"))
+def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+                  interpret, q_span=None):
     # all (BH, S, D) except k, v (BH / group, S, D) and lse (BH, S); returns
-    # dq, dk, dv. The dkv and dq passes tile different sequence axes, so each
-    # takes its own (block_q, block_k) pair.
+    # dq (BH, S, D) and dk, dv (BH / group, S, D). `q_span` pins the rows
+    # resident a step (tests); None takes the rule of shapes.
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
-    group = bh // k.shape[0]
-    # per query head; float32 where a group's heads are still to be added
-    dkv_dtype = (k.dtype, v.dtype) if group == 1 else (jnp.float32,) * 2
-    # D = rowsum(dO * O): one fused elementwise+reduce pass, reads dO/O once.
-    # lse/delta ride in (bh, seq, 128) lane-broadcast form (Mosaic block
-    # constraint — see _attn_fwd_kernel note).
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    lse3 = jnp.broadcast_to(lse[:, :, None], (bh, seq_q, 128))
-    delta3 = jnp.broadcast_to(delta[:, :, None], (bh, seq_q, 128))
-
-    dkv = pl.pallas_call(
-        functools.partial(_attn_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q_dkv, seq_q=seq_q),
-        grid=(bh, seq_k // block_k_dkv),
+    rows_kv, seq_k = k.shape[:2]
+    group = bh // rows_kv
+    span = q_span or _bwd_q_span(group, seq_q, d, q.dtype.itemsize, block_q)
+    spans, blocks = seq_q // span, span // block_q
+    # D = rowsum(dO * O), float32: a product of two bf16 is exact in float32
+    delta = jnp.einsum("rsd,rsd->rs", do, o, precision="highest",
+                       preferred_element_type=jnp.float32)
+    # a query block a row, (BH, S) -> (BH, spans, blocks, block_q): no copy
+    stats = [x.reshape(bh, spans, blocks, block_q) for x in (lse, delta)]
+    # one span: dk, dv leave in k's, v's dtype; more: float32 partials
+    part = (k.dtype, v.dtype) if spans == 1 else (jnp.float32,) * 2
+    vmem = (_bwd_resident_bytes(group, span, d, q.dtype.itemsize)
+            + 10 * block_k * _lanes(d) * k.dtype.itemsize
+            + 6 * block_q * block_k * 4)
+    wide = pl.BlockSpec((group, span, d), lambda r, c, j: (r, c, 0))
+    stat = pl.BlockSpec((group, None, blocks, block_q),
+                        lambda r, c, j: (r, c, 0, 0))
+    tile = pl.BlockSpec((None, block_k, d), lambda r, c, j: (r, j, 0))
+    tile_t = pl.BlockSpec((None, None, d, block_k),
+                          lambda r, c, j: (r, j, 0, 0))
+    part_tile = pl.BlockSpec((None, None, block_k, d),
+                             lambda r, c, j: (c, r, j, 0))
+    dq_t, dk, dv = pl.pallas_call(
+        functools.partial(_attn_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q),
+        grid=(rows_kv, spans, seq_k // block_k),
         interpret=interpret,
-        in_specs=[
-            pl.BlockSpec((None, seq_q, d), lambda b, j: (b, 0, 0)),    # q
-            pl.BlockSpec((None, seq_q, d), lambda b, j: (b, 0, 0)),    # do
-            pl.BlockSpec((None, seq_q, 128), lambda b, j: (b, 0, 0)),  # lse
-            pl.BlockSpec((None, seq_q, 128), lambda b, j: (b, 0, 0)),  # delta
-            pl.BlockSpec((None, block_k_dkv, d),
-                         lambda b, j: (b // group, j, 0)),              # k
-            pl.BlockSpec((None, block_k_dkv, d),
-                         lambda b, j: (b // group, j, 0)),              # v
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k_dkv, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k_dkv, d), lambda b, j: (b, j, 0)),
-        ],
+        in_specs=[wide, wide, stat, stat, tile, tile, tile_t],
+        out_specs=[pl.BlockSpec((group, blocks, d, block_q),
+                                lambda r, c, j: (r, c, 0, 0)),
+                   part_tile, part_tile],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_k, d), dkv_dtype[0]),
-            jax.ShapeDtypeStruct((bh, seq_k, d), dkv_dtype[1]),
+            jax.ShapeDtypeStruct((bh, spans * blocks, d, block_q), q.dtype),
+            jax.ShapeDtypeStruct((spans, rows_kv, seq_k, d), part[0]),
+            jax.ShapeDtypeStruct((spans, rows_kv, seq_k, d), part[1]),
         ],
-        **_tpu_params(interpret, 2),
-    )(q, do, lse3, delta3, k, v)
-    dk, dv = dkv
-    if group > 1:
-        dk = dk.reshape(bh // group, group, seq_k, d).sum(1).astype(k.dtype)
-        dv = dv.reshape(bh // group, group, seq_k, d).sum(1).astype(v.dtype)
-
-    dq = pl.pallas_call(
-        functools.partial(_attn_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k_dq, seq_k=seq_k),
-        grid=(bh, seq_q // block_q_dq),
-        interpret=interpret,
-        in_specs=[
-            pl.BlockSpec((None, block_q_dq, d), lambda b, i: (b, i, 0)),  # q
-            pl.BlockSpec((None, block_q_dq, d), lambda b, i: (b, i, 0)),  # do
-            pl.BlockSpec((None, block_q_dq, 128),
-                         lambda b, i: (b, i, 0)),                       # lse
-            pl.BlockSpec((None, block_q_dq, 128),
-                         lambda b, i: (b, i, 0)),                       # dlt
-            pl.BlockSpec((None, seq_k, d),
-                         lambda b, i: (b // group, 0, 0)),              # k
-            pl.BlockSpec((None, seq_k, d),
-                         lambda b, i: (b // group, 0, 0)),              # v
-        ],
-        out_specs=pl.BlockSpec((None, block_q_dq, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-        **_tpu_params(interpret, 2),
-    )(q, do, lse3, delta3, k, v)
-    return dq, dk, dv
+        scratch_shapes=[pltpu.VMEM((group, blocks, d, block_q), jnp.float32)],
+        **_tpu_params(interpret, ("parallel", "arbitrary", "arbitrary"), vmem),
+    )(q, do, *stats, k, v, _tiles_transposed(k, block_k))
+    dq = _tiles_restored(dq_t)
+    if spans == 1:
+        return dq, dk[0], dv[0]
+    return dq, dk.sum(0).astype(k.dtype), dv.sum(0).astype(v.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -433,18 +496,17 @@ def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
 
 
 def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
-    """(block_q_dkv, block_k_dkv, block_q_dq, block_k_dq) for the backward
-    pair: bf16-aware deterministic defaults under interpret/CPU, autotuned
-    (and cached) on TPU."""
-    def clamp4(c):
-        return (_clamp(c[0], s_q), _clamp(c[1], s_k),
-                _clamp(c[2], s_q), _clamp(c[3], s_k))
-    fallback = clamp4(_bwd_default_blocks(dtype))
+    """(block_q, block_k) for the one-pass backward kernel: the forward's
+    deterministic defaults under interpret/CPU (whatever the dtype: the
+    kernel's VMEM limit follows its shapes), autotuned (and cached) on TPU."""
+    def clamp2(c):
+        return (_clamp(c[0], s_q), _clamp(c[1], s_k))
+    fallback = clamp2((DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K))
     if interp:
         return fallback
     from ..autotune import get_tuner, shape_bucket, short_dtype, \
         source_version
-    cands = list(dict.fromkeys(clamp4(c) for c in _BWD_CANDIDATES))
+    cands = list(dict.fromkeys(clamp2(c) for c in _BWD_CANDIDATES))
     if len(cands) == 1:
         return cands[0]
     sig = "bwd|bh%d|s%dx%d|d%d|%s|c%d" % (
@@ -454,8 +516,7 @@ def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
     def build(cand):
         return functools.partial(
             _flash_bwd_bh, causal=causal, scale=1.0,
-            block_q_dkv=cand[0], block_k_dkv=cand[1],
-            block_q_dq=cand[2], block_k_dq=cand[3], interpret=False)
+            block_q=cand[0], block_k=cand[1], interpret=False)
 
     def make_args():
         args = _synth_bh(
@@ -505,25 +566,21 @@ def flash_attention_fwd(q, k, v, causal=False, scale=1.0,
 
 def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=1.0,
                         block_q=None, block_k=None, interpret=None):
-    """FlashAttention-2 backward: (dq, dk, dv), all (B, S, H, D). With no
-    explicit blocks the dkv and dq passes get independently tuned
-    (block_q, block_k) pairs; explicit block_q/block_k pin both passes
-    (legacy single-pair interface)."""
+    """FlashAttention-2 backward in one pass: dq (B, S, H, D) and dk, dv in
+    k's and v's shape and dtype. With no explicit blocks the kernel's
+    (block_q, block_k) is the tuned (or fallback) pair; explicit values pin
+    it."""
     b, s, h, d = q.shape
     s_k = k.shape[1]
     interp = _interpret(q) if interpret is None else interpret
     if block_q is None and block_k is None:
-        blocks = _tuned_bwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp,
+        bq, bk = _tuned_bwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp,
                                    group=h // k.shape[2])
     else:
-        bq = block_q or DEFAULT_BLOCK_Q
-        bk = block_k or DEFAULT_BLOCK_K
-        blocks = (bq, bk, bq, bk)
-    blocks = (_clamp(blocks[0], s), _clamp(blocks[1], s_k),
-              _clamp(blocks[2], s), _clamp(blocks[3], s_k))
+        bq, bk = block_q or DEFAULT_BLOCK_Q, block_k or DEFAULT_BLOCK_K
     dq, dk, dv = _flash_bwd_bh(
         _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(out),
         lse.reshape(b * h, s), _to_bh(do), causal, scale,
-        blocks[0], blocks[1], blocks[2], blocks[3], interp)
+        _clamp(bq, s), _clamp(bk, s_k), interp)
     h_kv = k.shape[2]
     return (_from_bh(dq, b, h), _from_bh(dk, b, h_kv), _from_bh(dv, b, h_kv))

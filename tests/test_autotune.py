@@ -245,16 +245,22 @@ class TestFlashBlockFallbacks:
         assert _tuned_fwd_blocks(64, 1024, 1024, 64, jnp.float32, True,
                                  True) == (512, 512)
         assert _tuned_bwd_blocks(64, 1024, 1024, 64, jnp.float32, True,
-                                 True) == (512, 512, 512, 512)
-        # bf16-aware: reduction-loop tiles halve, parallel tiles stay 512
+                                 True) == (512, 512)
+        # the one-pass kernel's (block_q, block_k), whatever the dtype: its
+        # VMEM limit follows the shapes, so bf16 halves nothing
         assert _tuned_bwd_blocks(64, 1024, 1024, 64, jnp.bfloat16, True,
-                                 True) == (256, 512, 512, 256)
+                                 True) == (512, 512)
+        # grouped heads take the same table
+        assert _tuned_bwd_blocks(64, 4096, 4096, 64, jnp.bfloat16, True,
+                                 True, group=4) == (512, 512)
         # short sequences clamp every entry to a divisor of s
         blocks = _tuned_bwd_blocks(8, 256, 256, 64, jnp.bfloat16, True, True)
-        assert all(256 % b == 0 for b in blocks)
+        assert blocks == (256, 256)
+        assert _tuned_bwd_blocks(8, 768, 768, 64, jnp.bfloat16, True,
+                                 True) == (256, 256)
 
     def test_bwd_blocks_parity_tuned_vs_pinned(self):
-        """Independent dkv/dq blocks change scheduling, never numerics."""
+        """The backward's tiles change scheduling, never numerics."""
         import jax.numpy as jnp
 
         from paddle_tpu.ops.pallas.flash_attention import (

@@ -5,8 +5,9 @@ Interpret mode (every other flash test on CPU) cannot see what Mosaic
 refuses: a block that does not fit VMEM, a slice off the tiling, compiler
 params that no longer exist. These compiles can, and cost no chip time:
 each default block configuration and each autotune candidate of
-ops/pallas/flash_attention.py is lowered with interpret=False for one v5e
-chip and must contain the kernel (`tpu_custom_call`).
+ops/pallas/flash_attention.py (the forward kernel, the one-pass backward
+kernel) is lowered with interpret=False for one v5e chip and must contain
+the kernel (`tpu_custom_call`).
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process at a time may load libtpu, so nothing here may touch it at
@@ -72,8 +73,7 @@ def _compile_bwd(one_chip, bh, s, d, blocks):
     lse = _sds((bh, s), jnp.float32, one_chip)
     return fa._flash_bwd_bh.lower(
         x, x, x, x, lse, x, causal=True, scale=d ** -0.5,
-        block_q_dkv=blocks[0], block_k_dkv=blocks[1], block_q_dq=blocks[2],
-        block_k_dq=blocks[3], interpret=False).compile()
+        block_q=blocks[0], block_k=blocks[1], interpret=False).compile()
 
 
 def _assert_kernel(compiled, n_kernels):
@@ -85,15 +85,21 @@ def _assert_kernel(compiled, n_kernels):
 
 
 def test_compiler_params_carry_dimension_semantics():
-    """`pltpu.CompilerParams` really reaches the kernels: every grid axis is
-    marked parallel (the old class name was swallowed by an except and the
-    kernels lowered without it)."""
+    """`pltpu.CompilerParams` really reaches the kernels (the old class name
+    was swallowed by an except and the kernels lowered without it): the grid
+    axes' semantics as given, and a VMEM limit only where the blocks pass
+    the default one."""
     from jax.experimental.pallas import tpu as pltpu
     from paddle_tpu.ops.pallas import flash_attention as fa
-    params = fa._tpu_params(False, 2)["compiler_params"]
+    params = fa._tpu_params(False, ("parallel", "parallel"))["compiler_params"]
     assert isinstance(params, pltpu.CompilerParams)
     assert tuple(params.dimension_semantics) == ("parallel", "parallel")
-    assert fa._tpu_params(True, 2) == {}
+    assert params.vmem_limit_bytes is None
+    params = fa._tpu_params(False, ("parallel", "arbitrary", "arbitrary"),
+                            40 * 2 ** 20)["compiler_params"]
+    assert tuple(params.dimension_semantics)[1:] == ("arbitrary", "arbitrary")
+    assert 40 * 2 ** 20 < params.vmem_limit_bytes <= fa.VMEM_LIMIT_CAP
+    assert fa._tpu_params(True, ("parallel", "parallel")) == {}
 
 
 @pytest.mark.parametrize("bh,s,d", SHAPES)
@@ -105,10 +111,9 @@ def test_fwd_default_blocks_compile(one_chip, bh, s, d):
 
 @pytest.mark.parametrize("bh,s,d", SHAPES)
 def test_bwd_default_blocks_compile(one_chip, bh, s, d):
-    import jax.numpy as jnp
     from paddle_tpu.ops.pallas import flash_attention as fa
-    blocks = fa._bwd_default_blocks(jnp.bfloat16)
-    _assert_kernel(_compile_bwd(one_chip, bh, s, d, blocks), 2)
+    blocks = (fa._clamp(fa.DEFAULT_BLOCK_Q, s), fa._clamp(fa.DEFAULT_BLOCK_K, s))
+    _assert_kernel(_compile_bwd(one_chip, bh, s, d, blocks), 1)
 
 
 @pytest.mark.parametrize("bh,s,d", SHAPES)
@@ -126,7 +131,7 @@ def test_every_bwd_candidate_compiles(one_chip, bh, s, d):
     from paddle_tpu.ops.pallas import flash_attention as fa
     for cand in fa._BWD_CANDIDATES:
         blocks = tuple(fa._clamp(b, s) for b in cand)
-        _assert_kernel(_compile_bwd(one_chip, bh, s, d, blocks), 2)
+        _assert_kernel(_compile_bwd(one_chip, bh, s, d, blocks), 1)
 
 
 def test_public_vjp_pair_compiles_at_gpt1p3b_widths(one_chip):
@@ -144,7 +149,8 @@ def test_public_vjp_pair_compiles_at_gpt1p3b_widths(one_chip):
     x = _sds((2, 1024, 16, 128), jnp.bfloat16, one_chip)
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
-    _assert_kernel(compiled, 3)
+    _assert_kernel(compiled, 2)
+    assert compiled.as_text().count("tpu_custom_call") == 2
 
 
 def test_flash_under_a_dp2_mp2_mesh_compiles(topo):
@@ -177,7 +183,7 @@ def test_flash_under_a_dp2_mp2_mesh_compiles(topo):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") == 2
     assert " all-gather(" not in text and " all-reduce(" not in text
 
 
@@ -188,7 +194,9 @@ def test_flash_under_a_dp2_mp2_mesh_compiles(topo):
 def test_grouped_head_vjp_pair_compiles_at_lfm2_widths(one_chip):
     """(b 2, s 4096, 32 query heads over 8 key/value heads, d 64) bf16
     causal, differentiated: k and v are read through the index maps, so the
-    program holds no repeated copy of them, and dk, dv come back with 8 heads."""
+    program holds no repeated copy of them, and dk, dv come back with 8
+    heads, added over each group inside the one backward kernel: no float32
+    array of the operands' size, no 128-lane statistic."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.attention import _flash_attention_diff
@@ -200,7 +208,17 @@ def test_grouped_head_vjp_pair_compiles_at_lfm2_widths(one_chip):
     q = _sds((2, 4096, 32, 64), jnp.bfloat16, one_chip)
     kv = _sds((2, 4096, 8, 64), jnp.bfloat16, one_chip)
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
-    _assert_kernel(compiled, 3)
+    _assert_kernel(compiled, 2)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    # what the program materialises: the entry computation's instructions
+    # (a fusion's body may widen an element on its way through)
+    entry = text[text.index("\nENTRY "):]
+    assert "f32[64,1,4096]" in entry                    # lse, lane-dense
+    for partial_or_wide in ("f32[64,4096,64]", "f32[16,4096,64]",
+                            "f32[1,16,4096,64]", "f32[64,4096,128]",
+                            "f32[64,8,64,512]"):
+        assert partial_or_wide not in entry, partial_or_wide
     assert [o.shape for o in compiled.out_info] == [
         (2, 4096, 32, 64), (2, 4096, 8, 64), (2, 4096, 8, 64)]
 
@@ -216,11 +234,33 @@ def test_every_candidate_compiles_at_grouped_heads_and_4096(one_chip, cand):
     _assert_kernel(fa._flash_fwd_bh.lower(
         q, kv, kv, causal=True, scale=0.125, block_q=bq, block_k=bk,
         interpret=False).compile(), 1)
-    b = fa._BWD_CANDIDATES[cand]
+    bq, bk = fa._BWD_CANDIDATES[cand]
     _assert_kernel(fa._flash_bwd_bh.lower(
-        q, kv, kv, q, lse, q, causal=True, scale=0.125, block_q_dkv=b[0],
-        block_k_dkv=b[1], block_q_dq=b[2], block_k_dq=b[3],
-        interpret=False).compile(), 2)
+        q, kv, kv, q, lse, q, causal=True, scale=0.125, block_q=bq,
+        block_k=bk, interpret=False).compile(), 1)
+
+
+@pytest.mark.parametrize("bh,rows_kv,s,d", [
+    pytest.param(8, 2, 16384, 64, id="group4-s16384-d64"),
+    pytest.param(4, 1, 32768, 64, id="group4-s32768-d64"),
+    pytest.param(2, 2, 32768, 128, id="group1-s32768-d128"),
+])
+def test_backward_in_spans_compiles_at_long_sequences(one_chip, bh, rows_kv, s, d):
+    """Where a group's q, dO and dQ over the whole sequence pass what VMEM
+    may hold resident, `_bwd_q_span` cuts the query range; the same kernel,
+    one span a grid step, and the forward beside it."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    assert fa._bwd_q_span(bh // rows_kv, s, d, 2, 512) < s or bh == rows_kv
+    q = _sds((bh, s, d), jnp.bfloat16, one_chip)
+    kv = _sds((rows_kv, s, d), jnp.bfloat16, one_chip)
+    lse = _sds((bh, s), jnp.float32, one_chip)
+    _assert_kernel(fa._flash_fwd_bh.lower(
+        q, kv, kv, causal=True, scale=d ** -0.5, block_q=512, block_k=512,
+        interpret=False).compile(), 1)
+    _assert_kernel(fa._flash_bwd_bh.lower(
+        q, kv, kv, q, lse, q, causal=True, scale=d ** -0.5, block_q=512,
+        block_k=512, interpret=False).compile(), 1)
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)],

@@ -199,9 +199,10 @@ class TestFlashAttention:
 
 
 class TestFlashAttentionBackward:
-    """Dedicated Pallas-backward parity (FlashAttention-2 recompute kernels,
-    ops/pallas/flash_attention.py) vs jax.vjp through the XLA path —
-    including head_dim=64, the GPT/BERT geometry the r3 kernel rejected."""
+    """Dedicated Pallas-backward parity (the one-pass FlashAttention-2
+    recompute kernel, ops/pallas/flash_attention.py) vs jax.vjp through the
+    XLA path — including head_dim=64, the GPT/BERT geometry the r3 kernel
+    rejected, grouped heads, and a length the 512-tiles do not divide."""
 
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("d", [64, 128])
@@ -228,6 +229,162 @@ class TestFlashAttentionBackward:
             np.testing.assert_allclose(np.asarray(gp), np.asarray(gx),
                                        rtol=5e-4, atol=1e-5,
                                        err_msg=f"grad wrt {nm}")
+
+    @staticmethod
+    def _operands(s, h, h_kv, d, dtype, seed=9, b=1):
+        rng = np.random.RandomState(seed)
+        mk = lambda heads: jnp.asarray(
+            rng.randn(b, s, heads, d).astype("float32") * 0.3).astype(dtype)
+        return mk(h), mk(h_kv), mk(h_kv), mk(h) / 0.3
+
+    @staticmethod
+    def _xla_grads(q, k, v, g, causal, scale):
+        """float32 gradients of XLA's attention at the operands' values."""
+        from paddle_tpu.ops.attention import _xla_attention
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        _, vjp = jax.vjp(lambda q_, k_, v_: _xla_attention(
+            q_, k_, v_, None, scale, causal, 0.0, None), *f32)
+        return vjp(g.astype(jnp.float32))
+
+    @staticmethod
+    def _assert_grads(got, want, dtype):
+        for gp, gx, nm in zip(got, want, "qkv"):
+            assert gp.dtype == dtype and gp.shape == gx.shape, nm
+            gp = np.asarray(gp.astype(jnp.float32))
+            if dtype == jnp.float32:
+                np.testing.assert_allclose(gp, np.asarray(gx), rtol=5e-4,
+                                           atol=1e-5, err_msg=f"grad wrt {nm}")
+            else:       # bf16 results: chip_smoke.py's limit, of the largest
+                err = np.abs(gp - np.asarray(gx)).max() / np.abs(gx).max()
+                assert err < 2e-2, (nm, err)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("group", [1, 4])
+    def test_one_pass_grad_parity_at_768(self, group, d, causal, dtype):
+        """768 positions: the 512-tiles clamp to 256, three key tiles, so a
+        causal call runs tile pairs on, under and (skipped) over the
+        diagonal; group 4 adds four query heads into one dk, dv in VMEM."""
+        from paddle_tpu.ops.attention import _flash_attention_diff
+        s, h = 768, 4
+        scale = 1.0 / np.sqrt(d)
+        q, k, v, g = self._operands(s, h, h // group, d, dtype)
+        _, vjp = jax.vjp(lambda q_, k_, v_: _flash_attention_diff(
+            q_, k_, v_, causal, scale, True), q, k, v)
+        self._assert_grads(vjp(g), self._xla_grads(q, k, v, g, causal, scale),
+                           dtype)
+
+    @pytest.mark.parametrize("block_q,block_k", [(128, 256), (256, 128),
+                                                 (128, 128), (512, 256)])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_unequal_tiles_same_gradients(self, causal, block_q, block_k):
+        """Tiles of unequal sides move where the diagonal crosses a tile
+        pair (two query blocks a key tile, or half of one), never the
+        numbers."""
+        from paddle_tpu.ops.pallas.flash_attention import (
+            flash_attention_bwd, flash_attention_fwd)
+        d, scale = 64, 0.125
+        q, k, v, g = self._operands(512, 4, 2, d, jnp.float32, seed=10)
+        out, lse = flash_attention_fwd(q, k, v, causal=causal, scale=scale,
+                                       block_q=block_q, block_k=block_k)
+        got = flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
+                                  scale=scale, block_q=block_q,
+                                  block_k=block_k)
+        self._assert_grads(got, self._xla_grads(q, k, v, g, causal, scale),
+                           jnp.float32)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("group", [1, 4])
+    def test_query_range_in_spans_same_gradients(self, group, causal):
+        """Where a group's q, dO, dQ would not fit VMEM the query range is
+        cut into spans (`_bwd_q_span`); here three spans of 256 rows by
+        hand: dq a span, dk and dv float32 partials that one sum adds."""
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        d, scale, dtype = 64, 0.125, jnp.bfloat16
+        q, k, v, g = self._operands(768, 4, 4 // group, d, dtype, seed=11)
+        out, lse = fa.flash_attention_fwd(q, k, v, causal=causal, scale=scale)
+        args = [fa._to_bh(x) for x in (q, k, v, out)] + [
+            lse.reshape(4, 768), fa._to_bh(g)]
+        whole = fa._flash_bwd_bh(*args, causal, scale, 256, 256, True)
+        spans = fa._flash_bwd_bh(*args, causal, scale, 256, 256, True,
+                                 q_span=256)
+        for a, b_, nm in zip(spans, whole, "qkv"):
+            assert a.dtype == dtype and a.shape == b_.shape
+            np.testing.assert_allclose(
+                np.asarray(a.astype(jnp.float32)),
+                np.asarray(b_.astype(jnp.float32)), rtol=2e-2, atol=2e-3,
+                err_msg=f"grad wrt {nm}")
+        got = [fa._from_bh(x, 1, n) for x, n in zip(spans, (4, 4 // group,
+                                                            4 // group))]
+        self._assert_grads(got, self._xla_grads(q, k, v, g, causal, scale),
+                           dtype)
+
+    def test_span_rule_follows_the_shapes(self):
+        from paddle_tpu.ops.pallas.flash_attention import (
+            VMEM_RESIDENT_BYTES, _bwd_q_span, _bwd_resident_bytes)
+        # the LFM2 cell's group (4 heads of 64 over 4096 rows, bf16) and the
+        # GPT family's heads of 128 hold their whole sequence
+        assert _bwd_q_span(4, 4096, 64, 2, 512) == 4096
+        assert _bwd_q_span(1, 4096, 128, 2, 512) == 4096
+        assert _bwd_q_span(1, 768, 64, 4, 256) == 768
+        # 32k positions at group 4 do not: whole query blocks that divide
+        # the sequence and fit
+        for group, seq, d, size, block in [(4, 32768, 64, 2, 512),
+                                           (4, 16384, 128, 4, 256),
+                                           (8, 24576, 64, 2, 512)]:
+            span = _bwd_q_span(group, seq, d, size, block)
+            assert span < seq and seq % span == 0 and span % block == 0
+            assert _bwd_resident_bytes(group, span, d, size) \
+                <= VMEM_RESIDENT_BYTES < _bwd_resident_bytes(group, seq, d, size)
+
+    @pytest.mark.parametrize("group,d", [(1, 128), (4, 64), (4, 128)])
+    def test_backward_stages_one_kernel_and_no_float32_copies(self, group, d):
+        """One pallas_call a backward; dk, dv leave it in k's and v's dtype
+        at (B*H / group, S, D); with bf16 operands the program holds no
+        float32 array of q's or k's (B*H, S, D) shape (the group's partial
+        gradients, a widened operand) and no float32 operand or result of a
+        kernel is 128 lanes of a per-row statistic."""
+        from paddle_tpu.ops.attention import _flash_attention_diff
+        b, s, h = 2, 256, 4
+        q, k, v, g = self._operands(s, h, h // group, d, jnp.bfloat16, b=b)
+
+        def bwd(q_, k_, v_, g_):
+            return jax.vjp(lambda *a: _flash_attention_diff(
+                *a, True, d ** -0.5, True), q_, k_, v_)[1](g_)
+
+        calls, f32_shapes = [], set()
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    calls.append(eqn)
+                    continue                    # not the kernel's own body
+                for var in eqn.outvars:
+                    if var.aval.dtype == jnp.float32:
+                        f32_shapes.add(tuple(var.aval.shape))
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(bwd)(q, k, v, g).jaxpr)
+        fwd_calls = [c for c in calls if len(c.outvars) == 2]
+        bwd_calls = [c for c in calls if len(c.outvars) == 3]
+        assert len(fwd_calls) == 1 and len(bwd_calls) == 1, calls
+        dq, dk, dv = (o.aval for o in bwd_calls[0].outvars)
+        # dq a query block transposed: (B*H, blocks, D, block_q)
+        assert dq.shape == (b * h, 1, d, s) and dq.dtype == jnp.bfloat16
+        for part in (dk, dv):
+            assert part.shape == (1, b * h // group, s, d)
+            assert part.dtype == jnp.bfloat16
+        for shape in ((b * h, s, d), (b * h // group, s, d),
+                      (1, b * h // group, s, d), (b * h, 1, d, s)):
+            assert shape not in f32_shapes, shape
+        for call in calls:
+            for var in list(call.invars) + list(call.outvars):
+                if var.aval.dtype == jnp.float32:
+                    assert var.aval.shape[-1] != 128 or s == 128, var.aval
+                    assert int(np.prod(var.aval.shape)) == b * h * s
 
     def test_supports_head_dim_64(self):
         from paddle_tpu.ops.pallas.flash_attention import supports
