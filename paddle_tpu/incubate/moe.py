@@ -363,7 +363,10 @@ class DroplessMoELayer(nn.Layer):
     of w_e * w2_e(silu(w1_e x) * w3_e x); the normalisation still runs over
     all k. With every expert held that is the whole layer; with a share,
     the shares' results add up to it (tests/test_dropless_moe.py), which is
-    what an expert-parallel rank computes before the exchange. There is no
+    what an expert-parallel rank computes before the exchange. With
+    `shared_width` a shared expert, one more SwiGLU every token goes through
+    (scopes `linear`, `swiglu`), is added after the combine; every rank holds
+    it whole, so the shares add up to the layer with it counted once. There is no
     capacity and no dropped pair: the (token, expert) pairs are sorted by
     expert into a buffer sized for the worst routing (every pair held here),
     each held expert's rows tile-aligned, and one grouped product per
@@ -383,7 +386,7 @@ class DroplessMoELayer(nn.Layer):
 
     def __init__(self, d_model, d_hidden, num_experts, top_k,
                  held_experts=None, routed_scaling_factor=1.0,
-                 weight_attr=None):
+                 weight_attr=None, shared_width=None):
         super().__init__()
         from ..ops.pallas.grouped_matmul import ROW_TILE
         held = list(range(num_experts)) if held_experts is None \
@@ -408,6 +411,11 @@ class DroplessMoELayer(nn.Layer):
         self.w1 = self.create_parameter([n, d_model, d_hidden], attr=weight_attr)
         self.w3 = self.create_parameter([n, d_model, d_hidden], attr=weight_attr)
         self.w2 = self.create_parameter([n, d_hidden, d_model], attr=weight_attr)
+        # a shared expert: one more SwiGLU of `shared_width` that every token
+        # goes through, added after the combine. It is whole on every chip
+        # (data parallelism leaves it so), so across shares it counts once
+        self.shared = None if shared_width is None else nn.SwiGLUFFN(
+            d_model, shared_width, weight_attr=weight_attr)
         for name in ("rows_total", "calls_total", "imbalance_total"):
             self.register_buffer(name, Tensor(jnp.zeros((), jnp.float32)),
                                  persistable=False)
@@ -474,6 +482,8 @@ class DroplessMoELayer(nn.Layer):
             yv, wv, pr, rp, rv, hp, nt, tm, kernel),
             y, w, pair_row, row_pair, row_valid, num_tiles, *held,
             name="moe_combine").reshape(shape)
+        if self.shared is not None:
+            out = out + self.shared(x)
         return out, apply(lambda c: c.astype(jnp.float32), counts, name="moe_route")
 
     def record_load(self, load):

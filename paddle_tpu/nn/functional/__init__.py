@@ -73,3 +73,17 @@ def gather_tree(ids, parents):
     ids/parents: (max_time, batch, beam) int tensors."""
     from ..decode import _backtrack
     return apply(_backtrack, ids, parents, name="gather_tree")
+
+
+def kimi_delta_attention(query, key, value, g, beta, scale=None, name=None):
+    """Kimi Delta Attention (ops/kda.py): the gated delta rule with a decay
+    per channel, chunked, with a chunked backward.
+
+    Shapes: query, key and g (batch, seq, heads, d_k), value (batch, seq,
+    heads, d_v), beta (batch, seq, heads). g <= 0 is the log of the decay
+    (float32), beta in [0, 1] the write strength; the queries are multiplied
+    by `scale` (d_k ** -0.5 by default). The state starts at zero in every
+    row of the batch. Returns (batch, seq, heads, d_v) in value's dtype.
+    """
+    from ...ops.kda import delta_attention
+    return delta_attention(query, key, value, g, beta, scale=scale)

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from ...core.dispatch import apply
 
 __all__ = ["conv1d", "conv2d", "conv3d", "conv1d_transpose", "conv2d_transpose",
-           "conv3d_transpose", "short_conv"]
+           "conv3d_transpose", "short_conv", "short_conv_silu"]
 
 
 def _norm_tuple(v, n):
@@ -191,12 +191,29 @@ def short_conv(bcx, weight, name=None):
     a convolution call would only hide them."""
     def prim(v, w):
         b, c, x = jnp.split(v.astype(jnp.float32), 3, axis=-1)
-        u = b * x
-        taps = w.astype(jnp.float32)
-        k = taps.shape[1]
-        seq = u.shape[1]
-        padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
-        acc = sum(padded[:, j:j + seq] * taps[:, j] for j in range(k))
-        return (c * acc).astype(v.dtype)
+        return (c * _causal_taps(b * x, w)).astype(v.dtype)
 
     return apply(prim, bcx, weight, name="short_conv")
+
+
+def _causal_taps(u, w):
+    """sum_j w[:, j] * u_{t - (K-1) + j} over u (batch, seq, channels) in
+    float32, zero before the sequence's start: K shifted multiply-adds."""
+    taps = w.astype(jnp.float32)
+    k = taps.shape[1]
+    seq = u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + seq] * taps[:, j] for j in range(k))
+
+
+def short_conv_silu(x, weight, name=None):
+    """silu of a causal depthwise convolution over `x` (batch, seq,
+    channels): y_t = silu(sum_j weight[:, j] * x_{t - (K-1) + j}), zero
+    before the sequence's start, as the linear-attention mixers put it after
+    their q, k and v projections (Kimi Delta Attention: K = 4). `weight` is
+    (channels, K), the last tap on the current step; the same shifted
+    multiply-adds as `short_conv`, in float32."""
+    def prim(v, w):
+        return jax.nn.silu(_causal_taps(v.astype(jnp.float32), w)).astype(v.dtype)
+
+    return apply(prim, x, weight, name="short_conv")

@@ -13,7 +13,7 @@ from ...core.dispatch import apply, unwrap
 from ...core.tensor import Tensor
 
 __all__ = ["batch_norm", "layer_norm", "rms_norm", "instance_norm",
-           "group_norm", "local_response_norm", "normalize"]
+           "group_norm", "local_response_norm", "normalize", "l2_norm"]
 
 
 def batch_norm(x, running_mean, running_var, weight=None, bias=None,
@@ -182,6 +182,17 @@ def rms_norm(x, weight=None, epsilon=1e-05, name=None):
 
     args = [] if weight is None else [weight]
     return apply(prim, x, *args, name="rms_norm")
+
+
+def l2_norm(x, epsilon=1e-06, name=None):
+    """x / sqrt(sum(x^2) + epsilon) over the last axis, the statistics in
+    float32 whatever the input's dtype: the per-head norm the delta-rule
+    mixers put on their queries and keys."""
+    def prim(v):
+        f = v.astype(jnp.float32)
+        return (f * jax.lax.rsqrt(jnp.sum(jnp.square(f), axis=-1, keepdims=True)
+                                  + epsilon)).astype(v.dtype)
+    return apply(prim, x, name="l2_norm")
 
 
 def local_response_norm(x, size, alpha=1e-4, beta=0.75, k=1.0,

@@ -16,11 +16,12 @@ from .. import functional as F
 from .common import Dropout, Linear
 from .container import LayerList
 from .layers import Layer
-from .norm import LayerNorm
+from .norm import LayerNorm, RMSNorm
 
 __all__ = ["MultiHeadAttention", "TransformerEncoderLayer",
            "TransformerEncoder", "TransformerDecoderLayer",
-           "TransformerDecoder", "Transformer"]
+           "TransformerDecoder", "Transformer", "MultiHeadLatentAttention",
+           "KimiDeltaAttention"]
 
 
 def _convert_attn_mask(attn_mask, dtype):
@@ -353,3 +354,127 @@ class Transformer(Layer):
         m = jnp.where(jnp.tril(jnp.ones((length, length), dtype=bool)), 0.0,
                       -1e30).astype(jnp.float32)
         return Tensor(m)
+
+
+class MultiHeadLatentAttention(Layer):
+    """Causal multi-head latent attention without rotation (DeepSeek-V2's
+    MLA as Kimi Linear uses it: `q_lora_rank` null, `mla_use_nope` true).
+
+    q_h = W_q^h x, `qk_nope_head_dim + qk_rope_head_dim` wide; c = W_kva x,
+    `kv_lora_rank + qk_rope_head_dim` wide: its first part, RMS-normed, is the
+    latent every head's keys and values are made from, [k_nope_h ; v_h] =
+    W_kvb^h c_kv, and its last `qk_rope_head_dim` entries are a key part all
+    heads share; k_h = [k_nope_h ; k_shared]. The value heads are
+    `v_head_dim` wide, the query/key heads wider, and
+    F.scaled_dot_product_attention takes the two sizes as they are (no v
+    padded to the keys' width). The shared key part is broadcast over the
+    heads when k is put together, which writes it `num_heads` times: a third
+    of k's bytes at 128 + 64, 33 MB of a 100 MB k at 2 x 4096 tokens and 32
+    heads in bfloat16 (the flash kernels take one key operand). No bias."""
+
+    def __init__(self, hidden_size, num_heads, kv_lora_rank, qk_nope_head_dim,
+                 qk_rope_head_dim, v_head_dim, epsilon=1e-05, weight_attr=None):
+        super().__init__()
+        self.num_heads = num_heads
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+
+        def linear(n_in, n_out):
+            return Linear(n_in, n_out, weight_attr=weight_attr, bias_attr=False)
+        self.q_proj = linear(hidden_size,
+                             num_heads * (qk_nope_head_dim + qk_rope_head_dim))
+        self.kv_a_proj = linear(hidden_size, kv_lora_rank + qk_rope_head_dim)
+        self.kv_a_norm = RMSNorm(kv_lora_rank, epsilon)
+        self.kv_b_proj = linear(kv_lora_rank,
+                                num_heads * (qk_nope_head_dim + v_head_dim))
+        self.o_proj = linear(num_heads * v_head_dim, hidden_size)
+
+    def forward(self, x):
+        import jax.numpy as jnp
+        from ...core.dispatch import apply
+        b, s, _ = x.shape
+        heads, nope, dv = self.num_heads, self.qk_nope_head_dim, self.v_head_dim
+        q = M.reshape(self.q_proj(x),
+                      [b, s, heads, nope + self.qk_rope_head_dim])
+        rank = self.kv_lora_rank
+        latent, shared = apply(lambda c: (c[..., :rank], c[..., rank:]),
+                               self.kv_a_proj(x), name="mla_kv")
+        kv = M.reshape(self.kv_b_proj(self.kv_a_norm(latent)),
+                       [b, s, heads, nope + dv])
+
+        def keys_values(kv_, shared_):
+            pe = jnp.broadcast_to(shared_[:, :, None, :],
+                                  kv_.shape[:3] + shared_.shape[-1:])
+            return (jnp.concatenate([kv_[..., :nope], pe], axis=-1),
+                    kv_[..., nope:])
+        k, v = apply(keys_values, kv, shared, name="mla_kv")
+        out = F.scaled_dot_product_attention(q, k, v, is_causal=True,
+                                             training=self.training)
+        return self.o_proj(M.reshape(out, [b, s, heads * dv]))
+
+
+class KimiDeltaAttention(Layer):
+    """The Kimi Delta Attention mixer (Kimi Linear, arXiv 2510.26692).
+
+    q = l2norm_head(silu(conv(W_q x))), k likewise, v = silu(conv(W_v x)),
+    conv a causal depthwise convolution of `conv_kernel` taps
+    (F.short_conv_silu); a decay per channel in log space,
+    g = -exp(A_log_h) * softplus(W_f_up W_f_down x + dt_bias), float32; a
+    write strength a head, beta = sigmoid(W_b x); o = F.kimi_delta_attention
+    (q, k, v, g, beta), the chunked gated delta rule with the queries scaled
+    by head_dim ** -0.5; out = W_o(rms_norm_head(o) * sigmoid(W_g_up W_g_down
+    x)), the norm's gain of `head_dim` shared by the heads. Both gates are
+    low-rank, hidden -> `gate_rank` -> heads * head_dim. No positions, no
+    bias but `dt_bias`. The leaves are named as the source's modelling code
+    names them (`q_conv1d`, `f_a_proj`, `A_log`, `o_norm`, ...)."""
+
+    def __init__(self, hidden_size, num_heads, head_dim, conv_kernel=4,
+                 gate_rank=None, epsilon=1e-05, weight_attr=None):
+        super().__init__()
+        from .. import initializer as I
+        self.num_heads, self.head_dim = num_heads, head_dim
+        width = num_heads * head_dim
+        rank = head_dim if gate_rank is None else gate_rank
+
+        def linear(n_in, n_out):
+            return Linear(n_in, n_out, weight_attr=weight_attr, bias_attr=False)
+        self.q_proj, self.k_proj, self.v_proj = (
+            linear(hidden_size, width) for _ in range(3))
+        taps = I.Uniform(-conv_kernel ** -0.5, conv_kernel ** -0.5)
+        self.q_conv1d, self.k_conv1d, self.v_conv1d = (
+            self.create_parameter([width, conv_kernel], attr=weight_attr,
+                                  default_initializer=taps) for _ in range(3))
+        self.f_a_proj, self.f_b_proj = linear(hidden_size, rank), linear(rank, width)
+        self.A_log = self.create_parameter(
+            [num_heads], default_initializer=I.Constant(0.0))
+        self.dt_bias = self.create_parameter(
+            [width], default_initializer=I.Constant(0.0))
+        self.b_proj = linear(hidden_size, num_heads)
+        self.g_a_proj, self.g_b_proj = linear(hidden_size, rank), linear(rank, width)
+        self.o_norm = RMSNorm(head_dim, epsilon)
+        self.o_proj = linear(width, hidden_size)
+
+    def forward(self, x):
+        import jax
+        import jax.numpy as jnp
+        from ...core.dispatch import apply
+        b, s, _ = x.shape
+        heads = [b, s, self.num_heads, self.head_dim]
+        q = F.l2_norm(M.reshape(
+            F.short_conv_silu(self.q_proj(x), self.q_conv1d), heads))
+        k = F.l2_norm(M.reshape(
+            F.short_conv_silu(self.k_proj(x), self.k_conv1d), heads))
+        v = M.reshape(F.short_conv_silu(self.v_proj(x), self.v_conv1d), heads)
+
+        def decay(f, a_log, dt_bias):
+            f32 = jnp.float32
+            g = jax.nn.softplus(f.astype(f32) + dt_bias.astype(f32)).reshape(heads)
+            return -jnp.exp(a_log.astype(f32))[:, None] * g
+        g = apply(decay, self.f_b_proj(self.f_a_proj(x)), self.A_log,
+                  self.dt_bias, name="kda_gate")
+        beta = F.sigmoid(self.b_proj(x))
+        o = self.o_norm(F.kimi_delta_attention(q, k, v, g, beta))
+        gate = M.reshape(F.sigmoid(self.g_b_proj(self.g_a_proj(x))), heads)
+        return self.o_proj(M.reshape(o * gate, [b, s, heads[2] * heads[3]]))

@@ -65,6 +65,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     """(batch, seq, heads, head_dim) attention. `key` and `value` may hold
     fewer heads than `query`, a divisor of its count (grouped-query
     attention): query head h reads key/value head h // (heads / kv_heads).
+    `value`'s heads may be of another size than `query`'s and `key`'s (latent
+    attention: 192 against 128); the result has value's head size and the
+    default scale is the query/key size's.
     `use_pallas=None` lets `takes_flash` choose the path from the shapes;
     True or False is the caller's own choice (True with a mask or dropout
     raises). The path a call took is counted: `attention.flash_total`,
@@ -87,7 +90,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     if use_pallas is None:
         use_pallas = takes_flash(qv.shape, unwrap(key).shape, qv.dtype,
                                  attn_mask is not None, dropout_p,
-                                 _platform())
+                                 _platform(), unwrap(value).shape)
     elif use_pallas and (attn_mask is not None or dropout_p > 0.0):
         raise ValueError(
             "use_pallas=True is incompatible with attn_mask/dropout_p: the "
@@ -178,17 +181,19 @@ def _flash_bwd(is_causal, scale, interpret, res, g):
 _flash_attention_diff.defvjp(_flash_fwd, _flash_bwd)
 
 
-def takes_flash(q_shape, k_shape, dtype, masked, dropout_p, platform):
+def takes_flash(q_shape, k_shape, dtype, masked, dropout_p, platform,
+                v_shape=None):
     """Whether attention over operands of these (batch, seq, heads, head_dim)
     shapes runs the flash kernel pair: on a TPU, plain or causal attention
     (no mask, no dropout) of a floating type over shapes the kernels tile,
     from FLASH_MIN_SEQ_Q query positions, where the keys are at least
     FLASH_MIN_SEQ_K long or XLA's attention could not hold its scores.
-    Everything else runs XLA's attention."""
+    Everything else runs XLA's attention. `v_shape` where the value heads
+    are of another size than the keys'."""
     if (platform != "tpu" or masked or dropout_p > 0.0
             or q_shape[1] < FLASH_MIN_SEQ_Q
             or not jnp.issubdtype(dtype, jnp.floating)
-            or not _pallas_supports(q_shape, k_shape)):
+            or not _pallas_supports(q_shape, k_shape, v_shape)):
         return False
     return k_shape[1] >= FLASH_MIN_SEQ_K or not _xla_holds_its_scores(
         q_shape, k_shape)
@@ -209,9 +214,10 @@ def _device_memory_bytes():
     return stats.get("bytes_limit", 16 * 2 ** 30)
 
 
-def _pallas_supports(q_shape, k_shape):
+def _pallas_supports(q_shape, k_shape, v_shape=None):
     from .pallas.flash_attention import supports
-    return supports(tuple(q_shape), tuple(k_shape))
+    return supports(tuple(q_shape), tuple(k_shape),
+                    None if v_shape is None else tuple(v_shape))
 
 
 @functools.lru_cache(maxsize=1)

@@ -46,6 +46,11 @@ Layout: inputs (B, S, H, D) paddle convention; kernels work on (B*H, S, D).
 Head dims of 64 are supported (VMEM pads the lane dim of a (block, 64) tile;
 every product then fills half of the 128 x 128 MXU: docs/kernels.md).
 
+The value heads may be of another size than the query/key heads (latent
+attention: 192 against 128): scores, dK and dQ^T contract or give d, the
+query/key size; O^T, dV and dP the value size d_v. Nothing is padded to the
+larger; where the two are equal the staged program is what it was.
+
 Grouped-query attention: k and v may hold H / group heads. Rows
 r * group ... r * group + group - 1 of q read row r of k and v, which the
 block specs say, so no repeated copy of k or v is ever written and dk, dv
@@ -153,17 +158,17 @@ def _tiles_restored(x_t):
 
 def _attn_fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, l_ref, *, scale, causal,
                      block_k):
-    # q_ref: (block_q, d); k_ref: (seq_k, d); vt_ref: (seq_k / block_k, d,
-    # block_k), each value tile transposed; ot_ref: (d, block_q), the output
+    # q_ref: (block_q, d); k_ref: (seq_k, d); vt_ref: (seq_k / block_k, d_v,
+    # block_k), each value tile transposed; ot_ref: (d_v, block_q), the output
     # tile transposed; l_ref: (1, block_q), the logsumexp rows lane-dense.
     # Every tile is formed transposed, keys down the sublanes and queries
     # along the lanes: the row statistics are lane-dense rows, their maxima
     # and sums run down the sublanes, and they broadcast for nothing.
-    block_q, d = q_ref.shape
+    block_q = q_ref.shape[0]
     q_idx = pl.program_id(1)
     fold = _scale_folds(scale)
     q = q_ref[...] * scale if fold else q_ref[...]
-    num_k_blocks = vt_ref.shape[0]
+    num_k_blocks, d_v = vt_ref.shape[:2]
 
     def step(kb, carry, masked):
         m_prev, l_prev, acc = carry
@@ -185,12 +190,12 @@ def _attn_fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, l_ref, *, scale, causal,
         l_new = l_prev * correction + jnp.sum(p_t, axis=0, keepdims=True)
         acc = acc * correction + jnp.dot(
             vt_tile, p_t.astype(vt_tile.dtype),
-            preferred_element_type=jnp.float32)         # (d, block_q)
+            preferred_element_type=jnp.float32)         # (d_v, block_q)
         return m_new, l_new, acc
 
     carry = (jnp.full((1, block_q), _MASKED, jnp.float32),
              jnp.zeros((1, block_q), jnp.float32),
-             jnp.zeros((d, block_q), jnp.float32))
+             jnp.zeros((d_v, block_q), jnp.float32))
     if causal:
         # k-tiles wholly under the diagonal, then the ones it crosses; the
         # tiles wholly above it are never visited
@@ -213,12 +218,13 @@ def _attn_fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, l_ref, *, scale, causal,
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret"))
 def _flash_fwd_bh(q, k, v, causal, scale, block_q, block_k, interpret):
-    # q: (BH, S, D), k,v: (BH / group, S, D) -> out (BH, S, D), lse (BH, S)
+    # q: (BH, S, D), k: (BH / group, S, D), v: (BH / group, S, Dv)
+    # -> out (BH, S, Dv), lse (BH, S)
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+    seq_k, d_v = v.shape[1:]
     group = bh // k.shape[0]
     tiles_q, tiles_k = seq_q // block_q, seq_k // block_k
-    vmem = (2 * seq_k * (_lanes(d) + d) * k.dtype.itemsize
+    vmem = (2 * seq_k * (_lanes(d) + d_v) * k.dtype.itemsize
             + 4 * block_q * block_k * 4)
     out_t, lse = pl.pallas_call(
         functools.partial(_attn_fwd_kernel, scale=scale, causal=causal,
@@ -228,15 +234,15 @@ def _flash_fwd_bh(q, k, v, causal, scale, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, seq_k, d), lambda b, i: (b // group, 0, 0)),
-            pl.BlockSpec((None, tiles_k, d, block_k),
+            pl.BlockSpec((None, tiles_k, d_v, block_k),
                          lambda b, i: (b // group, 0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, d, block_q), lambda b, i: (b, i, 0, 0)),
+            pl.BlockSpec((None, None, d_v, block_q), lambda b, i: (b, i, 0, 0)),
             pl.BlockSpec((None, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tiles_q, d, block_q), q.dtype),
+            jax.ShapeDtypeStruct((bh, tiles_q, d_v, block_q), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
         **_tpu_params(interpret, ("parallel", "parallel"), vmem),
@@ -252,9 +258,10 @@ def _attn_bwd_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref, kt_ref,
                      dqt_ref, dk_ref, dv_ref, dqt_acc, *, scale, causal,
                      block_q):
     # one key tile j of one key/value head, against one span of the group's
-    # query rows. q_ref, do_ref: (group, span, d); l_ref, dd_ref: (group,
-    # span / block_q, block_q) float32, a query block a row; k_ref, v_ref,
-    # dk_ref, dv_ref: (block_k, d); kt_ref: (d, block_k), the key tile
+    # query rows. q_ref: (group, span, d); do_ref: (group, span, d_v); l_ref,
+    # dd_ref: (group, span / block_q, block_q) float32, a query block a row;
+    # k_ref, dk_ref: (block_k, d); v_ref, dv_ref: (block_k, d_v); kt_ref:
+    # (d, block_k), the key tile
     # transposed; dqt_ref: (group, span / block_q, d, block_q), dq a query
     # block transposed; dqt_acc: the same in float32, resident over the key
     # tiles
@@ -326,7 +333,10 @@ def _attn_bwd_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref, kt_ref,
             clear, num_q_blocks, lambda i, c: pair(h, i, c, False), carry)
 
     zeros = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, group, head, (zeros, zeros))
+    dk, dv = jax.lax.fori_loop(
+        0, group, head,
+        (zeros, zeros if v_ref.shape == zeros.shape
+         else jnp.zeros(v_ref.shape, jnp.float32)))
     dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
@@ -339,20 +349,23 @@ def _attn_bwd_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref, kt_ref,
         for_each_block(write)
 
 
-def _bwd_resident_bytes(group, rows, d, itemsize):
+def _bwd_resident_bytes(group, rows, d, itemsize, d_v=None):
     """VMEM a grid step of the backward holds for `rows` query rows of a
-    group: q and dO double-buffered (a row of d pads to whole 128-lane
-    tiles), the transposed dQ block double-buffered, its float32 scratch."""
-    return group * rows * (4 * _lanes(d) * itemsize + d * (2 * itemsize + 4))
+    group: q (rows of d) and dO (rows of d_v) double-buffered (a row pads to
+    whole 128-lane tiles), the transposed dQ block double-buffered, its
+    float32 scratch."""
+    d_v = d if d_v is None else d_v
+    return group * rows * (2 * (_lanes(d) + _lanes(d_v)) * itemsize
+                           + d * (2 * itemsize + 4))
 
 
-def _bwd_q_span(group, seq_q, d, itemsize, block_q):
+def _bwd_q_span(group, seq_q, d, itemsize, block_q, d_v=None):
     """Query rows a grid step of the backward holds resident: the whole
     sequence where that fits VMEM_RESIDENT_BYTES, else the largest whole
     number of query blocks that divides the sequence and fits."""
     spans = 1
     while (seq_q // spans > block_q and _bwd_resident_bytes(
-            group, seq_q // spans, d, itemsize) > VMEM_RESIDENT_BYTES):
+            group, seq_q // spans, d, itemsize, d_v) > VMEM_RESIDENT_BYTES):
         spans += 1
         while seq_q % (spans * block_q):
             spans += 1
@@ -363,13 +376,15 @@ def _bwd_q_span(group, seq_q, d, itemsize, block_q):
     "causal", "scale", "block_q", "block_k", "interpret", "q_span"))
 def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                   interpret, q_span=None):
-    # all (BH, S, D) except k, v (BH / group, S, D) and lse (BH, S); returns
-    # dq (BH, S, D) and dk, dv (BH / group, S, D). `q_span` pins the rows
-    # resident a step (tests); None takes the rule of shapes.
+    # q (BH, S, D), k (BH / group, S, D), v (BH / group, S, Dv), o and do
+    # (BH, S, Dv), lse (BH, S); returns dq (BH, S, D) and dk, dv in k's and
+    # v's shapes. `q_span` pins the rows resident a step (tests); None takes
+    # the rule of shapes.
     bh, seq_q, d = q.shape
-    rows_kv, seq_k = k.shape[:2]
+    rows_kv, seq_k, d_v = v.shape
     group = bh // rows_kv
-    span = q_span or _bwd_q_span(group, seq_q, d, q.dtype.itemsize, block_q)
+    span = q_span or _bwd_q_span(group, seq_q, d, q.dtype.itemsize, block_q,
+                                 d_v)
     spans, blocks = seq_q // span, span // block_q
     # D = rowsum(dO * O), float32: a product of two bf16 is exact in float32
     delta = jnp.einsum("rsd,rsd->rs", do, o, precision="highest",
@@ -378,30 +393,36 @@ def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     stats = [x.reshape(bh, spans, blocks, block_q) for x in (lse, delta)]
     # one span: dk, dv leave in k's, v's dtype; more: float32 partials
     part = (k.dtype, v.dtype) if spans == 1 else (jnp.float32,) * 2
-    vmem = (_bwd_resident_bytes(group, span, d, q.dtype.itemsize)
-            + 10 * block_k * _lanes(d) * k.dtype.itemsize
+    vmem = (_bwd_resident_bytes(group, span, d, q.dtype.itemsize, d_v)
+            + block_k * (6 * _lanes(d) + 4 * _lanes(d_v)) * k.dtype.itemsize
             + 6 * block_q * block_k * 4)
-    wide = pl.BlockSpec((group, span, d), lambda r, c, j: (r, c, 0))
+
+    def wide(width):
+        return pl.BlockSpec((group, span, width), lambda r, c, j: (r, c, 0))
+
+    def tile(width):
+        return pl.BlockSpec((None, block_k, width), lambda r, c, j: (r, j, 0))
+
+    def part_tile(width):
+        return pl.BlockSpec((None, None, block_k, width),
+                            lambda r, c, j: (c, r, j, 0))
     stat = pl.BlockSpec((group, None, blocks, block_q),
                         lambda r, c, j: (r, c, 0, 0))
-    tile = pl.BlockSpec((None, block_k, d), lambda r, c, j: (r, j, 0))
     tile_t = pl.BlockSpec((None, None, d, block_k),
                           lambda r, c, j: (r, j, 0, 0))
-    part_tile = pl.BlockSpec((None, None, block_k, d),
-                             lambda r, c, j: (c, r, j, 0))
     dq_t, dk, dv = pl.pallas_call(
         functools.partial(_attn_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q),
         grid=(rows_kv, spans, seq_k // block_k),
         interpret=interpret,
-        in_specs=[wide, wide, stat, stat, tile, tile, tile_t],
+        in_specs=[wide(d), wide(d_v), stat, stat, tile(d), tile(d_v), tile_t],
         out_specs=[pl.BlockSpec((group, blocks, d, block_q),
                                 lambda r, c, j: (r, c, 0, 0)),
-                   part_tile, part_tile],
+                   part_tile(d), part_tile(d_v)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, spans * blocks, d, block_q), q.dtype),
             jax.ShapeDtypeStruct((spans, rows_kv, seq_k, d), part[0]),
-            jax.ShapeDtypeStruct((spans, rows_kv, seq_k, d), part[1]),
+            jax.ShapeDtypeStruct((spans, rows_kv, seq_k, d_v), part[1]),
         ],
         scratch_shapes=[pltpu.VMEM((group, blocks, d, block_q), jnp.float32)],
         **_tpu_params(interpret, ("parallel", "arbitrary", "arbitrary"), vmem),
@@ -416,11 +437,14 @@ def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q, block_k,
 # public entry points
 # ---------------------------------------------------------------------------
 
-def supports(q_shape, k_shape):
+def supports(q_shape, k_shape, v_shape=None):
+    """Whether the kernels tile these (batch, seq, heads, head_dim) shapes;
+    `v_shape` where the value heads are of another size than the keys'."""
     b, s_q, h, d = q_shape
     s_k = k_shape[1]
-    return (s_q % 128 == 0 and s_k % 128 == 0
-            and d % 64 == 0 and s_q == s_k and h % k_shape[2] == 0)
+    d_v = d if v_shape is None else v_shape[3]
+    return (s_q % 128 == 0 and s_k % 128 == 0 and d % 64 == 0
+            and d_v % 64 == 0 and s_q == s_k and h % k_shape[2] == 0)
 
 
 def _clamp(block, seq):
@@ -458,18 +482,22 @@ def _synth_bh(shapes, dtypes):
     return out
 
 
-def _group_tag(group):
-    """A signature's suffix for grouped-query shapes; none for plain
-    multi-head attention, whose cached configurations stay valid."""
-    return "" if group == 1 else "|g%d" % group
+def _group_tag(group, d, d_v):
+    """A signature's suffix for grouped-query shapes and for value heads of
+    another size than the keys'; none for plain multi-head attention, whose
+    cached configurations stay valid."""
+    return ("" if group == 1 else "|g%d" % group) + (
+        "" if d_v == d else "|v%d" % d_v)
 
 
-def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
+def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1,
+                      d_v=None):
     """(block_q, block_k) for the forward kernel: deterministic defaults
     under interpret/CPU, autotuned (and cached) on TPU."""
     fallback = (_clamp(DEFAULT_BLOCK_Q, s_q), _clamp(DEFAULT_BLOCK_K, s_k))
     if interp:
         return fallback
+    d_v = d if d_v is None else d_v
     from ..autotune import get_tuner, shape_bucket, short_dtype, \
         source_version
     cands = list(dict.fromkeys(
@@ -478,7 +506,7 @@ def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
         return cands[0]
     sig = "fwd|bh%d|s%dx%d|d%d|%s|c%d" % (
         shape_bucket((bh,))[0], s_q, s_k, d, short_dtype(dtype), int(causal)
-    ) + _group_tag(group)
+    ) + _group_tag(group, d, d_v)
 
     def build(cand):
         return functools.partial(
@@ -487,7 +515,7 @@ def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
 
     def make_args():
         return _synth_bh([(bh, s_q, d), (bh // group, s_k, d),
-                          (bh // group, s_k, d)], [dtype] * 3)
+                          (bh // group, s_k, d_v)], [dtype] * 3)
 
     return get_tuner().get(
         "flash_attention", sig, candidates=cands, build=build,
@@ -495,7 +523,8 @@ def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
         version=source_version(__name__))
 
 
-def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
+def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1,
+                      d_v=None):
     """(block_q, block_k) for the one-pass backward kernel: the forward's
     deterministic defaults under interpret/CPU (whatever the dtype: the
     kernel's VMEM limit follows its shapes), autotuned (and cached) on TPU."""
@@ -504,6 +533,7 @@ def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
     fallback = clamp2((DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K))
     if interp:
         return fallback
+    d_v = d if d_v is None else d_v
     from ..autotune import get_tuner, shape_bucket, short_dtype, \
         source_version
     cands = list(dict.fromkeys(clamp2(c) for c in _BWD_CANDIDATES))
@@ -511,7 +541,7 @@ def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
         return cands[0]
     sig = "bwd|bh%d|s%dx%d|d%d|%s|c%d" % (
         shape_bucket((bh,))[0], s_q, s_k, d, short_dtype(dtype), int(causal)
-    ) + _group_tag(group)
+    ) + _group_tag(group, d, d_v)
 
     def build(cand):
         return functools.partial(
@@ -520,10 +550,10 @@ def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
 
     def make_args():
         args = _synth_bh(
-            [(bh, s_q, d), (bh // group, s_k, d), (bh // group, s_k, d),
-             (bh, s_q, d)], [dtype] * 4)
+            [(bh, s_q, d), (bh // group, s_k, d), (bh // group, s_k, d_v),
+             (bh, s_q, d_v)], [dtype] * 4)
         lse = jnp.zeros((bh, s_q), jnp.float32)
-        do = _synth_bh([(bh, s_q, d)], [dtype])[0]
+        do = _synth_bh([(bh, s_q, d_v)], [dtype])[0]
         return args + [lse, do]
 
     return get_tuner().get(
@@ -534,7 +564,7 @@ def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1):
 
 def flash_attention(q, k, v, causal=False, scale=1.0,
                     block_q=None, block_k=None, interpret=None):
-    """q,k,v: (B, S, H, D) -> (B, S, H, D). Forward only; use
+    """q, k: (B, S, H, D), v: (B, S, H, Dv) -> (B, S, H, Dv). Forward only; use
     flash_attention_vjp for the Pallas-backward pair (attention.py wires it
     through jax.custom_vjp). interpret=None resolves per call from placement
     (_interpret); pass an explicit bool when the caller already resolved it
@@ -555,7 +585,7 @@ def flash_attention_fwd(q, k, v, causal=False, scale=1.0,
     interp = _interpret(q) if interpret is None else interpret
     if block_q is None and block_k is None:
         bq, bk = _tuned_fwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp,
-                                   group=h // k.shape[2])
+                                   group=h // k.shape[2], d_v=v.shape[3])
     else:
         bq = _clamp(block_q or DEFAULT_BLOCK_Q, s)
         bk = _clamp(block_k or DEFAULT_BLOCK_K, s_k)
@@ -575,7 +605,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=1.0,
     interp = _interpret(q) if interpret is None else interpret
     if block_q is None and block_k is None:
         bq, bk = _tuned_bwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp,
-                                   group=h // k.shape[2])
+                                   group=h // k.shape[2], d_v=v.shape[3])
     else:
         bq, bk = block_q or DEFAULT_BLOCK_Q, block_k or DEFAULT_BLOCK_K
     dq, dk, dv = _flash_bwd_bh(

@@ -3,9 +3,13 @@ from .ernie import (  # noqa: F401
     ErnieConfig, ErnieForSequenceClassification, ErnieModel,
 )
 from .gpt import GPTForCausalLM, GPTModel  # noqa: F401
+from .kimi_linear import (  # noqa: F401
+    KimiLinearConfig, KimiLinearForCausalLM, KimiLinearModel,
+)
 from .lfm2 import LFM2Config, LFM2ForCausalLM, LFM2Model  # noqa: F401
 
 __all__ = ["BertModel", "BertForSequenceClassification", "GPTModel",
            "GPTForCausalLM", "ErnieConfig", "ErnieModel",
            "ErnieForSequenceClassification", "LFM2Config", "LFM2Model",
-           "LFM2ForCausalLM"]
+           "LFM2ForCausalLM", "KimiLinearConfig", "KimiLinearModel",
+           "KimiLinearForCausalLM"]

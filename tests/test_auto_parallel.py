@@ -22,6 +22,19 @@ NDEV = len(jax.devices())
 pytestmark = pytest.mark.skipif(NDEV < 8, reason="needs 8 virtual devices")
 
 
+@pytest.fixture(autouse=True)
+def _no_mesh_left_by_another_file():
+    """`Engine.prepare` refuses a global mesh it did not build. A test file
+    that ran earlier in the same worker may have left one active (which file
+    that is turns with the scheduling of `--dist loadfile`), so it is put
+    aside for the test and put back after."""
+    from paddle_tpu.distributed import mesh
+    saved = dict(mesh._STATE)
+    mesh._STATE.update(mesh=None, axis_degrees=None)
+    yield
+    mesh._STATE.update(saved)
+
+
 @pytest.fixture()
 def mesh2d():
     return ProcessMesh(np.arange(8).reshape(4, 2), dim_names=["x", "y"])

@@ -46,6 +46,7 @@ SUBSYSTEMS = [
     "disagg",        # disaggregated prefill/decode (serving/disagg.py)
     "integrity",     # SDC defense (checksum consensus, replay)
     "io",            # input pipeline / data workers
+    "kda",           # Kimi Delta Attention's chunked op (ops/kda.py)
     "metrics",       # the registry/exporter's own health
     "moe",           # expert layers: elastic expert parallelism
                      # (fleet/expert_parallel.py), routing load (incubate/moe.py)
