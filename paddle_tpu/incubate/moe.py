@@ -455,7 +455,10 @@ class DroplessMoELayer(nn.Layer):
         def plan(s, bias):
             out = _route_plan(s, bias, top_k=k, lookup=self._lookup,
                               n_held=len(self.held_experts), tm=tm, rows=rows)
-            return out + (row_moves.held_pairs(out[1], rows) if kernel else ())
+            if kernel:
+                out += row_moves.held_pairs(out[1], rows, row_moves.token_block(
+                    n, k, self.d_model, xf._val.dtype))
+            return out
         (idx, pair_row, row_pair, row_valid, tile_group, num_tiles,
          counts, *held) = apply(plan, scores.detach(), self.expert_bias,
                                 name="moe_route")

@@ -16,13 +16,18 @@ run-time value:
     pair_dots:        out[t, j] = <y[pair_row[t, j]], g[t]>
                       (a pair with pair_row >= R adds nothing)
 
-Why the packed form. A copy may slice a tiled array only in whole tiles, and
-in (R, H) a tile is eight rows (sixteen of bfloat16) by 128 columns: one row
-is no slice of it. So the array a row is read from by index is the row's C
-32-bit words laid over C / 128 sublane rows of 128: (R * C / 128, 128)
-uint32, where row r is the rows r * C / 128 onward and, C being a multiple of
-1024, whole tiles. C = H for float32; H / 2 for bfloat16, word c of a row
-holding column c in its low half and column c + C in its high half.
+Why the packed form. A copy takes whole sublane rows of a tiled array, and
+in (R, H) a sublane row of a tile is 128 columns of one row (of two rows in
+bfloat16): a row is H / 128 pieces in as many tiles, no slice a copy takes.
+So the array a row is read from by index is the row's C 32-bit words laid
+over C / 128 sublane rows of 128, its lane chunks: (R * C / 128, 128) uint32,
+where row r is the sublane rows r * C / 128 onward, contiguous, and one copy
+whatever C / 128 is: a copy need not start or end on an (8, 128) tile (the
+chip, PERF.md PR 38: 9 sublane rows from any offset are moved right, and
+sooner than 16 from a tile's start). C = H for float32; H / 2 for bfloat16,
+word c of a row holding column c in its low half and column c + C in its
+high half; C is any multiple of 128 (`words`), and the packed form is the
+array's bytes.
 `pack_rows` makes it from the (R, H) array in VMEM, for the tiles in use, and
 the kernels that read rows by index unpack in VMEM (shifts and bitcasts:
 exact), so what they write is an ordinary (., H) array again. Between the
@@ -47,20 +52,21 @@ from .grouped_matmul import ROW_TILE
 TOKEN_BLOCK = 256   # tokens of one grid step of the kernels that write tokens
 CHUNK = 32          # rows unpacked at a time: what the vector registers hold
 UNROLL = 8          # copies the scalar core issues a trip of its loop
+LANE_BODIES = 32    # lane chunks x picks a chunk's text holds as copies of its body
 VMEM_LIMIT_BYTES = 32 * 2 ** 20
 
 
 def words(h, dtype):
     """32-bit words of one packed row of `h` elements, or None where the
     kernels do not take the row: other types than float32 and bfloat16, or a
-    row that is not whole (8, 128) tiles of words (a copy may not split one)."""
+    row that is not whole lane chunks of 128 words."""
     if dtype == jnp.float32:
         c = h
     elif dtype == jnp.bfloat16 and h % 2 == 0:
         c = h // 2
     else:
         return None
-    return c if c % 1024 == 0 else None
+    return c if c % 128 == 0 else None
 
 
 def _params(interpret):
@@ -92,16 +98,38 @@ def _unpack(u, dtype):
 
 
 def _row(ref, r, per_row):
-    """Packed row r of a packed ref: `per_row` whole sublane rows."""
+    """Packed row r of a packed ref: `per_row` sublane rows."""
     return ref.at[pl.ds(pl.multiple_of(r * per_row, per_row), per_row), :]
 
 
-def _lane_chunks(ref, first, n, per_row):
-    """Rows first..first+n of a packed ref as `per_row` views (n, 128): lane
-    chunk q of every row. Rows r, r+1, ... of chunk q lie `per_row` sublane
-    rows apart, which is one strided access and no shuffle."""
-    return [ref.at[pl.ds(first * per_row + q, n, stride=per_row), :]
-            for q in range(per_row)]
+def _cols(start):
+    """The 128 columns from `start`, a multiple of 128."""
+    if isinstance(start, int):
+        return slice(start, start + 128)
+    return pl.ds(pl.multiple_of(start, 128), 128)
+
+
+def _over_lanes(ref, picks, first, per_row, body, carry=None):
+    """carry = body(q, views, carry) for every lane chunk q of a packed row:
+    views[j] is chunk q of the CHUNK rows of the packed `ref` from row
+    first(j), (CHUNK, 128). Rows r, r+1, ... of a chunk lie `per_row` sublane
+    rows apart, which is one strided access and no shuffle. The kernel's
+    text holds a copy of the body a lane chunk while those are at most
+    LANE_BODIES with `picks` views each, and beyond that one body in a loop,
+    q a run-time value: the text is what the host traces and lowers, in the
+    discovery pass and twice in the step, and it would grow with the picks
+    times the row's width."""
+    def view(at, q):
+        return ref.at[pl.ds(at * per_row + q, CHUNK, stride=per_row), :]
+    if picks * per_row <= LANE_BODIES:
+        views = [[view(at, q) for q in range(per_row)]
+                 for at in map(first, range(picks))]
+        for q in range(per_row):
+            carry = body(q, [of_pick[q] for of_pick in views], carry)
+        return carry
+    return jax.lax.fori_loop(
+        0, per_row,
+        lambda q, c: body(q, [view(first(j), q) for j in range(picks)], c), carry)
 
 
 def _wait(count, buf, sem, per_row):
@@ -154,10 +182,12 @@ def _pack_kernel(x_ref, o_ref):
 
     def chunk(s):
         at = pl.ds(s, CHUNK)
-        for q, out in enumerate(_lane_chunks(o_ref, s, CHUNK, per_row)):
-            halves = [x_ref[at, p * c + q * 128:p * c + (q + 1) * 128]
+
+        def lane(q, views, _):
+            halves = [x_ref[at, _cols(p * c + q * 128)]
                       for p in range(4 // x_ref.dtype.itemsize)]
-            out[...] = _pack2(*halves)
+            views[0][...] = _pack2(*halves)
+        _over_lanes(o_ref, 1, lambda _: s, per_row, lane)
     _chunks(x_ref.shape[0], chunk)
 
 
@@ -214,10 +244,11 @@ def _from_tokens_kernel(row_pair_ref, tile_rows_ref, *rest, tm, k, dtype, scaled
 
     def chunk(s):
         keep = s + jax.lax.broadcasted_iota(jnp.int32, (CHUNK, 128), 0) < count
-        for q, words_ in enumerate(_lane_chunks(buf, s, CHUNK, per_row)):
-            for p, v in enumerate(_unpack(jnp.where(keep, words_[...], 0), dtype)):
-                o_ref[pl.ds(s, CHUNK), p * c + q * 128:p * c + (q + 1) * 128] = \
-                    v.astype(dtype)
+
+        def lane(q, views, _):
+            for p, v in enumerate(_unpack(jnp.where(keep, views[0][...], 0), dtype)):
+                o_ref[pl.ds(s, CHUNK), _cols(p * c + q * 128)] = v.astype(dtype)
+        _over_lanes(buf, 1, lambda _: s, per_row, lane)
     _chunks(tm, chunk)
 
 
@@ -289,13 +320,12 @@ def _to_tokens_kernel(held_pair_ref, held_row_ref, block_start_ref, y_hbm,
         if weighted:
             w = [jnp.broadcast_to(w_ref[at, j:j + 1], (CHUNK, 128)) for j in range(k)]
         dot = [jnp.zeros((CHUNK, 128), jnp.float32) for _ in range(k)]
-        chunks = [_lane_chunks(buf, j * tb + s, CHUNK, per_row) for j in range(k)]
-        for q in range(per_row):
-            cols = [slice(p * c + q * 128, p * c + (q + 1) * 128)
-                    for p in range(4 // dtype.itemsize)]
+
+        def lane(q, views, dot):
+            cols = [_cols(p * c + q * 128) for p in range(4 // dtype.itemsize)]
             total = None
             for j in range(k):
-                halves = _unpack(jnp.where(keep[j], chunks[j][q][...], 0), dtype)
+                halves = _unpack(jnp.where(keep[j], views[j][...], 0), dtype)
                 if dots:
                     dot[j] = dot[j] + sum(
                         v * g_ref[at, col].astype(jnp.float32)
@@ -308,26 +338,37 @@ def _to_tokens_kernel(held_pair_ref, held_row_ref, block_start_ref, y_hbm,
             if not dots:
                 for v, col in zip(total, cols):
                     o_ref[at, col] = v.astype(dtype)
+            return dot
+        dot = _over_lanes(buf, k, lambda j: j * tb + s, per_row, lane,
+                          dot if dots else [])
         if dots:
             o_ref[at, :] = jnp.concatenate(
                 [jnp.sum(d, axis=1, keepdims=True) for d in dot], axis=1)
     _chunks(tb, chunk)
 
 
-def _token_block(n):
-    return TOKEN_BLOCK if n >= TOKEN_BLOCK else -(-n // CHUNK) * CHUNK
+def token_block(n, k, h, dtype):
+    """Tokens of one grid step of the kernels that write tokens, of `n` with
+    `k` pairs each and rows of `h` elements: TOKEN_BLOCK, halved while the
+    k packed rows a token of the block would take more than half of
+    VMEM_LIMIT_BYTES (the operands' blocks, held twice, take the rest), and
+    no more than the tokens there are, in whole chunks."""
+    tb = TOKEN_BLOCK
+    while tb > CHUNK and k * tb * words(h, dtype) * 4 > VMEM_LIMIT_BYTES // 2:
+        tb //= 2
+    return tb if n >= tb else -(-n // CHUNK) * CHUNK
 
 
-@functools.partial(jax.jit, static_argnames=("rows",))
-def held_pairs(pair_row, rows):
-    """The pairs that have a row, listed for the kernels that write tokens:
-    (held_pair, held_row, block_start). held_pair holds the pairs t * k + j
-    with pair_row[t, j] < rows, in their order, then the others; held_row the
-    row of each; block_start[b] where the pairs of token block b start in
-    both, block_start[-1] their number. One stable sort of N * k keys that
-    take two values and a sum: computed once per plan, beside it."""
+@functools.partial(jax.jit, static_argnames=("rows", "tb"))
+def held_pairs(pair_row, rows, tb):
+    """The pairs that have a row, listed for the kernels that write tokens
+    `tb` = token_block(...) at a time: (held_pair, held_row, block_start).
+    held_pair holds the pairs t * k + j with pair_row[t, j] < rows, in their
+    order, then the others; held_row the row of each; block_start[b] where
+    the pairs of token block b start in both, block_start[-1] their number.
+    One stable sort of N * k keys that take two values and a sum: computed
+    once per plan, beside it."""
     n, k = pair_row.shape
-    tb = _token_block(n)
     flat = jnp.pad(pair_row, ((0, -n % tb), (0, 0)), constant_values=rows).reshape(-1)
     absent = (flat >= rows).astype(jnp.int32)
     _, held_pair, held_row = jax.lax.sort(
@@ -345,8 +386,9 @@ def _to_tokens(packed_y, pair_row, held, w, g, h, dtype, interpret):
     c = words(h, dtype)
     assert c, (h, dtype)
     rows = packed_y.shape[0] * 128 // c
-    tb = _token_block(n)
+    tb = token_block(n, k, h, dtype)
     pad = -n % tb
+    assert held[2].shape == ((n + pad) // tb + 1,), (held[2].shape, n, tb)
     if pad:   # whole blocks: the tokens added hold no pair
         pair_row = jnp.pad(pair_row, ((0, pad), (0, 0)), constant_values=rows)
         w = None if w is None else jnp.pad(w, ((0, pad), (0, 0)))
@@ -384,8 +426,8 @@ def _to_tokens(packed_y, pair_row, held, w, g, h, dtype, interpret):
 def tokens_from_rows(packed_y, pair_row, held, w=None, *, h, dtype, interpret=False):
     """packed_y packed rows (R * C / 128, 128); pair_row (N, k) the row of each of a
     token's pairs, R or more where there is none; held = held_pairs(pair_row,
-    R); w (N, k) float32 or None -> (N, H) in `dtype`: out[t] = sum_j w[t, j]
-    * y[pair_row[t, j]], a float32 sum."""
+    R, token_block(N, k, h, dtype)); w (N, k) float32 or None -> (N, H) in
+    `dtype`: out[t] = sum_j w[t, j] * y[pair_row[t, j]], a float32 sum."""
     return _to_tokens(packed_y, pair_row, held, w, None, h, dtype, interpret)
 
 

@@ -283,26 +283,25 @@ def test_grouped_products_compile_at_lfm2_widths(one_chip, k, n):
     _assert_kernel(gm.tgmm.lower(x, dy, tiles, used, groups=groups).compile(), 1)
 
 
-@pytest.mark.parametrize("move", ["pack", "gather", "weighted-rows", "add-back",
-                                  "combine", "pair-dots"])
-def test_row_moves_compile_at_lfm2_widths(one_chip, move):
-    """The expert layer's row moves at the cell's shapes: a buffer of 34,816
-    rows of 2048 bf16, 8192 tokens, 4 picks, tiles of 256; the grid or the
-    loop bound a run-time value. A refusal by Mosaic (a slice off the tiling,
-    scalar memory, VMEM) fails here and not on the chip."""
+ROW_MOVES = ["pack", "gather", "weighted-rows", "add-back", "combine", "pair-dots"]
+
+
+def _compile_row_move(one_chip, move, n, k, h, dt):
+    """One of the expert layer's row moves compiled for `n` tokens of `h`
+    columns, `k` picks a token, 8 experts held, tiles of 256; the grid or the
+    loop bound a run-time value. Returns the bytes of the kernel's scratch."""
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import grouped_matmul as gm
     from paddle_tpu.ops.pallas import row_moves as rm
-    n, k, h, dt = 2 * 4096, 4, 2048, jnp.bfloat16
     rows = n * k + 8 * gm.ROW_TILE
-    per_row = rm.words(h, dt) // 128
+    per_row, tb = rm.words(h, dt) // 128, rm.token_block(n, k, h, dt)
     i32 = jnp.int32
     tokens3, rows3 = (_sds((r * per_row, 128), jnp.uint32, one_chip) for r in (n, rows))
     row_pair, tile_rows = _sds((rows,), i32, one_chip), _sds((rows // gm.ROW_TILE,), i32, one_chip)
     used, pair_row = _sds((), i32, one_chip), _sds((n, k), i32, one_chip)
     w = _sds((n, k), jnp.float32, one_chip)
     held = (_sds((n * k,), i32, one_chip), _sds((n * k,), i32, one_chip),
-            _sds((n // rm.TOKEN_BLOCK + 1,), i32, one_chip))
+            _sds((n // tb + 1,), i32, one_chip))
     lowered = {
         "pack": lambda: rm.pack_rows.lower(_sds((rows, h), dt, one_chip), used),
         "gather": lambda: rm.rows_from_tokens.lower(
@@ -318,6 +317,19 @@ def test_row_moves_compile_at_lfm2_widths(one_chip, move):
     want = {"pack": (rows * per_row, 128), "gather": (rows, h), "weighted-rows": (rows, h),
             "add-back": (n, h), "combine": (n, h), "pair-dots": (n, k)}[move]
     assert compiled.out_info.shape == want
+    scratch_rows = {"pack": 0, "gather": gm.ROW_TILE, "weighted-rows": gm.ROW_TILE}.get(
+        move, k * tb)
+    return scratch_rows * per_row * 128 * 4
+
+
+@pytest.mark.parametrize("move", ROW_MOVES)
+def test_row_moves_compile_at_lfm2_widths(one_chip, move):
+    """The expert layer's row moves at the cell's shapes: a buffer of 34,816
+    rows of 2048 bf16, 8192 tokens, 4 picks, tiles of 256; the grid or the
+    loop bound a run-time value. A refusal by Mosaic (a slice off the tiling,
+    scalar memory, VMEM) fails here and not on the chip."""
+    import jax.numpy as jnp
+    _compile_row_move(one_chip, move, 2 * 4096, 4, 2048, jnp.bfloat16)
 
 
 # ---------------------------------------------------------------------------
@@ -372,6 +384,31 @@ def test_grouped_products_compile_at_kimi_linear_widths(one_chip, k, n):
     _assert_kernel(gm.gmm.lower(x, w, tiles, used).compile(), 1)
     _assert_kernel(gm.gmm.lower(dy, w, tiles, used, transpose_w=True).compile(), 1)
     _assert_kernel(gm.tgmm.lower(x, dy, tiles, used, groups=groups).compile(), 1)
+
+
+@pytest.mark.parametrize("move", ROW_MOVES)
+def test_row_moves_compile_at_kimi_linear_widths(one_chip, move):
+    """The row moves at the Kimi Linear cell's shapes: a buffer of 67,584 rows
+    of 2304 bf16 (9 lane chunks a row: a copy of one neither starts nor ends
+    on a tile), 8192 tokens, 8 picks. The scratch of the kernels that write
+    tokens, 8 x 256 packed rows, stays under the limit the kernels ask for."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import row_moves as rm
+    assert rm.words(2304, jnp.bfloat16) == 1152
+    scratch = _compile_row_move(one_chip, move, 2 * 4096, 8, 2304, jnp.bfloat16)
+    assert scratch <= rm.VMEM_LIMIT_BYTES // 2      # 9 MiB where tokens are written
+
+
+@pytest.mark.parametrize("move", ["combine", "pair-dots"])
+def test_the_token_block_follows_the_picks_and_the_rows_bytes(one_chip, move):
+    """8 picks of float32 rows of 4096: 256 tokens a grid step would be 32 MiB
+    of packed rows, the whole limit; `token_block` halves the block and the
+    kernels that write tokens compile."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import row_moves as rm
+    assert rm.token_block(1024, 8, 4096, jnp.float32) == 128
+    scratch = _compile_row_move(one_chip, move, 1024, 8, 4096, jnp.float32)
+    assert scratch == rm.VMEM_LIMIT_BYTES // 2
 
 
 def test_kda_op_compiles_with_its_backward(one_chip):
