@@ -353,7 +353,9 @@ class DroplessMoELayer(nn.Layer):
     mixture models use it).
 
     Every token is scored over all `num_experts` (the published count):
-    s = sigmoid(x W_g) in float32. The `top_k` experts are the largest of
+    s = sigmoid(x W_g) in float32, or with `score="softmax"` the softmax of
+    x W_g over all of them (Qwen3-MoE-style routing: the weights are then the
+    softmax's values renormalised over the picked). The `top_k` experts are the largest of
     s + expert_bias (a parameter that takes no gradient; a balancing rule
     outside this layer may move it); their weights are the un-biased scores
     normalised over the k picked, times `routed_scaling_factor`. The layer
@@ -366,7 +368,14 @@ class DroplessMoELayer(nn.Layer):
     what an expert-parallel rank computes before the exchange. With
     `shared_width` a shared expert, one more SwiGLU every token goes through
     (scopes `linear`, `swiglu`), is added after the combine; every rank holds
-    it whole, so the shares add up to the layer with it counted once. There is no
+    it whole, so the shares add up to the layer with it counted once.
+    `absent="stand_in"` (default "drop": the above) gives an expert that is
+    not held here the held expert of slot (its id mod the number held) as its
+    stand-in, so the sum runs over all k picked and the rows here are tokens
+    x k whatever the router picks: the rows a rank of a deployment is sent by
+    all the ranks when routing is even, computed with the weights it has.
+    That is no share of the published layer (experts a stride apart then
+    share weights). There is no
     capacity and no dropped pair: the (token, expert) pairs are sorted by
     expert into a buffer sized for the worst routing (every pair held here),
     each held expert's rows tile-aligned, and one grouped product per
@@ -386,9 +395,19 @@ class DroplessMoELayer(nn.Layer):
 
     def __init__(self, d_model, d_hidden, num_experts, top_k,
                  held_experts=None, routed_scaling_factor=1.0,
-                 weight_attr=None, shared_width=None):
+                 weight_attr=None, shared_width=None, score="sigmoid",
+                 absent="drop"):
         super().__init__()
         from ..ops.pallas.grouped_matmul import ROW_TILE
+        if score not in ("sigmoid", "softmax"):
+            from ..framework.errors import InvalidArgumentError
+            raise InvalidArgumentError(
+                f"score {score!r} is neither 'sigmoid' nor 'softmax'")
+        if absent not in ("drop", "stand_in"):
+            from ..framework.errors import InvalidArgumentError
+            raise InvalidArgumentError(
+                f"absent {absent!r} is neither 'drop' nor 'stand_in'")
+        self.score, self.absent = score, absent
         held = list(range(num_experts)) if held_experts is None \
             else [int(e) for e in held_experts]
         if len(set(held)) != len(held) or not all(0 <= e < num_experts for e in held):
@@ -400,7 +419,9 @@ class DroplessMoELayer(nn.Layer):
         self.held_experts = held
         self.routed_scaling_factor = routed_scaling_factor
         self._row_tile = ROW_TILE
-        self._lookup = np.full(num_experts, -1, np.int32)
+        # published expert -> the slot of the stacked weights that computes it
+        self._lookup = np.full(num_experts, -1, np.int32) if absent == "drop" \
+            else (np.arange(num_experts) % len(held)).astype(np.int32)
         self._lookup[held] = np.arange(len(held), dtype=np.int32)
         self.gate = nn.Linear(d_model, num_experts, weight_attr=weight_attr,
                               bias_attr=False)
@@ -442,14 +463,21 @@ class DroplessMoELayer(nn.Layer):
         n, k, tm = xf.shape[0], self.top_k, self._row_tile
         rows = self.buffer_rows(n)
         scale = self.routed_scaling_factor
+        # sigmoid scores may all be near 0; a softmax's top k never sum to 0
+        norm_eps = 1e-6 if self.score == "sigmoid" else 0.0
         # off the TPU the grouped products run interpreted and the row moves
         # are XLA's; on it both are kernels, where the moves take the width
         interp = _interpret(xf._val)
         kernel = not interp and bool(row_moves.words(self.d_model, xf._val.dtype))
 
-        def score(v, wg):
-            return jax.nn.sigmoid(jnp.matmul(
-                v, wg, preferred_element_type=jnp.float32))
+        if self.score == "sigmoid":
+            def score(v, wg):
+                return jax.nn.sigmoid(jnp.matmul(
+                    v, wg, preferred_element_type=jnp.float32))
+        else:
+            def score(v, wg):
+                return jax.nn.softmax(jnp.matmul(
+                    v, wg, preferred_element_type=jnp.float32), axis=-1)
         scores = apply(score, xf, self.gate.weight, name="moe_route")
 
         def plan(s, bias):
@@ -469,7 +497,7 @@ class DroplessMoELayer(nn.Layer):
             # tables; its transpose is then no scatter either
             chosen = picked[:, :, None] == jnp.arange(s.shape[1], dtype=picked.dtype)
             w = jnp.sum(jnp.where(chosen, s[:, None, :], 0), axis=2)
-            return w / (jnp.sum(w, axis=1, keepdims=True) + 1e-6) * scale
+            return w / (jnp.sum(w, axis=1, keepdims=True) + norm_eps) * scale
         w = apply(weigh, scores, idx, name="moe_route")
         xs = apply(lambda v, rp, rv, pr, nt, *hp: _gather_rows(
             v, rp, rv, pr, hp, nt, tm, kernel),

@@ -27,15 +27,56 @@ def sequence_mask(lengths, maxlen=None, dtype="int64", name=None):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, name=None):
+                                 training=True, name=None, key_set=None,
+                                 return_lse=False):
     """Fused attention entry point (reference: operators/fused/fused_attention).
 
     Shapes: (batch, seq, heads, head_dim) — paddle convention. Uses the Pallas
     flash-attention kernel when available on TPU, else the XLA softmax path.
+    `key_set` gives every query the keys it attends to: (batch, seq, seq),
+    or whatever `sparse_attention_index` returned; `return_lse` gives (out,
+    the logsumexp of each query's scores (batch, heads, seq)).
     """
     from ...ops.attention import scaled_dot_product_attention as sdpa
     return sdpa(query, key, value, attn_mask=attn_mask, dropout_p=dropout_p,
-                is_causal=is_causal, training=training)
+                is_causal=is_causal, training=training, key_set=key_set,
+                return_lse=return_lse)
+
+
+def sparse_attention_index(q_index, k_index, weights, topk, name=None):
+    """The keys each query attends to under a learned sparse-attention index
+    (DeepSeek Sparse Attention; ops/sparse_index.py): the `topk` causal keys
+    with the largest I[t, s] = sum_j weights[t, j] relu(q_index[t, j] .
+    k_index[s]), every causal key where a row holds at most `topk`; ties at
+    the threshold are all kept.
+
+    Shapes: q_index (batch, seq, heads, d), k_index (batch, seq, d), weights
+    (batch, seq, heads). Returns (key_set for
+    `scaled_dot_product_attention(..., key_set=)` and
+    `sparse_attention_index_loss`: (batch, seq, seq) int8, or on a TPU, where
+    the index runs as kernels, the pair (sets, table) in the layout the
+    attention and loss kernels read; stats (3,) float32: pairs selected,
+    tiles under the diagonal with none, queries). No gradient flows through
+    any of them.
+    """
+    from ...ops.sparse_index import sparse_attention_index as index
+    return index(q_index, k_index, weights, topk)
+
+
+def sparse_attention_index_loss(q_index, k_index, weights, key_set, query, key,
+                                scale=None, lse=None, name=None):
+    """The index's training loss: KL from the main attention's probabilities
+    over `key_set` (softmax of query . key * scale over the set, averaged
+    over the heads, gradient stopped) to the softmax of the index scores
+    over the same set, averaged over the positions. A float32 scalar that
+    differentiates in q_index, k_index and weights only. `lse` is the main
+    attention's logsumexp over the sets (`scaled_dot_product_attention(...,
+    return_lse=True)`): with it the probabilities are formed tile by tile in
+    a kernel on a TPU; without it, or elsewhere, by whole rows in XLA.
+    """
+    from ...ops.sparse_index import sparse_attention_index_loss as loss
+    return loss(q_index, k_index, weights, key_set, query, key, scale=scale,
+                lse=lse)
 
 
 def embedding_renorm_(*args, **kwargs):

@@ -80,19 +80,41 @@ def alpha_dropout(x, p=0.5, training=True, name=None):
 
 
 def rotary_position_embedding(q, k, theta=10000.0, position_offset=0,
-                              name=None):
+                              name=None, position_ids=None, sections=None):
     """Rotary positions (Su et al. 2021) on `q` and `k`, each (batch, seq,
     heads, head_dim), in the rotate-half convention over the whole head:
     entry i pairs with entry i + head_dim/2 and the pair at position t turns
     by t * theta^(-2i/head_dim). Angles and the rotation are float32; the
     results keep their dtypes. `position_offset` is the position of the
-    first row (a cached decode step)."""
-    def prim(qv, kv):
+    first row (a cached decode step).
+
+    `position_ids` gives the positions instead: (batch, seq), or (streams,
+    batch, seq) with `sections`, how many of the head_dim / 2 frequency pairs
+    each stream turns, in order (multimodal rotary positions: (16, 24, 24)
+    gives pairs 0-15 the first stream's position, 16-39 the second's, 40-63
+    the third's). Equal streams give what one stream gives."""
+    def prim(qv, kv, *pos):
         d, s = qv.shape[-1], qv.shape[1]
         inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-        t = jnp.arange(position_offset, position_offset + s, dtype=jnp.float32)
-        angle = jnp.concatenate([t[:, None] * inv[None, :]] * 2, axis=-1)
-        cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+        if not pos:
+            t = jnp.arange(position_offset, position_offset + s, dtype=jnp.float32)
+            angle = jnp.concatenate([t[:, None] * inv[None, :]] * 2, axis=-1)
+            cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+        else:
+            t = pos[0].astype(jnp.float32)
+            if t.ndim == 3:
+                parts = (t.shape[0],) if sections is None else tuple(sections)
+                if len(parts) != t.shape[0] or sum(parts) != d // 2:
+                    raise ValueError(
+                        f"sections {sections} do not split {d // 2} frequency "
+                        f"pairs over {t.shape[0]} position streams")
+                stream = np.repeat(np.arange(len(parts)), parts)
+                # (batch, seq, pairs): pair i reads its stream's position
+                t = jnp.moveaxis(t, 0, -1)[..., stream]
+            else:
+                t = t[..., None]
+            angle = jnp.concatenate([t * inv] * 2, axis=-1)[:, :, None, :]
+            cos, sin = jnp.cos(angle), jnp.sin(angle)
 
         def turn(v):
             f = v.astype(jnp.float32)
@@ -101,7 +123,8 @@ def rotary_position_embedding(q, k, theta=10000.0, position_offset=0,
 
         return turn(qv), turn(kv)
 
-    return apply(prim, q, k, name="rope")
+    extra = [] if position_ids is None else [position_ids]
+    return apply(prim, q, k, *extra, name="rope")
 
 
 def embedding(x, weight, padding_idx=None, sparse=False, name=None):

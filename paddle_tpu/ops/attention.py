@@ -31,7 +31,8 @@ FLASH_MIN_SEQ_Q = 256
 XLA_SCORE_TENSORS = 4
 
 
-def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, dropout_key):
+def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, dropout_key,
+                   return_lse=False):
     # q,k,v: (B, S, H, D) paddle layout -> compute in (B, H, S, D)
     group = q.shape[2] // k.shape[2]
     if group > 1:
@@ -55,19 +56,34 @@ def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, dropout_key):
     if dropout_p > 0.0 and dropout_key is not None:
         keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0).astype(probs.dtype)
-    out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-    return jnp.swapaxes(out, 1, 2)
+    out = jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", probs, v), 1, 2)
+    if return_lse:
+        return out, jax.lax.stop_gradient(
+            jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1))
+    return out
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, use_pallas=None, scale=None):
+                                 training=True, use_pallas=None, scale=None,
+                                 key_set=None, return_lse=False):
     """(batch, seq, heads, head_dim) attention. `key` and `value` may hold
     fewer heads than `query`, a divisor of its count (grouped-query
     attention): query head h reads key/value head h // (heads / kv_heads).
     `value`'s heads may be of another size than `query`'s and `key`'s (latent
     attention: 192 against 128); the result has value's head size and the
     default scale is the query/key size's.
+    `key_set` (batch, seq_q, seq_k), integer or bool, gives every query the
+    keys it attends to (not 0), the same for all heads: a learned sparse
+    index's choice (`F.sparse_attention_index`). With `is_causal` a key after
+    its query is out whatever the set says; every query must keep a key. The
+    softmax and the gradients are over the set; the set takes none. Where
+    the index ran as kernels its `key_set` is the pair (sets, table) in the
+    set kernels' own layout, handed on as it is: it is causal already, and
+    only XLA's attention forms the square from it.
+    `return_lse` gives (out, lse): the logsumexp of each query's scaled scores
+    over the keys it attends to, (batch, heads, seq) float32, no gradient:
+    what forms the probabilities again (`F.sparse_attention_index_loss`).
     `use_pallas=None` lets `takes_flash` choose the path from the shapes;
     True or False is the caller's own choice (True with a mask or dropout
     raises). The path a call took is counted: `attention.flash_total`,
@@ -90,13 +106,24 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     if use_pallas is None:
         use_pallas = takes_flash(qv.shape, unwrap(key).shape, qv.dtype,
                                  attn_mask is not None, dropout_p,
-                                 _platform(), unwrap(value).shape)
+                                 _platform(), unwrap(value).shape,
+                                 key_set=key_set is not None,
+                                 on_mesh=key_set is not None
+                                 and _on_mesh(qv))
     elif use_pallas and (attn_mask is not None or dropout_p > 0.0):
         raise ValueError(
             "use_pallas=True is incompatible with attn_mask/dropout_p: the "
             "flash kernel computes plain (optionally causal) attention")
     _metrics.get_registry().inc_counter(
         "attention.flash_total" if use_pallas else "attention.xla_total")
+    tiled = isinstance(key_set, (tuple, list))
+    if use_pallas and key_set is not None:
+        out, lse = apply(_flash_set_prim(qv, is_causal, scale), query, key,
+                         value, *(key_set if tiled else [key_set]),
+                         name="flash_attention")
+        return (out, lse.detach()) if return_lse else out
+    if return_lse and use_pallas:
+        raise ValueError("return_lse: the plain flash pair keeps its logsumexp")
     if use_pallas:
         return apply(_flash_prim(qv, is_causal, scale), query, key, value,
                      name="flash_attention")
@@ -104,14 +131,30 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     def prim(q, k, v, *rest):
         rest = list(rest)
         kd = rest.pop() if dropout_kd is not None else None
+        picked = None
+        if key_set is not None:
+            from .pallas.flash_attention import set_square
+            table = rest.pop() if tiled else None
+            picked = set_square((rest.pop(), table)) if tiled else rest.pop()
         m = rest[0] if rest else None
+        if picked is not None:
+            # the set as a boolean mask over the heads; an additive mask
+            # beside it is folded into one
+            in_set = (picked != 0)[:, None]
+            m = in_set if m is None else jnp.where(
+                in_set, m if m.dtype != jnp.bool_ else jnp.where(m, 0.0, -1e30),
+                -1e30)
         dk = jax.random.wrap_key_data(kd) if kd is not None else None
-        return _xla_attention(q, k, v, m, scale, is_causal, dropout_p, dk)
+        return _xla_attention(q, k, v, m, scale, is_causal, dropout_p, dk,
+                              return_lse)
 
     extra = [attn_mask] if attn_mask is not None else []
+    if key_set is not None:
+        extra.extend(key_set if tiled else [key_set])
     if dropout_kd is not None:
         extra.append(dropout_kd)
-    return apply(prim, query, key, value, *extra, name="sdpa")
+    out = apply(prim, query, key, value, *extra, name="sdpa")
+    return (out[0], out[1].detach()) if return_lse else out
 
 
 def _flash_prim(qv, is_causal, scale):
@@ -150,6 +193,52 @@ def _flash_prim(qv, is_causal, scale):
                      check_rep=False)
 
 
+def _flash_set_prim(qv, is_causal, scale):
+    """The flash pair over a set a query (ops/pallas/flash_attention.py) for
+    operands like `qv`, on one device: the interpret decision is baked as in
+    `_flash_prim`. A square set has `is_causal` folded into it by one XLA
+    pass; a (sets, table) pair is the index kernel's, causal as written, and
+    goes to the kernels untouched."""
+    from .pallas.flash_attention import _interpret
+    interp = _interpret(qv)
+
+    def prim(q, k, v, *picked):
+        if len(picked) == 2:
+            return _flash_set_diff(q, k, v, picked, scale, interp)
+        picked = (picked[0] != 0)
+        if is_causal:
+            s_q, s_k = picked.shape[1:]
+            picked = picked & jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
+        return _flash_set_diff(q, k, v, picked.astype(jnp.int8), scale, interp)
+    return prim
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash_set_diff(q, k, v, picked, scale, interpret):
+    """(out, lse (B, H, S) float32); lse carries no gradient. `picked`: the
+    square set, or the kernels' (sets, table)."""
+    from .pallas.flash_attention import flash_attention_set_fwd
+    return flash_attention_set_fwd(q, k, v, picked, scale=scale,
+                                   interpret=interpret)[:2]
+
+
+def _flash_set_fwd(q, k, v, picked, scale, interpret):
+    from .pallas.flash_attention import flash_attention_set_fwd
+    out, lse, tiles = flash_attention_set_fwd(q, k, v, picked, scale=scale,
+                                              interpret=interpret)
+    return (out, lse), (q, k, v, out, lse, tiles)
+
+
+def _flash_set_bwd(scale, interpret, res, g):
+    from .pallas.flash_attention import flash_attention_set_bwd
+    q, k, v, out, lse, tiles = res
+    return (*flash_attention_set_bwd(q, k, v, out, lse, g[0], tiles,
+                                     scale=scale, interpret=interpret), None)
+
+
+_flash_set_diff.defvjp(_flash_set_fwd, _flash_set_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_attention_diff(q, k, v, is_causal, scale, interpret):
     """Pallas flash attention, forward AND backward.
@@ -182,21 +271,31 @@ _flash_attention_diff.defvjp(_flash_fwd, _flash_bwd)
 
 
 def takes_flash(q_shape, k_shape, dtype, masked, dropout_p, platform,
-                v_shape=None):
+                v_shape=None, key_set=False, on_mesh=False):
     """Whether attention over operands of these (batch, seq, heads, head_dim)
     shapes runs the flash kernel pair: on a TPU, plain or causal attention
     (no mask, no dropout) of a floating type over shapes the kernels tile,
     from FLASH_MIN_SEQ_Q query positions, where the keys are at least
     FLASH_MIN_SEQ_K long or XLA's attention could not hold its scores.
     Everything else runs XLA's attention. `v_shape` where the value heads
-    are of another size than the keys'."""
+    are of another size than the keys'. A set of keys a query (`key_set`)
+    is no mask in this sense: the pair takes it by the same rule, except
+    where the operands are spread over a mesh (`on_mesh`: the kernels over a
+    set are not mapped over one), and XLA's attention reads it as a boolean
+    mask where the rule says no."""
     if (platform != "tpu" or masked or dropout_p > 0.0
+            or (key_set and on_mesh)
             or q_shape[1] < FLASH_MIN_SEQ_Q
             or not jnp.issubdtype(dtype, jnp.floating)
             or not _pallas_supports(q_shape, k_shape, v_shape)):
         return False
     return k_shape[1] >= FLASH_MIN_SEQ_K or not _xla_holds_its_scores(
         q_shape, k_shape)
+
+
+def _on_mesh(value):
+    from ..distributed.mesh import operand_mesh
+    return operand_mesh(value) is not None
 
 
 def _xla_holds_its_scores(q_shape, k_shape):

@@ -614,3 +614,341 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=1.0,
         _clamp(bq, s), _clamp(bk, s_k), interp)
     h_kv = k.shape[2]
     return (_from_bh(dq, b, h), _from_bh(dk, b, h_kv), _from_bh(dv, b, h_kv))
+
+
+# ---------------------------------------------------------------------------
+# attention over a set a query (a learned sparse index: ops/sparse_index.py)
+# ---------------------------------------------------------------------------
+#
+# The same pair with two more operands. `sets` (B, tiles_q, S_k, block_q) int8
+# says, transposed a query block at a time as the kernels form their tiles,
+# which keys each query attends to (1) and which not (0): causality is part
+# of the set, so no tile builds an iota. `table` (B * tiles_q * tiles_k,)
+# int32, prefetched into SMEM, holds the pairs of the set a tile: a tile
+# with none is never multiplied, a tile that is whole runs the body with no
+# select. The statistics, L and every gradient are over the set. A query
+# whose set is empty gets no defined output. Tiles are `block` x `block`
+# (the published chunk sizes, 512) and not searched.
+
+SET_BLOCK = 512
+
+
+def _set_tiles(picked, block_q, block_k):
+    """`picked` (B, S_q, S_k) int8 of 0 and 1 -> (sets (B, tiles_q, S_k,
+    block_q), table (B * tiles_q * tiles_k,) int32): XLA's one pass over the
+    set."""
+    b, s_q, s_k = picked.shape
+    sets = jnp.swapaxes(picked.reshape(b, s_q // block_q, block_q, s_k), 2, 3)
+    return sets, tile_counts(picked, block_q, block_k).reshape(-1)
+
+
+def tile_counts(picked, block_q, block_k):
+    """(B, S_q / block_q, S_k / block_k) int32: pairs of the set a tile."""
+    b, s_q, s_k = picked.shape
+    tiles = picked.reshape(b, s_q // block_q, block_q, s_k // block_k, block_k)
+    return jnp.sum(tiles.astype(jnp.int32), axis=(2, 4))
+
+
+def tiled_counts(sets, block_k):
+    """`tile_counts` of sets already in the kernels' layout, (B, tiles_q,
+    S_k, block_q)."""
+    b, tiles_q, s_k, block_q = sets.shape
+    tiles = sets.reshape(b, tiles_q, s_k // block_k, block_k, block_q)
+    return jnp.sum(tiles.astype(jnp.int32), axis=(3, 4))
+
+
+def set_tiles(key_set, block_q, block_k):
+    """The (sets, table) the set kernels read: a pair is taken as it is, in
+    the tiles it came in (the index kernel writes that layout; `tile_blocks`
+    says which), a square (B, S_q, S_k) int8 of 0 and 1 is tiled by XLA at
+    `block_q` x `block_k`."""
+    if isinstance(key_set, (tuple, list)):
+        return tuple(key_set)
+    return _set_tiles(key_set, block_q, block_k)
+
+
+def tile_blocks(tiles):
+    """(block_q, block_k) of a (sets, table) pair."""
+    sets, table = tiles
+    b, tiles_q, s_k, block_q = sets.shape
+    return block_q, s_k // (table.shape[0] // (b * tiles_q))
+
+
+def set_square(key_set):
+    """The set as (B, S_q, S_k): query t of block i of a (sets, table) pair
+    is row i * block + t; a square is returned as it is."""
+    if not isinstance(key_set, (tuple, list)):
+        return key_set
+    b, tiles, s_k, block = key_set[0].shape
+    return jnp.swapaxes(key_set[0], 2, 3).reshape(b, tiles * block, s_k)
+
+
+def _attn_set_fwd_kernel(tab_ref, q_ref, k_ref, vt_ref, set_ref, ot_ref, l_ref,
+                         *, scale, block_k):
+    # as _attn_fwd_kernel; set_ref: (seq_k, block_q) int8, the query block's
+    # sets with the keys down the sublanes; tab_ref: the pairs a tile
+    block_q = q_ref.shape[0]
+    fold = _scale_folds(scale)
+    q = q_ref[...] * scale if fold else q_ref[...]
+    num_k_blocks, d_v = vt_ref.shape[:2]
+    base = (pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)) * num_k_blocks
+
+    def step(kb, carry, masked):
+        m_prev, l_prev, acc = carry
+        keys = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+        k_tile = k_ref[keys, :]
+        vt_tile = vt_ref[kb]
+        s_t = jax.lax.dot_general(k_tile, q, _NT,
+                                  preferred_element_type=jnp.float32)
+        if not fold:
+            s_t = s_t * scale
+        if masked:
+            s_t = jnp.where(set_ref[keys, :].astype(jnp.int32) != 0, s_t, _MASKED)
+        m_new = jnp.maximum(m_prev, jnp.max(s_t, axis=0, keepdims=True))
+        p_t = jnp.exp(s_t - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_new = l_prev * correction + jnp.sum(p_t, axis=0, keepdims=True)
+        acc = acc * correction + jnp.dot(
+            vt_tile, p_t.astype(vt_tile.dtype),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    def visit(kb, carry):
+        pairs = tab_ref[base + kb]
+        return jax.lax.cond(
+            pairs == 0, lambda c: c,
+            lambda c: jax.lax.cond(pairs == block_q * block_k,
+                                   lambda c: step(kb, c, False),
+                                   lambda c: step(kb, c, True), c), carry)
+
+    m, l, acc = jax.lax.fori_loop(0, num_k_blocks, visit, (
+        jnp.full((1, block_q), _MASKED, jnp.float32),
+        jnp.zeros((1, block_q), jnp.float32),
+        jnp.zeros((d_v, block_q), jnp.float32)))
+    l_safe = jnp.maximum(l, 1e-30)
+    ot_ref[...] = (acc / l_safe).astype(ot_ref.dtype)
+    l_ref[...] = m + jnp.log(l_safe)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "scale", "block_q",
+                                             "block_k", "interpret"))
+def _flash_set_fwd_bh(q, k, v, sets, table, heads, scale, block_q, block_k,
+                      interpret):
+    # q (B*H, S, D), k (B*Hkv, S, D), v (B*Hkv, S, Dv), sets and table of
+    # `_set_tiles` -> out (B*H, S, Dv), lse (B*H, S). The heads are the
+    # innermost grid axis: a query block's sets are fetched once for all of
+    # them, a key/value head's k and v once for its group.
+    bh, seq_q, d = q.shape
+    seq_k, d_v = v.shape[1:]
+    batch = bh // heads
+    kv_heads = k.shape[0] // batch
+    group = heads // kv_heads
+    tiles_q, tiles_k = seq_q // block_q, seq_k // block_k
+    vmem = (2 * seq_k * (_lanes(d) + d_v) * k.dtype.itemsize
+            + 2 * seq_k * block_q + 4 * block_q * block_k * 4)
+    out_t, lse = pl.pallas_call(
+        functools.partial(_attn_set_fwd_kernel, scale=scale, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, tiles_q, heads),
+            in_specs=[
+                pl.BlockSpec((None, block_q, d),
+                             lambda b, i, h, tab: (b * heads + h, i, 0)),
+                pl.BlockSpec((None, seq_k, d),
+                             lambda b, i, h, tab: (b * kv_heads + h // group, 0, 0)),
+                pl.BlockSpec((None, tiles_k, d_v, block_k),
+                             lambda b, i, h, tab: (b * kv_heads + h // group, 0, 0, 0)),
+                pl.BlockSpec((None, None, seq_k, block_q),
+                             lambda b, i, h, tab: (b, i, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, None, d_v, block_q),
+                             lambda b, i, h, tab: (b * heads + h, i, 0, 0)),
+                pl.BlockSpec((None, 1, block_q),
+                             lambda b, i, h, tab: (b * heads + h, 0, i)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, tiles_q, d_v, block_q), q.dtype),
+            jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_set_fwd",
+        **_tpu_params(interpret, ("parallel", "parallel", "arbitrary"), vmem),
+    )(table, q, k, _tiles_transposed(v, block_k), sets)
+    return _tiles_restored(out_t), lse.reshape(bh, seq_q)
+
+
+def _attn_set_bwd_kernel(tab_ref, q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref,
+                         kt_ref, set_ref, dqt_ref, dk_ref, dv_ref, dqt_acc, *,
+                         scale, block_q, kv_heads, tiles_q):
+    # as _attn_bwd_kernel; set_ref: (span / block_q, block_k, block_q) int8,
+    # the span's query blocks against this key tile; tab_ref as the forward's
+    group, span, d = q_ref.shape
+    block_k = k_ref.shape[0]
+    j = pl.program_id(2)
+    num_q_blocks = span // block_q
+    tiles_k = pl.num_programs(2)
+    base = ((pl.program_id(0) // kv_heads) * tiles_q
+            + pl.program_id(1) * num_q_blocks) * tiles_k + j
+    fold = _scale_folds(scale)
+    k_tile = k_ref[...] * scale if fold else k_ref[...]
+    v_tile = v_ref[...]
+    kt_tile = kt_ref[...] * scale if fold else kt_ref[...]
+
+    def for_each_block(fn):
+        def head(h, carry):
+            def block(i, carry):
+                fn(h, i)
+                return carry
+            return jax.lax.fori_loop(0, num_q_blocks, block, carry)
+        jax.lax.fori_loop(0, group, head, 0)
+
+    @pl.when(j == 0)
+    def _():
+        def zero(h, i):
+            dqt_acc[h, i] = jnp.zeros((d, block_q), jnp.float32)
+        for_each_block(zero)
+
+    def pair(h, i, carry, masked):
+        dk, dv = carry
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q, do = q_ref[h, rows, :], do_ref[h, rows, :]
+        lse, delta = l_ref[h, pl.ds(i, 1), :], dd_ref[h, pl.ds(i, 1), :]
+        s_t = jax.lax.dot_general(k_tile, q, _NT,
+                                  preferred_element_type=jnp.float32)
+        if not fold:
+            s_t = s_t * scale
+        if masked:
+            s_t = jnp.where(set_ref[i].astype(jnp.int32) != 0, s_t, _MASKED)
+        p_t = jnp.exp(s_t - lse)
+        dv = dv + jnp.dot(p_t.astype(do.dtype), do,
+                          preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(v_tile, do, _NT,
+                                   preferred_element_type=jnp.float32)
+        ds_t = (p_t * (dp_t - delta)).astype(q.dtype)
+        dk = dk + jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
+        dqt_acc[h, i] += jnp.dot(kt_tile, ds_t,
+                                 preferred_element_type=jnp.float32)
+        return dk, dv
+
+    def visit(h, i, carry):
+        pairs = tab_ref[base + i * tiles_k]
+        return jax.lax.cond(
+            pairs == 0, lambda c: c,
+            lambda c: jax.lax.cond(pairs == block_q * block_k,
+                                   lambda c: pair(h, i, c, False),
+                                   lambda c: pair(h, i, c, True), c), carry)
+
+    def head(h, carry):
+        return jax.lax.fori_loop(
+            0, num_q_blocks, lambda i, c: visit(h, i, c), carry)
+
+    dk, dv = jax.lax.fori_loop(
+        0, group, head, (jnp.zeros((block_k, d), jnp.float32),
+                         jnp.zeros(v_ref.shape, jnp.float32)))
+    dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    @pl.when(j == tiles_k - 1)
+    def _():
+        def write(h, i):
+            dq_t = dqt_acc[h, i]
+            dqt_ref[h, i] = (dq_t if fold else dq_t * scale).astype(
+                dqt_ref.dtype)
+        for_each_block(write)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "scale", "block_q", "block_k", "interpret", "q_span"))
+def _flash_set_bwd_bh(q, k, v, o, lse, do, sets, table, heads, scale, block_q,
+                      block_k, interpret, q_span=None):
+    # as _flash_bwd_bh, over the sets of `_set_tiles`
+    bh, seq_q, d = q.shape
+    rows_kv, seq_k, d_v = v.shape
+    group = bh // rows_kv
+    kv_heads = heads // group
+    span = q_span or _bwd_q_span(group, seq_q, d, q.dtype.itemsize, block_q,
+                                 d_v)
+    spans, blocks = seq_q // span, span // block_q
+    delta = jnp.einsum("rsd,rsd->rs", do, o, precision="highest",
+                       preferred_element_type=jnp.float32)
+    stats = [x.reshape(bh, spans, blocks, block_q) for x in (lse, delta)]
+    part = (k.dtype, v.dtype) if spans == 1 else (jnp.float32,) * 2
+    vmem = (_bwd_resident_bytes(group, span, d, q.dtype.itemsize, d_v)
+            + block_k * (6 * _lanes(d) + 4 * _lanes(d_v)) * k.dtype.itemsize
+            + 2 * span * block_k + 6 * block_q * block_k * 4)
+
+    def wide(width):
+        return pl.BlockSpec((group, span, width),
+                            lambda r, c, j, tab: (r, c, 0))
+
+    def tile(width):
+        return pl.BlockSpec((None, block_k, width),
+                            lambda r, c, j, tab: (r, j, 0))
+
+    def part_tile(width):
+        return pl.BlockSpec((None, None, block_k, width),
+                            lambda r, c, j, tab: (c, r, j, 0))
+    stat = pl.BlockSpec((group, None, blocks, block_q),
+                        lambda r, c, j, tab: (r, c, 0, 0))
+    tile_t = pl.BlockSpec((None, None, d, block_k),
+                          lambda r, c, j, tab: (r, j, 0, 0))
+    set_tile = pl.BlockSpec((None, blocks, block_k, block_q),
+                            lambda r, c, j, tab: (r // kv_heads, c, j, 0))
+    dq_t, dk, dv = pl.pallas_call(
+        functools.partial(_attn_set_bwd_kernel, scale=scale, block_q=block_q,
+                          kv_heads=kv_heads, tiles_q=seq_q // block_q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows_kv, spans, seq_k // block_k),
+            in_specs=[wide(d), wide(d_v), stat, stat, tile(d), tile(d_v),
+                      tile_t, set_tile],
+            out_specs=[pl.BlockSpec((group, blocks, d, block_q),
+                                    lambda r, c, j, tab: (r, c, 0, 0)),
+                       part_tile(d), part_tile(d_v)],
+            scratch_shapes=[pltpu.VMEM((group, blocks, d, block_q),
+                                       jnp.float32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, spans * blocks, d, block_q), q.dtype),
+            jax.ShapeDtypeStruct((spans, rows_kv, seq_k, d), part[0]),
+            jax.ShapeDtypeStruct((spans, rows_kv, seq_k, d_v), part[1]),
+        ],
+        interpret=interpret,
+        name="flash_set_bwd",
+        **_tpu_params(interpret, ("parallel", "arbitrary", "arbitrary"), vmem),
+    )(table, q, do, *stats, k, v, _tiles_transposed(k, block_k), sets)
+    dq = _tiles_restored(dq_t)
+    if spans == 1:
+        return dq, dk[0], dv[0]
+    return dq, dk.sum(0).astype(k.dtype), dv.sum(0).astype(v.dtype)
+
+
+def flash_attention_set_fwd(q, k, v, picked, scale=1.0, block=SET_BLOCK,
+                            interpret=None):
+    """Attention of q (B, S, H, D) over k, v (B, S, Hkv, D / Dv), query t of
+    row b reading the keys s with `picked[b, t, s]` 1 (int8 of 0 and 1; a
+    causal set holds no key after its query), or `picked` the (sets, table)
+    pair of `set_tiles`, whose own tiles are then walked whatever `block`
+    says. Returns (out, lse (B, H, S) float32, the set's tiles
+    for the backward)."""
+    b, s, h, d = q.shape
+    interp = _interpret(q) if interpret is None else interpret
+    tiles = set_tiles(picked, _clamp(block, s), _clamp(block, k.shape[1]))
+    bq, bk = tile_blocks(tiles)
+    out, lse = _flash_set_fwd_bh(_to_bh(q), _to_bh(k), _to_bh(v), *tiles, h,
+                                 scale, bq, bk, interp)
+    return _from_bh(out, b, h), lse.reshape(b, h, s), tiles
+
+
+def flash_attention_set_bwd(q, k, v, out, lse, do, tiles, scale=1.0,
+                            interpret=None, q_span=None):
+    """The backward over the same sets, in the forward's tiles: dq, dk, dv."""
+    b, s, h, d = q.shape
+    interp = _interpret(q) if interpret is None else interpret
+    dq, dk, dv = _flash_set_bwd_bh(
+        _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(out), lse.reshape(b * h, s),
+        _to_bh(do), *tiles, h, scale, *tile_blocks(tiles), interp, q_span)
+    h_kv = k.shape[2]
+    return (_from_bh(dq, b, h), _from_bh(dk, b, h_kv), _from_bh(dv, b, h_kv))

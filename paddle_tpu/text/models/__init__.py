@@ -3,6 +3,9 @@ from .ernie import (  # noqa: F401
     ErnieConfig, ErnieForSequenceClassification, ErnieModel,
 )
 from .gpt import GPTForCausalLM, GPTModel  # noqa: F401
+from .keye_vl2 import (  # noqa: F401
+    KeyeVL2Config, KeyeVL2ForCausalLM, KeyeVL2Model,
+)
 from .kimi_linear import (  # noqa: F401
     KimiLinearConfig, KimiLinearForCausalLM, KimiLinearModel,
 )
@@ -12,4 +15,5 @@ __all__ = ["BertModel", "BertForSequenceClassification", "GPTModel",
            "GPTForCausalLM", "ErnieConfig", "ErnieModel",
            "ErnieForSequenceClassification", "LFM2Config", "LFM2Model",
            "LFM2ForCausalLM", "KimiLinearConfig", "KimiLinearModel",
-           "KimiLinearForCausalLM"]
+           "KimiLinearForCausalLM", "KeyeVL2Config", "KeyeVL2Model",
+           "KeyeVL2ForCausalLM"]

@@ -429,3 +429,69 @@ def test_kda_op_compiles_with_its_backward(one_chip):
     compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(x, x, x, g, beta).compile()
     # 1.52 GiB through 8 heads at a time; 2.46 with all 32 at once
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+
+
+# ---------------------------------------------------------------------------
+# Keye-VL-2.0 widths: attention over a set a query at 8192 positions
+
+def test_the_pair_over_a_set_compiles_at_keye_widths(one_chip):
+    """(b 1, s 8192, 32 query heads over 4 key/value heads, d 128) bf16 over
+    an int8 set a query, differentiated through the custom_vjp that
+    scaled_dot_product_attention(key_set=) holds: two kernels, the set's
+    tiles and the prefetched table made by XLA, no float32 array of (heads,
+    seq, seq) or (seq, seq) anywhere in the program."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.attention import _flash_set_diff
+
+    def loss(q, k, v, picked):
+        out, _ = _flash_set_diff(q, k, v, picked, 128 ** -0.5, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((1, 8192, 4, 128), jnp.bfloat16, one_chip)
+    picked = _sds((1, 8192, 8192), jnp.int8, one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, picked).compile()
+    _assert_kernel(compiled, 2)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "f32[1,8192,8192]" not in text and "f32[32,8192,8192]" not in text
+    # the scores' bytes would be 8.6 GB: the program's temporaries are the
+    # set's tiles and the backward's float32 partials
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 * 2 ** 30
+
+
+def test_the_index_hands_its_sets_to_the_pair_and_the_loss_at_keye_widths(one_chip):
+    """One layer's path over the sets at (1, 8192), differentiated: the sets
+    kernel writes the sets in the layout the flash pair and the loss kernel
+    read, and they go from one to the others as they are: four kernels, and
+    no (8192, 8192) square of the set (int8 or bool) anywhere in the
+    program, so no transpose of it either; XLA's share is the table, one
+    reduction over the sets."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import sparse_index
+    from paddle_tpu.ops.attention import _flash_set_diff
+
+    def loss(q, k, v, qi, ki, w):
+        tiles, stats = sparse_index.index_key_set(qi, ki, w, 2048, mode="kernel")
+        out, lse = _flash_set_diff(q, k, v, tiles, 128 ** -0.5, False)
+        index = sparse_index.index_loss(qi, ki, w, tiles, q, k, lse, 128 ** -0.5,
+                                        sparse_index.INDEX_CHUNK, "kernel")
+        return jnp.sum(out.astype(jnp.float32)) + index, stats
+
+    q = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((1, 8192, 4, 128), jnp.bfloat16, one_chip)
+    qi = _sds((1, 8192, 16, 64), jnp.bfloat16, one_chip)
+    ki = _sds((1, 8192, 64), jnp.bfloat16, one_chip)
+    w = _sds((1, 8192, 16), jnp.bfloat16, one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=range(6), has_aux=True)).lower(
+        q, kv, kv, qi, ki, w).compile()
+    _assert_kernel(compiled, 4)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    for square in ("s8[1,8192,8192]", "pred[1,8192,8192]", "f32[1,8192,8192]"):
+        assert square not in text
+    assert "s8[1,16,8192,512]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 * 2 ** 30

@@ -44,6 +44,8 @@ SUBSYSTEMS = [
     "decode",        # continuous-batching decode (serving/decode/)
     "dispatch",      # the op dispatch seam (core/dispatch.py)
     "disagg",        # disaggregated prefill/decode (serving/disagg.py)
+    "dsa",           # the sparse-attention index: pairs selected, tiles
+                     # skipped (text/models/keye_vl2.py, ops/sparse_index.py)
     "integrity",     # SDC defense (checksum consensus, replay)
     "io",            # input pipeline / data workers
     "kda",           # Kimi Delta Attention's chunked op (ops/kda.py)
