@@ -15,15 +15,21 @@ a chunk, Gamma = exp(G) and S_0 the state at the chunk's start:
     S_C = Diag(Gamma_C) S_0 + sum_i (k_i * Gamma_C / Gamma_i) Delta_i^T
 
 Three stages, none of them a scan over tokens:
-  1. inside the chunks, for every (batch, head, chunk) at once: A, the
-     query-key products, T, U, W and the decayed copies of q and k, as batched
-     matrix products and elementwise work;
+  1. inside the chunks, for every (batch, head, chunk) at once: G (a product
+     with a triangle of ones, `_running_sums`), A, the query-key products, T,
+     U, W and the decayed copies of q and k, as batched matrix products and
+     elementwise work;
   2. across the chunks: Delta and the next chunk's state from the last, the one
      sequential part, two small products a chunk, by `lax.scan` over the 64
-     chunks of a row (a pair of Pallas kernels that kept the state in VMEM
-     measured three times slower on the chip for the forward pass and level
-     for the backward, PERF.md PR 36, and went);
+     chunks of a row;
   3. the outputs of every chunk at once from its start state and Delta.
+Two kernels were built for this, measured on the chip and taken out
+(docs/kernels.md has the readings): a Pallas pair for stage 2 alone inside
+the loop over the head groups (PR 36: three times slower in the op for the
+forward pass), and the whole forward pass as one kernel outside any loop
+(PR 40: 5.84 ms against this form's 6.43; the exact float32 inverse of one
+chunk of 8 heads does not fill the vector unit as the chunks of a group
+side by side in the lanes do).
 
 The overflow rule. Every ratio Gamma_r / Gamma_i with i <= r is at most 1, but
 its two factors apart are not: at alpha = 0.5 exp(-G) over a chunk of 64 is
@@ -153,6 +159,17 @@ def _unchunked(x, b, h):
     return jnp.transpose(x, (1, 0, 3, 2, 4)).reshape(b, n * c, h, d)
 
 
+def _running_sums(g):
+    """The running sums of g (n, r, C, D) over a chunk's tokens, as a product
+    with a (C, C) lower triangle of ones, float32 at full precision (g reaches
+    -10 a token and a chunk's sum -640: one bfloat16 pass is not enough).
+    `jnp.cumsum` is a `reduce-window` on the TPU, four plain passes, and its
+    pull-back another; this one's pull-back is the transposed triangle."""
+    c = g.shape[2]
+    return jnp.matmul(jnp.tril(jnp.ones((c, c), g.dtype)), g,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def _intra(q, k, v, g, beta, scale, chunk):
     """From the op's operands ((B, S, H, D); beta (B, S, H)), per chunk:
     qk (n, r, C, C) the decayed query-key products at or under the diagonal,
@@ -163,7 +180,7 @@ def _intra(q, k, v, g, beta, scale, chunk):
     beta = _chunked(beta.astype(f32)[..., None], chunk)[..., 0]      # (n, r, C)
     q = q * scale
     c, sub = chunk, min(SUB, chunk)
-    gsum = jnp.cumsum(g, axis=2)
+    gsum = _running_sums(g)
     rows_k, rows_q = [], []
     for a in range(c // sub):
         lo, hi = a * sub, (a + 1) * sub

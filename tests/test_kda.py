@@ -1,8 +1,9 @@
 """Kimi Delta Attention's chunked op (paddle_tpu/ops/kda.py) against the
 token-by-token recurrence it stands for, in float32 on the CPU: forward and
 all five gradients, whole and ragged sequences, the decay's whole range, the
-overflow rule, beta's two ends, the state's restart in every row and bfloat16
-operands.
+overflow rule, beta's two ends, the state's restart in every row, bfloat16
+operands, and the running sums of g as a triangle product against sums taken
+in float64.
 
 Tolerances. In float32 both sides do the same arithmetic in another order
 (sums over a chunk against sums a token at a time): 3e-5 of the largest entry
@@ -157,6 +158,45 @@ def test_the_strongest_decay_float32_allows_half_a_sub_chunk():
     ops[3] = jnp.full_like(ops[3], -5.5)
     for name, a, b in zip("q k v g beta".split(), *both_gradients(ops)):
         assert gap(a, b) < (2e-4 if name == "g" else F32_TOL), name
+
+
+@pytest.mark.parametrize("case", ["strongest", "mixed", "short-chunk"])
+def test_running_sums_as_a_triangle_product(case):
+    """The running sums of g inside a chunk are a product with a lower
+    triangle of ones at full float32 precision, not `jnp.cumsum`: at g = -10
+    a token a chunk's sum reaches -640, and they agree with sums taken in
+    float64 to float32's rounding of 64 terms, forward and pulled back (the
+    transposed triangle); one bfloat16 pass of the same product does not."""
+    c = 16 if case == "short-chunk" else 64
+    key = jax.random.PRNGKey(11)
+    g = jnp.full((3, 2, c, 24), -10.0) if case == "strongest" \
+        else jax.random.uniform(key, (3, 2, c, 24), minval=-10.0, maxval=0.0)
+    want = np.cumsum(np.asarray(g, np.float64), axis=2)
+    got, pull = jax.vjp(kda._running_sums, g)
+    assert got.dtype == jnp.float32
+    assert float(np.max(np.abs(np.asarray(got) - want))) < 1e-6 * 10.0 * c
+    assert gap(got, jnp.cumsum(g, axis=2)) < 1e-6
+    ct = jax.random.normal(jax.random.PRNGKey(12), g.shape)
+    back = np.flip(np.cumsum(np.flip(np.asarray(ct, np.float64), 2), axis=2), 2)
+    assert float(np.max(np.abs(np.asarray(pull(ct)[0]) - back))) < 1e-5
+    tri = jnp.tril(jnp.ones((c, c), jnp.bfloat16))
+    one_pass = jnp.matmul(tri, g.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+    if case != "strongest":             # -10 itself is a bfloat16
+        assert float(np.max(np.abs(np.asarray(one_pass) - want))) > 0.05
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_no_cumsum_is_left_in_the_op(direction):
+    """Neither pass holds a `cumsum` (a `reduce-window` on the TPU, four
+    plain passes over a group's float32 array): the sums and their pull-back
+    are products."""
+    ops = operands(1, 1, 128, 2, 16, 16)
+    fn = chunked if direction == "forward" else \
+        jax.grad(lambda *a: jnp.sum(chunked(*a)), argnums=range(5))
+    text = str(jax.make_jaxpr(fn)(*ops))
+    assert "cumsum" not in text and "reduce_window" not in text
+    assert "dot_general" in text
 
 
 @pytest.mark.parametrize("beta", [0.0, 1.0])

@@ -23,7 +23,14 @@ from benchmarks import harness  # noqa: E402
 from benchmarks.reference import adamw  # noqa: E402
 
 CELL = "kimi-linear-48b-a3b.pretrain-1chip-b2-s4096"
-SEED = 11
+# 11 until PR 40: that draw holds a router near-tie (token 56 of the first
+# layer: its eighth and ninth experts closer in score plus bias than the
+# 2.4e-6 by which float32 rounding in the mixer before it moves a score), so
+# any change in the order of a sum upstream picks another expert for it and
+# moves the loss by 3e-5; the op's running sums as a product did, while
+# agreeing with the token recurrence as closely as before (5e-7 against 9e-7
+# of the largest output). The parent passes at 12 as well.
+SEED = 12
 TOL = 1e-4
 
 

@@ -414,7 +414,7 @@ def test_the_token_block_follows_the_picks_and_the_rows_bytes(one_chip, move):
 def test_kda_op_compiles_with_its_backward(one_chip):
     """The whole op at (2, 4096, 32, 128) bf16, differentiated, for a v5e:
     batched products and scans over the chunks inside the loop over the head
-    groups, whose temporaries are a group's."""
+    groups, whose temporaries are a group's; the running sums as a product."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import kda
@@ -427,8 +427,11 @@ def test_kda_op_compiles_with_its_backward(one_chip):
     g = _sds((2, 4096, 32, 128), jnp.float32, one_chip)
     beta = _sds((2, 4096, 32), jnp.bfloat16, one_chip)
     compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(x, x, x, g, beta).compile()
-    # 1.52 GiB through 8 heads at a time; 2.46 with all 32 at once
+    # 1.52 GB through 8 heads at a time; 2.46 GiB with all 32 at once
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+    # the running sums of g and their pull-back are products with a triangle
+    # of ones: no `reduce-window`, the TPU's cumsum, in either pass
+    assert "reduce-window" not in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------
