@@ -9,6 +9,10 @@ Architecture (vs the reference at /root/reference, see SURVEY.md):
 """
 from __future__ import annotations
 
+import time as _time
+
+_import_start = _time.perf_counter()   # `runtime.import`, closed at the last line
+
 __version__ = "0.1.0"
 
 import os as _os
@@ -39,6 +43,11 @@ except OSError as _e:
     import warnings as _warnings
     _warnings.warn(f"paddle_tpu: no persistent compilation cache — "
                    f"{_cache_dir!r} cannot be created: {_e}")
+
+# every compile request of the process from here on, by set-up phase: the
+# constructors' compiles come before any span
+from .profiler import compile_events as _compile_events
+_compile_events.listen()
 
 from .core import autograd as _autograd_mod  # noqa: F401
 from .core.autograd import enable_grad, no_grad, set_grad_enabled  # noqa: F401
@@ -235,3 +244,6 @@ def check_shape(shape):
     for d in list(shape):
         if not isinstance(d, int) and not hasattr(d, "shape"):
             raise TypeError(f"invalid dim {d!r} in shape {shape!r}")
+
+
+_compile_events.record_import(_import_start, _time.perf_counter())

@@ -15,6 +15,8 @@ from collections import defaultdict, deque
 
 import jax.numpy as jnp
 
+from ..profiler.compile_events import OP_TIMER as _OP_TIMER
+
 __all__ = [
     "GradNode",
     "no_grad",
@@ -199,7 +201,10 @@ def backward(tensors, grad_tensors=None, retain_graph=False,
                     f"an in-place operation before backward ran (version "
                     f"{getattr(t, '_version', 0)} != {ver}); clone() the "
                     f"tensor before the in-place op")
-        in_grads = node.vjp_fn(cot)
+        if _OP_TIMER[0] is None:
+            in_grads = node.vjp_fn(cot)
+        else:   # a to_static discovery pass is open
+            in_grads = _OP_TIMER[0](f"grad({node.name})", node.vjp_fn, cot)
         for t, g in zip(node.inputs, in_grads):
             nxt = t._grad_node
             if nxt is not None:

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..profiler import metrics as _metrics
+from ..profiler.compile_events import OP_TIMER as _OP_TIMER
 from . import autograd
 from .autograd import GradNode
 from .tensor import Tensor
@@ -86,6 +87,9 @@ def apply(prim, *args, name=None, **kwargs):
     """
     if _STATIC_BUILDER[0] is not None:
         return _STATIC_BUILDER[0].record(prim, args, kwargs, name)
+    if _OP_TIMER[0] is not None:   # a to_static discovery pass is open
+        return _OP_TIMER[0](name or getattr(prim, "__name__", "op"),
+                            _apply_impl, prim, args, kwargs, name)
     return _apply_impl(prim, args, kwargs, name)
 
 

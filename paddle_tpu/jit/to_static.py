@@ -39,7 +39,7 @@ from ..core.autograd import GradNode
 from ..core.dtypes import is_inexact
 from ..core.tensor import Tensor, _TraceHooks
 from ..profiler import metrics as _metrics
-from ..profiler.compile_events import compile_span
+from ..profiler.compile_events import compile_span, setup_span, timed_ops
 
 __all__ = ["to_static", "not_to_static", "TracedLayer", "InputSpec"]
 
@@ -660,12 +660,12 @@ class StaticFunction:
         bwd_before = autograd.backward_run_counter[0]
         ops_before = _dispatch.OPS_DISPATCHED[0]
         try:
-            with jax.profiler.TraceAnnotation("to_static.discover",
-                                              fn=self._name) as span:
+            with setup_span("to_static.discover", fn=self._name) as span, \
+                    timed_ops(span):
                 out = self._fn(*args, **kwargs)
                 ops = _dispatch.OPS_DISPATCHED[0] - ops_before
                 _count("discover_ops", ops)
-                span.set_metadata(ops=ops)
+                span["attrs"]["ops"] = ops
         finally:
             (_TraceHooks.on_read, _TraceHooks.on_write,
              _TraceHooks.on_create) = prev

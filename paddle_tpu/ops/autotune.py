@@ -32,35 +32,45 @@ import time
 import jax
 import jax.numpy as jnp
 
-# ---------------------------------------------------------------------------
-# counters (test/observability seam; profiler counter events ride on top)
+from ..profiler import metrics as _metrics
+from ..profiler.compile_events import on_thread, setup_span
 
-_COUNTERS = {
-    "searches": 0,       # timed candidate searches actually performed
-    "candidate_failures": 0,  # candidates that raised while being timed
-    "mem_hits": 0,       # in-process memo hits
-    "disk_hits": 0,      # persistent-cache hits (zero-search steady state)
-    "fallbacks": 0,      # unsearchable placements served the fallback table
-    "cache_errors": 0,   # corrupt/torn cache files ignored and rebuilt
-}
+# ---------------------------------------------------------------------------
+# counters: `autotune.<name>_total` in the metrics registry
+
+_COUNTED = (
+    "searches",            # timed candidate searches actually performed
+    "candidate_failures",  # candidates that raised while being timed
+    "mem_hits",            # in-process memo hits
+    "disk_hits",           # persistent-cache hits (zero-search steady state)
+    "fallbacks",           # unsearchable placements served the fallback table
+    "cache_errors",        # corrupt/torn cache files ignored and rebuilt
+)
+_since = {}   # the registry's totals at the last reset_counters()
+
+
+def _count(name):
+    _metrics.get_registry().inc_counter(f"autotune.{name}_total")
+
+
+def _totals():
+    reg = _metrics.get_registry()
+    return {name: int(reg.counter_value(f"autotune.{name}_total"))
+            for name in _COUNTED}
 
 
 def counters():
-    return dict(_COUNTERS)
+    """The registry's `autotune.*_total` since the last `reset_counters()`
+    (a registry emptied since then counts from zero again)."""
+    out = {}
+    for name, total in _totals().items():
+        since = _since.get(name, 0)
+        out[name] = total - since if total >= since else total
+    return out
 
 
 def reset_counters():
-    for k in _COUNTERS:
-        _COUNTERS[k] = 0
-
-
-def _record(name, value):
-    """Mirror a decision onto the profiler timeline as a counter event."""
-    try:
-        from .. import profiler
-        profiler.record_counter(name, value)
-    except Exception:
-        pass
+    _since.update(_totals())
 
 
 # ---------------------------------------------------------------------------
@@ -140,7 +150,7 @@ class AutotuneCache:
         except (OSError, ValueError):
             return None
         if not isinstance(rec, dict) or rec.get("key") != key:
-            _COUNTERS["cache_errors"] += 1
+            _count("cache_errors")
             return None
         return rec.get("value")
 
@@ -248,37 +258,40 @@ class Autotuner:
         """
         key = "%s|%s|v=%s" % (op, signature, version)
         if key in self._mem:
-            _COUNTERS["mem_hits"] += 1
+            _count("mem_hits")
             return self._mem[key]
         got = self._cache.get(key)
         if got is not None:
-            _COUNTERS["disk_hits"] += 1
+            _count("disk_hits")
             got = _tuplify(got)
             self._mem[key] = got
             return got
         if not self.searchable():
             # deterministic fallback; memoised in-process only, so a later
             # run on a real device still gets to search
-            _COUNTERS["fallbacks"] += 1
+            _count("fallbacks")
             self._mem[key] = fallback
             return fallback
         times, errors = {}, {}
 
-        def search():
-            args = make_args()
-            for cand in candidates:
-                try:
-                    times[cand] = self._measure(build(cand), args)
-                except Exception as e:  # counted; raised below when it matters
-                    _COUNTERS["candidate_failures"] += 1
-                    errors[cand] = e
-                    if self.first_failure is None:
-                        self.first_failure = "%s|%s %r: %s: %s" % (
-                            op, signature, cand, type(e).__name__, e)
+        def search(span):
+            with on_thread(span):   # the candidates' compiles count under it
+                args = make_args()
+                for cand in candidates:
+                    try:
+                        times[cand] = self._measure(build(cand), args)
+                    except Exception as e:  # counted; raised below when it matters
+                        _count("candidate_failures")
+                        errors[cand] = e
+                        if self.first_failure is None:
+                            self.first_failure = "%s|%s %r: %s: %s" % (
+                                op, signature, cand, type(e).__name__, e)
 
-        _outside_any_trace(search)
-        _COUNTERS["searches"] += 1
-        _record("autotune.search/%s" % op, 1)
+        with setup_span("autotune.search", op=op, signature=signature) as span:
+            _outside_any_trace(functools.partial(search, span))
+            span["attrs"].update(candidates=len(times) + len(errors),
+                                 failed=len(errors))
+        _count("searches")
         for cand in required:
             if cand in errors:
                 raise AutotuneError(

@@ -26,8 +26,10 @@ import time
 import jax
 
 from . import metrics as _metrics
+from .compile_events import setup_timeline
 
 __all__ = [
+    "setup_timeline",
     "Profiler", "RecordEvent", "ProfilerTarget", "ProfilerState",
     "start_profiler", "stop_profiler", "reset_profiler", "profiler",
     "export_chrome_tracing", "export_rank_trace", "summary",

@@ -462,8 +462,7 @@ def test_metric_name_lint_manifest_guard():
     grandfathered = set(ast.literal_eval(_assigned("GRANDFATHERED")))
     # frozen: pre-convention names only — anything new must follow the
     # pattern instead of being added here
-    assert grandfathered <= {"autotune.search/{}", "straggler.rank{}",
-                             "{}.{}"}
+    assert grandfathered <= {"straggler.rank{}", "{}.{}"}
 
 
 def test_span_name_lint_passes_on_tree():

@@ -40,6 +40,8 @@ SUBSYSTEMS = [
     "autotune",      # kernel-tier block autotuning
     "campaign",      # chaos-campaign engine (resilience/campaign.py)
     "ckpt",          # zero-stall checkpointing (resilience/snapshot.py)
+    "compile",       # every compile request of the process, by set-up phase
+                     # (profiler/compile_events.py)
     "compiled_step", # whole-step compilation (jit/compiled_step.py)
     "decode",        # continuous-batching decode (serving/decode/)
     "dispatch",      # the op dispatch seam (core/dispatch.py)
@@ -55,6 +57,8 @@ SUBSYSTEMS = [
     "prefix",        # prefix-sharing KV cache (serving/decode/prefix.py)
     "profiler",      # profiler-internal (samples/sec, ...)
     "rollout",       # live model rollout (serving/rollout.py)
+    "runtime",       # the process's own set-up: the package's import, the
+                     # set-up timeline's bound (profiler/compile_events.py)
     "serving",       # inference server
     "slo",           # SLO burn-rate accounting (serving/metrics.py)
     "spec",          # speculative decoding (serving/decode/specdecode.py)
@@ -73,7 +77,6 @@ UNITS = ["bytes", "count", "ms", "per_sec", "ratio", "sec", "total", "us"]
 # and tests/test_integrity, so they are pinned, not fixed. FROZEN: new names
 # must pass the pattern instead.
 GRANDFATHERED = [
-    "autotune.search/{}",   # per-op search counter (slash-namespaced)
     "straggler.rank{}",     # value is a ratio; name predates unit suffixes
     "{}.{}",                # serving export_to_profiler re-emits snapshot
                             # keys under a caller prefix; the source names
@@ -81,9 +84,8 @@ GRANDFATHERED = [
 ]
 
 # Calls whose first argument mints a metric name. ``observe_many`` takes
-# (name, value) pairs instead and is handled separately; ``_record`` is
-# autotune's local wrapper around record_counter.
-NAME_CALLS = {"record_counter", "record_sample", "_record",
+# (name, value) pairs instead and is handled separately.
+NAME_CALLS = {"record_counter", "record_sample",
               "inc_counter", "set_gauge", "observe", "register_gauge_fn",
               "register_counter_fn"}
 PAIRS_CALLS = {"observe_many"}
