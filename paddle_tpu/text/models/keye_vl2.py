@@ -187,7 +187,14 @@ class KeyeVL2Attention(nn.Layer):
         self.indexer = KeyeVL2Indexer(cfg)
 
     def forward(self, x, position_ids=None):
-        """(out, the layer's index loss, the index's stats)."""
+        """(out, the layer's index loss, the index's stats). A block runs the
+        three parts itself, the core between its rematerialised regions."""
+        out, index_loss, stats = self.core(*self.operands(x, position_ids))
+        return self.project(out), index_loss, stats
+
+    def operands(self, x, position_ids=None):
+        """(q, k, v, q_index, k_index, weights) from the block's normed
+        input: what the sparse-attention core reads."""
         b, s, _ = x.shape
         q = M.reshape(self.q_proj(x), [b, s, self.num_heads, self.head_dim])
         k = M.reshape(self.k_proj(x), [b, s, self.num_kv_heads, self.head_dim])
@@ -196,7 +203,13 @@ class KeyeVL2Attention(nn.Layer):
             self.q_norm(q), self.k_norm(k), theta=self.rope_theta,
             position_ids=position_ids,
             sections=None if position_ids is None else self.sections)
-        q_index, k_index, weights = self.indexer(x, position_ids)
+        return (q, k, v, *self.indexer(x, position_ids))
+
+    def core(self, q, k, v, q_index, k_index, weights):
+        """(the heads' outputs (b, s, heads, d), the layer's index loss, the
+        index's stats): the index's sets, the attention over them and the
+        index loss, which a rematerialised block keeps on the tape
+        (`KeyeVL2Block.forward`)."""
         key_set, stats = F.sparse_attention_index(q_index, k_index, weights,
                                                   self.topk)
         out, lse = F.scaled_dot_product_attention(
@@ -204,8 +217,11 @@ class KeyeVL2Attention(nn.Layer):
             return_lse=True)
         index_loss = F.sparse_attention_index_loss(q_index, k_index, weights,
                                                    key_set, q, k, lse=lse)
-        return (self.o_proj(M.reshape(out, [b, s, self.num_heads * self.head_dim])),
-                index_loss, stats)
+        return out, index_loss, stats
+
+    def project(self, out):
+        b, s = out.shape[:2]
+        return self.o_proj(M.reshape(out, [b, s, self.num_heads * self.head_dim]))
 
 
 class KeyeVL2Block(nn.Layer):
@@ -221,14 +237,32 @@ class KeyeVL2Block(nn.Layer):
             weight_attr=I.Normal(0.0, INITIALIZER_RANGE), score="softmax",
             absent=cfg.absent_experts)
 
-    def forward(self, x, position_ids=None):
+    def forward(self, x, position_ids=None, rematerialise=False):
         """(y, the expert layer's load, the index loss, the index's stats):
-        the model adds the last three up outside any rematerialised region."""
-        out, index_loss, stats = self.self_attn(self.input_layernorm(x),
-                                                position_ids)
-        x = x + out
+        the model adds the last three up outside any rematerialised region.
+
+        With `rematerialise` the block is two regions of
+        `fleet.utils.recompute` round the sparse-attention core, and the core
+        runs once, on the tape: its rerun would be the flash forward, the
+        sets kernel and a walk of every pair for the loss alone, for results
+        (the heads' outputs, the logsumexp, the sets) of a quarter of a
+        gigabyte a layer at 8192 positions (docs/kernels.md)."""
+        if rematerialise:
+            from ...distributed.fleet.utils import recompute as region
+        else:
+            def region(function, *args):
+                return function(*args)
+        # the positions ride in the closure: state the region reads
+        operands = region(lambda v: self.self_attn.operands(
+            self.input_layernorm(v), position_ids), x)
+        out, index_loss, stats = self.self_attn.core(*operands)
+        y, load = region(self._after_core, x, out)
+        return y, load, index_loss, stats
+
+    def _after_core(self, x, out):
+        x = x + self.self_attn.project(out)
         out, load = self.mlp(self.post_attention_layernorm(x))
-        return x + out, load, index_loss, stats
+        return x + out, load
 
 
 class KeyeVL2Model(nn.Layer):
@@ -249,14 +283,9 @@ class KeyeVL2Model(nn.Layer):
         0, 1, 2, ... in all three."""
         x = self.embed_tokens(input_ids)
         remat = self.config.recompute and self.training
-        if remat:
-            from ...distributed.fleet.utils import recompute
         index_loss = None
         for block in self.layers:
-            # the positions ride in the closure: state the region reads
-            x, load, layer_loss, stats = recompute(
-                lambda v, block=block: block(v, position_ids), x) \
-                if remat else block(x, position_ids)
+            x, load, layer_loss, stats = block(x, position_ids, remat)
             block.mlp.record_load(load)
             block.self_attn.indexer.record(stats)
             index_loss = layer_loss if index_loss is None else index_loss + layer_loss

@@ -81,6 +81,17 @@ def batch(cell, n=1):
     return out[0] if n == 1 else out
 
 
+def image_positions():
+    """An image's patch grid in the middle of the row: the temporal stream
+    stands still over it while height and width walk the grid."""
+    pos = np.broadcast_to(np.arange(SEQ)[None, None], (3, BATCH, SEQ)).copy()
+    pos[0, :, 32:96] = 32
+    pos[1, :, 32:96] = 32 + np.arange(64) // 8
+    pos[2, :, 32:96] = 32 + np.arange(64) % 8
+    pos[:, :, 96:] -= 64 - 8
+    return pos
+
+
 @pytest.fixture(scope="module")
 def cell():
     c = tiny()
@@ -184,14 +195,7 @@ def test_three_adamw_steps(cell, leaves):
 def test_unequal_position_streams(cell, leaves):
     ref, cfg = cell["family"].reference, cell["cfg"]
     x, y = batch(cell)
-    rng = np.random.default_rng(3)
-    # an image's patch grid in the middle of the row: the temporal stream
-    # stands still over it while height and width walk the grid
-    pos = np.broadcast_to(np.arange(SEQ)[None, None], (3, BATCH, SEQ)).copy()
-    pos[0, :, 32:96] = 32
-    pos[1, :, 32:96] = 32 + np.arange(64) // 8
-    pos[2, :, 32:96] = 32 + np.arange(64) % 8
-    pos[:, :, 96:] -= 64 - 8
+    pos = image_positions()
     want = ref.loss_parts(leaves, jnp.asarray(x), jnp.asarray(y), cfg,
                           positions=jnp.asarray(pos))
     text = ref.loss_parts(leaves, jnp.asarray(x), jnp.asarray(y), cfg)
@@ -201,13 +205,39 @@ def test_unequal_position_streams(cell, leaves):
                          position_ids=paddle.to_tensor(pos.astype(np.int32)))
     assert abs(float(lm.item()) - float(want[0])) < 2e-5 * float(want[0])
     assert abs(float(index.item()) - float(want[1])) < 2e-5 * float(want[1])
-    # under rematerialisation the positions ride in the region's closure
+    # under rematerialisation the positions ride in the first region's closure
     over = dict(cell, cfg=dict(cell["cfg"], recompute=True))
     model, _ = build(over, leaves)
     loss, _, index = model(paddle.to_tensor(x), labels=paddle.to_tensor(y),
                            position_ids=paddle.to_tensor(pos.astype(np.int32)))
     assert abs(float(index.item()) - float(want[1])) < 2e-5 * float(want[1])
     loss.backward()
+
+
+@pytest.mark.parametrize("positions", [None, "streams"], ids=["text", "streams"])
+def test_a_rematerialised_model_is_the_plain_model(cell, leaves, positions):
+    """Two regions round the core and the core on the tape are the plain
+    block's arithmetic: both losses and every leaf's gradient, with the
+    positions given (they ride in the first region's closure) and absent."""
+    x, y = (paddle.to_tensor(a) for a in batch(cell))
+    pos = None if positions is None else paddle.to_tensor(image_positions().astype(np.int32))
+    got = {}
+    for recompute in (False, True):
+        over = dict(cell, cfg=dict(cell["cfg"], recompute=recompute))
+        model, names = build(over, leaves)
+        loss, lm, index = model(x, labels=y, position_ids=pos)
+        loss.backward()
+        tensors = model.state_dict()
+        got[recompute] = (float(lm.item()), float(index.item()),
+                          {leaf: tensors[key].grad for leaf, key in names.items()})
+    (lm, index, grads), (lm_r, index_r, grads_r) = got[False], got[True]
+    assert abs(lm_r - lm) <= 1e-6 * lm and abs(index_r - index) <= 1e-6 * index
+    for leaf, grad in grads.items():
+        if grad is None:
+            assert leaf.endswith("expert_bias") and grads_r[leaf] is None
+            continue
+        assert float(jnp.linalg.norm(grad._val)) > 0.0, leaf
+        assert norm_gap(grads_r[leaf]._val, grad._val) <= 1e-6, leaf
 
 
 def test_the_shares_over_all_eight_sets_add_up_to_the_uncut_layer():
@@ -289,61 +319,101 @@ def test_a_faulty_program_is_told_from_the_model(cell, leaves, reference_grads, 
         assert all(float(jnp.linalg.norm(d_lm[k])) == 0.0 for k in d_lm if ".index_" in k)
 
 
-def test_a_rematerialised_step_stages_the_scopes_and_moves_the_counters(monkeypatch):
+@pytest.fixture(scope="module")
+def traced_step():
+    """One training step over rematerialised blocks, run once eagerly (the
+    discovery pass) and traced once, on a platform rule that says TPU so that
+    attention over the sets takes the flash pair and the index its kernels
+    (interpreted on a CPU). What each pass called of the sparse-attention
+    core, what the counters moved by, and the scopes of the lowered text."""
+    import re
+    from paddle_tpu.jit.to_static import _flatten_tensors
+    from paddle_tpu.ops import attention
+    from paddle_tpu.ops.pallas import flash_attention
+    from paddle_tpu.ops.pallas import sparse_index as kernels
+    from paddle_tpu.profiler import metrics
+    calls, bodies = [], []
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, "_platform", lambda: "tpu")
+        patch.setattr(attention, "FLASH_MIN_SEQ_K", 128)
+        patch.setattr(attention, "FLASH_MIN_SEQ_Q", 128)
+        for module, name in ((kernels, "index_sets"), (kernels, "index_loss_walk"),
+                             (flash_attention, "flash_attention_set_fwd")):
+            patch.setattr(module, name, lambda *a, _f=getattr(module, name), _n=name, **kw:
+                          calls.append((_n, kw.get("with_grads"))) or _f(*a, **kw))
+        cell = tiny(recompute=True, head_dim=64)
+        cell["cfg"]["rope_scaling"]["mrope_section"] = [8, 12, 12]
+        cell["cfg"]["sa_config"]["indexer_head_dim"] = 64     # the index's kernels too
+        family = cell["family"]
+        model, _ = build(cell, seeded(cell))
+        opt = paddle.optimizer.AdamW(learning_rate=1e-4, parameters=model.parameters())
+
+        @paddle.jit.to_static
+        def step(x, y):
+            bodies.append(len(calls))                 # one run of the body a pass
+            loss = family.loss_of(model, x, y)
+            loss.backward()
+            opt.step()
+            opt.clear_grad()
+            return loss
+
+        def counters():
+            return dict(metrics.get_registry().snapshot()["counters"])
+        x, y = (paddle.to_tensor(a) for a in batch(cell))
+        before = counters()
+        step(x, y)                                    # the eager discovery pass
+        eager, discovered = list(calls), counters()
+        (prog,) = step.programs.values()
+        step._build(prog, (x, y), {})                 # traces; compiles nothing
+        built, after = list(calls), counters()
+        text = prog.jitted_donate.lower(
+            tuple(t._val for t in prog.mutated), tuple(t._val for t in prog.ro),
+            tuple(t._val for t in _flatten_tensors(((x, y), {}), []))
+        ).as_text(debug_info=True)
+
+    def moved(then, now):
+        return {k: now[k] - then.get(k, 0.0) for k in now}
+    return {"layers": cell["cfg"]["num_layers"], "text": text,
+            "names": set(re.findall(r'loc\("(jit\(pure_fn\)/[^"]*)"', text)),
+            # the body's runs: the eager pass, `_build`'s traces, `lower`'s
+            "passes": {"eager": 1, "traced": sum(len(eager) <= at < len(built) for at in bodies)},
+            "calls": {"eager": eager, "traced": built[len(eager):], "lowered": calls[len(built):]},
+            "moved": {"eager": moved(before, discovered), "traced": moved(discovered, after),
+                      "both": moved(before, after)}}
+
+
+def test_a_rematerialised_step_stages_the_scopes_and_moves_the_counters(traced_step):
     """`dsa_index`, `dsa_index_loss`, `flash_attention` and `moe_experts` on
     forward, rerun and backward instructions of a step whose blocks are
     rematerialised; attention over the sets takes the flash pair where the
     platform rule says TPU; the device counters follow the steps."""
-    import re
-    from benchmarks import program_trace
-    from paddle_tpu.jit.to_static import _flatten_tensors
-    from paddle_tpu.ops import attention
-    from paddle_tpu.profiler import metrics
-    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
-    monkeypatch.setattr(attention, "FLASH_MIN_SEQ_K", 128)
-    monkeypatch.setattr(attention, "FLASH_MIN_SEQ_Q", 128)
-    cell = tiny(recompute=True, head_dim=64)
-    cell["cfg"]["rope_scaling"]["mrope_section"] = [8, 12, 12]
-    cell["cfg"]["sa_config"]["indexer_head_dim"] = 64     # the index's kernels too
-    family, cfg = cell["family"], cell["cfg"]
-    model, _ = build(cell, seeded(cell))
-    opt = paddle.optimizer.AdamW(learning_rate=1e-4, parameters=model.parameters())
-    from paddle_tpu.ops.pallas import sparse_index as kernels
-    staged = set()
-    for name in ("index_sets", "index_loss_walk"):
-        monkeypatch.setattr(kernels, name, lambda *a, _f=getattr(kernels, name),
-                            _n=name, **kw: staged.add(_n) or _f(*a, **kw))
-
-    @paddle.jit.to_static
-    def step(x, y):
-        loss = family.loss_of(model, x, y)
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-        return loss
-
-    def counters():
-        return metrics.get_registry().snapshot()["counters"]
-    x, y = (paddle.to_tensor(a) for a in batch(cell))
-    before = counters()
-    step(x, y)                                    # the eager discovery pass
-    (prog,) = step.programs.values()
-    step._build(prog, (x, y), {})                 # traces; compiles nothing
-    after = counters()
-    assert after["attention.flash_total"] > before.get("attention.flash_total", 0.0)
-    moved = {k: after[k] - before.get(k, 0.0) for k in after if k.startswith("dsa.")}
+    from benchmarks import kernel_costs_keye, program_trace
+    moved, names = traced_step["moved"]["both"], traced_step["names"]
+    assert moved["attention.flash_total"] > 0
     assert moved["dsa.calls_total"] == 2 and moved["dsa.queries_total"] == 2 * BATCH * SEQ
-    from benchmarks import kernel_costs_keye
     assert moved["dsa.selected_pairs_total"] >= 2 * BATCH * kernel_costs_keye.set_pairs(SEQ, 32)
     assert moved["dsa.tiles_skipped_total"] == 0  # one tile a row at this size
-    text = prog.jitted_donate.lower(
-        tuple(t._val for t in prog.mutated), tuple(t._val for t in prog.ro),
-        tuple(t._val for t in _flatten_tensors(((x, y), {}), []))
-    ).as_text(debug_info=True)
-    names = set(re.findall(r'loc\("(jit\(pure_fn\)/[^"]*)"', text))
-    assert staged == {"index_sets", "index_loss_walk"}     # interpreted on a CPU
+    staged = {name for name, _ in traced_step["calls"]["lowered"]}
+    assert {"index_sets", "index_loss_walk"} <= staged     # interpreted on a CPU
     for scope in ("dsa_index", "dsa_index_loss", "flash_attention", "moe_experts", "rope"):
         mine = [n for n in names if program_trace.scope_of(n + "/op") == scope]
         assert any(n.startswith(f"jit(pure_fn)/jvp({scope})") for n in mine), scope
         assert any(f"transpose(jvp(" in n for n in mine) or scope == "dsa_index", scope
-    assert "checkpoint" not in text               # a custom_vjp region keeps the names
+    assert "checkpoint" not in traced_step["text"]  # a custom_vjp region keeps the names
+
+
+@pytest.mark.parametrize("which", ["eager", "traced"])
+def test_a_rematerialised_step_runs_its_sparse_attention_core_once(traced_step, which):
+    """The core is outside the block's two regions: a layer a pass of the
+    step's body (the eager discovery pass; each trace of the step program)
+    one sets kernel, one flash forward over the sets and one walk of the
+    index loss, the one that forms the gradients with the value; no walk for
+    the loss alone, and no second forward in a region's discovery, first run
+    or rerun."""
+    runs = traced_step["passes"][which] * traced_step["layers"]
+    assert runs > 0
+    calls = traced_step["calls"][which]
+    assert sorted(calls) == sorted(runs * [
+        ("index_sets", None), ("flash_attention_set_fwd", None),
+        ("index_loss_walk", True)]), calls
+    assert traced_step["moved"][which]["attention.flash_total"] == runs
