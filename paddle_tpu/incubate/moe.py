@@ -330,7 +330,18 @@ _LAYERS = weakref.WeakSet()
 
 
 def _layer_totals(name):
-    return [float(getattr(layer, name)._val) for layer in _LAYERS]
+    """`name`'s device counter of every live layer that keeps it (the
+    balance loss's two only where the layer computes one)."""
+    return [float(getattr(layer, name)._val) for layer in _LAYERS
+            if hasattr(layer, name)]
+
+
+def _worst_mean_a_call(name):
+    """The largest, over the live layers that keep `name` and have been
+    called, of that counter over the layer's calls; 0 where none has."""
+    return max((float(getattr(layer, name)._val) / float(layer.calls_total._val)
+                for layer in _LAYERS
+                if hasattr(layer, name) and float(layer.calls_total._val)), default=0.0)
 
 
 # Routing counters kept on the device and fetched only when the registry is
@@ -340,11 +351,42 @@ _metrics.get_registry().register_counter_fn(
     "moe.rows_here_total", lambda: sum(_layer_totals("rows_total")))
 _metrics.get_registry().register_counter_fn(
     "moe.layer_calls_total", lambda: sum(_layer_totals("calls_total")))
-_metrics.get_registry().register_gauge_fn("moe.live_layers_count", lambda: len(_LAYERS))
-_metrics.get_registry().register_gauge_fn(
-    "moe.load_max_over_mean_ratio", lambda: max(
-        (i / c for i, c in zip(_layer_totals("imbalance_total"),
-                               _layer_totals("calls_total")) if c), default=0.0))
+_metrics.get_registry().register_counter_fn(
+    "moe.balance_loss_total", lambda: sum(_layer_totals("balance_total")))
+
+
+def _register_gauges():
+    """At import and again whenever a layer is built: the registry's `reset`
+    drops gauge functions, and their owner registers them again."""
+    registry = _metrics.get_registry()
+    registry.register_gauge_fn("moe.live_layers_count", lambda: len(_LAYERS))
+    registry.register_gauge_fn(
+        "moe.load_max_over_mean_ratio", lambda: _worst_mean_a_call("imbalance_total"))
+    registry.register_gauge_fn(
+        "moe.router_max_over_mean_ratio",
+        lambda: _worst_mean_a_call("router_imbalance_total"))
+
+
+_register_gauges()
+
+
+def _balance(scores, picked, sequences, alpha):
+    """(the sequence-wise balance loss, the picks of each published expert
+    (E,) float32). scores (N, E) float32, the router's over every published
+    expert; picked (N, k) their top k; the N tokens are `sequences` rows of
+    N / sequences. A sequence b: f_e = E / (k T) x the picks of e among its T
+    tokens, P_e = the mean of its scores of e; the loss is alpha x the mean
+    over the sequences of sum_e f_e P_e (DeepSeek-V2, `seq_aux`). f is a
+    count: the gradient flows through P alone."""
+    n, e = scores.shape
+    k, t = picked.shape[1], n // sequences
+    chosen = picked[:, :, None] == jnp.arange(e, dtype=picked.dtype)     # (N, k, E)
+    picks = jnp.sum(chosen, axis=1, dtype=jnp.float32).reshape(sequences, t, e)
+    picks = jnp.sum(picks, axis=1)                                       # (B, E)
+    f = jax.lax.stop_gradient(picks * (e / (k * t)))
+    mean_score = jnp.mean(scores.reshape(sequences, t, e), axis=1)
+    return (alpha * jnp.mean(jnp.sum(f * mean_score, axis=1)),
+            jnp.sum(picks, axis=0))
 
 
 class DroplessMoELayer(nn.Layer):
@@ -358,7 +400,10 @@ class DroplessMoELayer(nn.Layer):
     softmax's values renormalised over the picked). The `top_k` experts are the largest of
     s + expert_bias (a parameter that takes no gradient; a balancing rule
     outside this layer may move it); their weights are the un-biased scores
-    normalised over the k picked, times `routed_scaling_factor`. The layer
+    normalised over the k picked, times `routed_scaling_factor`; with
+    `renormalize=False` the scores as the router gave them, times the factor
+    (DeepSeek-V2's `norm_topk_prob: false`: a softmax's top k then sum to
+    less than 1). The layer
     holds `held_experts` (ids among the published ones; all by default) as
     stacked SwiGLU weights w1, w3 (held, d_model, d_hidden) and w2 (held,
     d_hidden, d_model), and returns sum over the picked experts *held here*
@@ -391,12 +436,30 @@ class DroplessMoELayer(nn.Layer):
     moved by `record_load` with what `forward` returns beside the result
     (`rows_total`, `calls_total`, `imbalance_total`; the registry's
     `moe.rows_here_total`, `moe.layer_calls_total`, `moe.load_max_over_mean_ratio`).
+
+    `balance_alpha` fixes at construction how many values `forward` returns:
+    None (no such loss) gives (out, load), as every model built without it
+    unpacks; a number, 0.0 included, gives (out, load, balance_loss, picks)
+    and the two counters below. It is DeepSeek-V2's
+    sequence-wise balance loss (`seq_aux`; `_balance`), a float32 scalar for
+    the caller to add to its training loss, made from the scores the layer
+    already has and the picks the plan already made, under the scope
+    `moe_balance_loss`. Its f runs over the `num_experts` published experts,
+    whatever is held here and whatever stands in for what: the router is
+    whole on every rank, so every share computes the same loss. The first
+    axis of a three-dimensional `x` counts the sequences. `picks` (the picks
+    of each published expert) go with `load` to `record_load`, which then
+    also moves `balance_total` and `router_imbalance_total` (the registry's
+    `moe.balance_loss_total`, to be divided by `moe.layer_calls_total`, and
+    `moe.router_max_over_mean_ratio`: the most-picked published expert's
+    picks over the mean of all, which `moe.load_max_over_mean_ratio` cannot
+    see where stand-ins fold the published experts onto the held slots).
     """
 
     def __init__(self, d_model, d_hidden, num_experts, top_k,
                  held_experts=None, routed_scaling_factor=1.0,
                  weight_attr=None, shared_width=None, score="sigmoid",
-                 absent="drop"):
+                 absent="drop", renormalize=True, balance_alpha=None):
         super().__init__()
         from ..ops.pallas.grouped_matmul import ROW_TILE
         if score not in ("sigmoid", "softmax"):
@@ -408,6 +471,7 @@ class DroplessMoELayer(nn.Layer):
             raise InvalidArgumentError(
                 f"absent {absent!r} is neither 'drop' nor 'stand_in'")
         self.score, self.absent = score, absent
+        self.renormalize, self.balance_alpha = renormalize, balance_alpha
         held = list(range(num_experts)) if held_experts is None \
             else [int(e) for e in held_experts]
         if len(set(held)) != len(held) or not all(0 <= e < num_experts for e in held):
@@ -437,10 +501,13 @@ class DroplessMoELayer(nn.Layer):
         # (data parallelism leaves it so), so across shares it counts once
         self.shared = None if shared_width is None else nn.SwiGLUFFN(
             d_model, shared_width, weight_attr=weight_attr)
-        for name in ("rows_total", "calls_total", "imbalance_total"):
+        for name in ("rows_total", "calls_total", "imbalance_total") + (
+                () if balance_alpha is None
+                else ("balance_total", "router_imbalance_total")):
             self.register_buffer(name, Tensor(jnp.zeros((), jnp.float32)),
                                  persistable=False)
         _LAYERS.add(self)
+        _register_gauges()
 
     def buffer_rows(self, n_tokens):
         """Rows of the sorted buffer for `n_tokens` tokens: every pair held
@@ -497,6 +564,8 @@ class DroplessMoELayer(nn.Layer):
             # tables; its transpose is then no scatter either
             chosen = picked[:, :, None] == jnp.arange(s.shape[1], dtype=picked.dtype)
             w = jnp.sum(jnp.where(chosen, s[:, None, :], 0), axis=2)
+            if not self.renormalize:
+                return w * scale
             return w / (jnp.sum(w, axis=1, keepdims=True) + norm_eps) * scale
         w = apply(weigh, scores, idx, name="moe_route")
         xs = apply(lambda v, rp, rv, pr, nt, *hp: _gather_rows(
@@ -515,18 +584,33 @@ class DroplessMoELayer(nn.Layer):
             name="moe_combine").reshape(shape)
         if self.shared is not None:
             out = out + self.shared(x)
-        return out, apply(lambda c: c.astype(jnp.float32), counts, name="moe_route")
+        load = apply(lambda c: c.astype(jnp.float32), counts, name="moe_route")
+        if self.balance_alpha is None:
+            return out, load
+        sequences = shape[0] if len(shape) == 3 else 1
+        balance, picks = apply(
+            lambda s, picked: _balance(s, picked, sequences, self.balance_alpha),
+            scores, idx, name="moe_balance_loss")
+        return out, load, balance, picks.detach()
 
-    def record_load(self, load):
-        """Add one call's rows per held expert to the device counters."""
+    def record_load(self, load, balance=None, picks=None):
+        """Add one call's rows per held expert to the device counters; with
+        them, where the layer computes a balance loss, the loss and the
+        published experts' picks."""
         def add(rows, calls, imbalance, c):
             total = jnp.sum(c)
             return (rows + total, calls + 1.0, imbalance
                     + jnp.max(c) * c.shape[0] / jnp.maximum(total, 1.0))
+
+        def add_balance(total, worst, loss, p):
+            return total + loss, worst + jnp.max(p) * p.shape[0] / jnp.maximum(jnp.sum(p), 1.0)
+        totals = (self.rows_total, self.calls_total, self.imbalance_total)
         from ..core import autograd
         with autograd.no_grad():
-            new = apply(add, self.rows_total, self.calls_total,
-                        self.imbalance_total, load, name="moe_route")
-        for t, v in zip((self.rows_total, self.calls_total,
-                         self.imbalance_total), new):
+            new = list(apply(add, *totals, load, name="moe_route"))
+            if balance is not None:
+                totals += (self.balance_total, self.router_imbalance_total)
+                new += apply(add_balance, *totals[3:], balance.detach(), picks,
+                             name="moe_balance_loss")
+        for t, v in zip(totals, new):
             t._value = v._val

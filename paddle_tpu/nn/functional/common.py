@@ -15,7 +15,7 @@ __all__ = [
     "embedding", "one_hot", "label_smooth", "pad", "interpolate", "upsample",
     "pixel_shuffle", "pixel_unshuffle", "channel_shuffle", "unfold", "fold",
     "cosine_similarity", "bilinear", "class_center_sample", "zeropad2d",
-    "rotary_position_embedding",
+    "rotary_position_embedding", "yarn_frequencies", "yarn_scales",
 ]
 
 
@@ -79,22 +79,111 @@ def alpha_dropout(x, p=0.5, training=True, name=None):
     return apply(prim, x, kd, name="alpha_dropout")
 
 
+def yarn_frequencies(dim, theta, rope_scaling):
+    """(dim / 2,) float32: the angle a position turns pair n by under YaRN
+    (Peng et al. 2023, "NTK-by-parts"), as DeepSeek-V2's modelling code forms
+    it. theta_n = theta^(-2n/dim) is blended with theta_n / factor: pairs
+    that turn more than `beta_fast` times over the original context keep
+    their frequency, those that turn fewer than `beta_slow` times are slowed
+    by `factor`, a linear ramp between (low = floor, high = ceil of the pair
+    index at which a pair turns beta_fast, beta_slow times). Worked in
+    float64 and rounded once."""
+    factor, original = rope_scaling["factor"], rope_scaling["original_max_position_embeddings"]
+    plain = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair_turning(rotations):
+        return dim * np.log(original / (rotations * 2 * np.pi)) / (2 * np.log(theta))
+    low = max(np.floor(pair_turning(rope_scaling.get("beta_fast", 32))), 0)
+    high = min(np.ceil(pair_turning(rope_scaling.get("beta_slow", 1))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (plain * keep + plain / factor * (1.0 - keep)).astype(np.float32)
+
+
+def yarn_scales(rope_scaling):
+    """(what cos and sin are multiplied by, what the softmax scale is
+    multiplied by) under YaRN: with m(a) = 0.1 a ln(factor) + 1 (1 at
+    factor <= 1), m(mscale) / m(mscale_all_dim) and m(mscale_all_dim)^2."""
+    def m(a):
+        factor = rope_scaling["factor"]
+        return 1.0 if factor <= 1 else 0.1 * a * float(np.log(factor)) + 1.0
+    every = m(rope_scaling.get("mscale_all_dim", 0))
+    return m(rope_scaling.get("mscale", 1)) / every, every * every
+
+
+def _rope_tables(dim, seq, theta, position_offset, rope_scaling):
+    """(cos, sin), each (seq, dim / 2) float32, of positions
+    position_offset ... at theta^(-2n/dim), or under YaRN (`rope_scaling`) at
+    `yarn_frequencies` and times `yarn_scales`' first factor: what
+    `_rotate_pairs` reads. jax.numpy out."""
+    if rope_scaling is None:
+        inv, table_scale = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim), 1.0
+    else:
+        inv = jnp.asarray(yarn_frequencies(dim, theta, rope_scaling))
+        table_scale = yarn_scales(rope_scaling)[0]
+    t = jnp.arange(position_offset, position_offset + seq, dtype=jnp.float32)
+    angle = t[:, None] * inv[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if table_scale != 1.0:
+        cos, sin = cos * table_scale, sin * table_scale
+    return cos, sin
+
+
+def _rotate_pairs(v, cos, sin, interleaved):
+    """`v` (batch, seq, heads, dim) with each pair of entries turned by its
+    angle, float32 arithmetic, v's dtype out; cos, sin (seq, dim / 2).
+    Half-split pairing: entry n with entry n + dim / 2. `interleaved`: entry
+    2n with entry 2n + 1, the result stored half-split. jax.numpy in,
+    jax.numpy out."""
+    f = v.astype(jnp.float32)
+    half = v.shape[-1] // 2
+    a, b = (f[..., 0::2], f[..., 1::2]) if interleaved else (f[..., :half], f[..., half:])
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(v.dtype)
+
+
 def rotary_position_embedding(q, k, theta=10000.0, position_offset=0,
-                              name=None, position_ids=None, sections=None):
+                              name=None, position_ids=None, sections=None,
+                              rope_scaling=None, interleaved=False):
     """Rotary positions (Su et al. 2021) on `q` and `k`, each (batch, seq,
-    heads, head_dim), in the rotate-half convention over the whole head:
-    entry i pairs with entry i + head_dim/2 and the pair at position t turns
-    by t * theta^(-2i/head_dim). Angles and the rotation are float32; the
-    results keep their dtypes. `position_offset` is the position of the
-    first row (a cached decode step).
+    heads, head_dim); their head counts may differ (one key head under many
+    query heads). Angles and the rotation are float32; the results keep
+    their dtypes. `position_offset` is the position of the first row (a
+    cached decode step).
+
+    Pairing. By default the rotate-half convention over the whole head:
+    entry i pairs with entry i + head_dim/2. `interleaved=True` pairs entry
+    2n with entry 2n + 1 (the layout of DeepSeek-V2's weights) and stores
+    the result half-split (the turned pair n at n and n + head_dim/2), as
+    that model's code leaves it: a reordering that queries and keys share, so
+    their products are those of the interleaved order.
+
+    Frequencies. Pair n at position t turns by t * theta^(-2n/head_dim) by
+    default; by YaRN's blend where `rope_scaling` is a dict of `type` "yarn"
+    (`yarn_frequencies`; cos and sin then carry `yarn_scales`' first factor,
+    and the caller owes the softmax its second).
 
     `position_ids` gives the positions instead: (batch, seq), or (streams,
     batch, seq) with `sections`, how many of the head_dim / 2 frequency pairs
     each stream turns, in order (multimodal rotary positions: (16, 24, 24)
     gives pairs 0-15 the first stream's position, 16-39 the second's, 40-63
     the third's). Equal streams give what one stream gives."""
+    if rope_scaling is not None:
+        kind = rope_scaling.get("type", rope_scaling.get("rope_type"))
+        if kind != "yarn":
+            raise ValueError(f"rope_scaling of type {kind!r}: only 'yarn' is computed")
+
     def prim(qv, kv, *pos):
         d, s = qv.shape[-1], qv.shape[1]
+        if rope_scaling is not None or interleaved:
+            if pos:
+                raise ValueError("position_ids under rope_scaling or the "
+                                 "interleaved pairing are not computed")
+            cos, sin = _rope_tables(d, s, theta, position_offset, rope_scaling)
+            return (_rotate_pairs(qv, cos, sin, interleaved),
+                    _rotate_pairs(kv, cos, sin, interleaved))
         inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
         if not pos:
             t = jnp.arange(position_offset, position_offset + s, dtype=jnp.float32)
@@ -124,7 +213,7 @@ def rotary_position_embedding(q, k, theta=10000.0, position_offset=0,
         return turn(qv), turn(kv)
 
     extra = [] if position_ids is None else [position_ids]
-    return apply(prim, q, k, *extra, name="rope")
+    return apply(prim, q, k, *extra, name=name or "rope")
 
 
 def embedding(x, weight, padding_idx=None, sparse=False, name=None):

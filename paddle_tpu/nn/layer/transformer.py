@@ -357,8 +357,10 @@ class Transformer(Layer):
 
 
 class MultiHeadLatentAttention(Layer):
-    """Causal multi-head latent attention without rotation (DeepSeek-V2's
-    MLA as Kimi Linear uses it: `q_lora_rank` null, `mla_use_nope` true).
+    """Causal multi-head latent attention (DeepSeek-V2's MLA with
+    `q_lora_rank` null), in one of two modes: without rotation, as Kimi
+    Linear uses it (`mla_use_nope` true; the default), or with the decoupled
+    rotary part, as DeepSeek-V2 has it (`rope` given).
 
     q_h = W_q^h x, `qk_nope_head_dim + qk_rope_head_dim` wide; c = W_kva x,
     `kv_lora_rank + qk_rope_head_dim` wide: its first part, RMS-normed, is the
@@ -367,19 +369,43 @@ class MultiHeadLatentAttention(Layer):
     heads share; k_h = [k_nope_h ; k_shared]. The value heads are
     `v_head_dim` wide, the query/key heads wider, and
     F.scaled_dot_product_attention takes the two sizes as they are (no v
-    padded to the keys' width). The shared key part is broadcast over the
-    heads when k is put together, which writes it `num_heads` times: a third
-    of k's bytes at 128 + 64, 33 MB of a 100 MB k at 2 x 4096 tokens and 32
-    heads in bfloat16 (the flash kernels take one key operand). No bias."""
+    padded to the keys' width). No bias.
+
+    `rope`: a dict with `theta`, and `rope_scaling` (None, or YaRN's as
+    F.rotary_position_embedding takes it). The last `qk_rope_head_dim`
+    entries of every query head and the shared key part are then turned by
+    their positions 0, 1, 2, ... (pairs of neighbouring entries, as the
+    source's weights are laid out: F.rotary_position_embedding's
+    `interleaved`), the first `qk_nope_head_dim` are not, and under YaRN the
+    softmax scale is the heads' width ** -0.5 times m(mscale_all_dim)^2
+    (F.yarn_scales). The slices,
+    the rotation and the query put together again stage under the scope
+    `mla_rope`; the shared part is turned once, as one head, before it is
+    broadcast.
+
+    The shared key part is broadcast over the heads when k is put together
+    (scope `mla_kv`), which writes it `num_heads` times: a third of k's bytes
+    at 128 + 64, 33 MB of a 100 MB k at 2 x 4096 tokens and 32 heads in
+    bfloat16 (the flash kernels take one key operand). Rotated or not, what
+    is broadcast is one (batch, seq, 64) part, so the rotation adds nothing
+    to that cost: it reads and writes the 64 shared entries a token once and
+    64 of every query head's 192."""
 
     def __init__(self, hidden_size, num_heads, kv_lora_rank, qk_nope_head_dim,
-                 qk_rope_head_dim, v_head_dim, epsilon=1e-05, weight_attr=None):
+                 qk_rope_head_dim, v_head_dim, epsilon=1e-05, weight_attr=None,
+                 rope=None):
         super().__init__()
         self.num_heads = num_heads
         self.kv_lora_rank = kv_lora_rank
         self.qk_nope_head_dim = qk_nope_head_dim
         self.qk_rope_head_dim = qk_rope_head_dim
         self.v_head_dim = v_head_dim
+        self.rope = rope
+        # the softmax's scale: None is the heads' width ** -0.5
+        self.scale = None
+        if rope is not None and rope.get("rope_scaling") is not None:
+            self.scale = (qk_nope_head_dim + qk_rope_head_dim) ** -0.5 \
+                * F.yarn_scales(rope["rope_scaling"])[1]
 
         def linear(n_in, n_out):
             return Linear(n_in, n_out, weight_attr=weight_attr, bias_attr=False)
@@ -403,6 +429,16 @@ class MultiHeadLatentAttention(Layer):
                                self.kv_a_proj(x), name="mla_kv")
         kv = M.reshape(self.kv_b_proj(self.kv_a_norm(latent)),
                        [b, s, heads, nope + dv])
+        if self.rope is not None:
+            q_nope, q_pe = apply(lambda v: (v[..., :nope], v[..., nope:]), q,
+                                 name="mla_rope")
+            q_pe, shared = F.rotary_position_embedding(
+                q_pe, M.reshape(shared, [b, s, 1, self.qk_rope_head_dim]),
+                theta=self.rope["theta"], rope_scaling=self.rope.get("rope_scaling"),
+                interleaved=True, name="mla_rope")
+            q = apply(lambda a, c: jnp.concatenate([a, c], axis=-1), q_nope, q_pe,
+                      name="mla_rope")
+            shared = M.reshape(shared, [b, s, self.qk_rope_head_dim])
 
         def keys_values(kv_, shared_):
             pe = jnp.broadcast_to(shared_[:, :, None, :],
@@ -410,8 +446,8 @@ class MultiHeadLatentAttention(Layer):
             return (jnp.concatenate([kv_[..., :nope], pe], axis=-1),
                     kv_[..., nope:])
         k, v = apply(keys_values, kv, shared, name="mla_kv")
-        out = F.scaled_dot_product_attention(q, k, v, is_causal=True,
-                                             training=self.training)
+        out = F.scaled_dot_product_attention(
+            q, k, v, is_causal=True, training=self.training, scale=self.scale)
         return self.o_proj(M.reshape(out, [b, s, heads * dv]))
 
 

@@ -1,4 +1,7 @@
 from .bert import BertModel, BertForSequenceClassification  # noqa: F401
+from .deepseek_v2 import (  # noqa: F401
+    DeepseekV2Config, DeepseekV2ForCausalLM, DeepseekV2Model,
+)
 from .ernie import (  # noqa: F401
     ErnieConfig, ErnieForSequenceClassification, ErnieModel,
 )
@@ -16,4 +19,5 @@ __all__ = ["BertModel", "BertForSequenceClassification", "GPTModel",
            "ErnieForSequenceClassification", "LFM2Config", "LFM2Model",
            "LFM2ForCausalLM", "KimiLinearConfig", "KimiLinearModel",
            "KimiLinearForCausalLM", "KeyeVL2Config", "KeyeVL2Model",
-           "KeyeVL2ForCausalLM"]
+           "KeyeVL2ForCausalLM", "DeepseekV2Config", "DeepseekV2Model",
+           "DeepseekV2ForCausalLM"]
