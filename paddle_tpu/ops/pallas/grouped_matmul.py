@@ -21,6 +21,22 @@ index maps. Because a tile never straddles two groups here, there is no
 masking and no revisiting of output tiles. The whole contraction axis is one
 block (K is a model width of a few thousand), so gmm needs no accumulator.
 
+The column tile `tn` follows the shapes of the call (`_col_tile`). The row
+operand's block (tm, K) changes at every grid step, so with the grid
+(N / tn, num_tiles) all of it crosses HBM N / tn times, while a group's
+weight block is fetched once whatever tn is (a group's tiles are
+consecutive). So tn is the whole width N, the grid (1, num_tiles) and the
+row operand read once, whenever a step's blocks fit `VMEM_BUDGET_BYTES`
+(`gmm_vmem_bytes`, `tgmm_vmem_bytes`: reckoned from tm, K, N and the item
+size); where they do not, the largest divisor of N that is a multiple of
+128 and fits. At 51,200 rows of bf16 a product of 2048 x 1408 under the
+old rule (halve from 512 until the tile divides N: 128, as 1408 = 11 x 128)
+read its row operand eleven times, 2.31 GB and 2.8 ms at 819 GB/s for 1.5
+ms of products, and took 3.4 ms; one of 1408 x 2048 (tn 512, four passes,
+0.58 GB) 1.7; with the width as one block both take 1.55 (docs/kernels.md,
+"The expert layer's kernels"). The contraction stays one block, so tn
+changes no sum's order.
+
 `grouped_matmul` is the differentiable entry: its backward is a gmm against
 the transposed weights (read transposed by the block spec, never copied) and
 a tgmm. `gmm_flops` and friends for a roofline live with the benchmark
@@ -36,17 +52,39 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 ROW_TILE = 256      # tm: rows of a tile, and the alignment of a group's rows
-COL_TILE = 512      # tn: output columns of a block
 # x tile, weight block and output block, double-buffered, and tgmm's float32
-# accumulator pass the 16 MiB the compiler allows a kernel by default
+# accumulator pass the 16 MiB the compiler allows a kernel by default (the
+# chip has 128)
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
+# what a step's blocks may take of it: the rest is the compiler's own
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES * 3 // 4
 
 
-def _col_tile(n):
-    tn = min(COL_TILE, n)
-    while n % tn:
-        tn //= 2
-    return tn
+def gmm_vmem_bytes(tm, k, tn, itemsize):
+    """What one grid step of gmm holds: the row tile, the weight block and
+    the output tile, each twice (the next is fetched while this one is
+    computed), and the float32 product."""
+    return 2 * itemsize * (tm * k + k * tn + tm * tn) + 4 * tm * tn
+
+
+def tgmm_vmem_bytes(tm, k, tn, itemsize):
+    """One grid step of tgmm: the two row tiles and the output block, each
+    twice, and the float32 accumulator (the product is added to it in
+    pieces: the compiler takes 62 MiB so reckoned and refuses 70)."""
+    return 2 * itemsize * (tm * k + tm * tn + k * tn) + 4 * k * tn
+
+
+def _col_tile(vmem_bytes, tm, k, n, itemsize):
+    """The widest block of output columns whose step fits the budget by
+    `vmem_bytes` (one of the two above): N itself, else the largest divisor
+    of N that is a multiple of 128 (a block's last dimension is that or the
+    whole)."""
+    widths = [n] + [tn for tn in range((n - 1) // 128 * 128, 0, -128)
+                    if n % tn == 0]
+    for tn in widths:
+        if vmem_bytes(tm, k, tn, itemsize) <= VMEM_BUDGET_BYTES:
+            return tn
+    return widths[-1]
 
 
 def _params(interpret):
@@ -73,7 +111,8 @@ def gmm(x, w, tile_group, num_tiles, transpose_w=False, tm=ROW_TILE,
     rows, k = x.shape
     n = w.shape[1] if transpose_w else w.shape[2]
     assert rows % tm == 0 and tile_group.shape == (rows // tm,), (x.shape, tm)
-    tn = _col_tile(n)
+    tn = _col_tile(gmm_vmem_bytes, tm, k, n,
+                   max(x.dtype.itemsize, w.dtype.itemsize))
     if transpose_w:
         w_spec = pl.BlockSpec((None, tn, k), lambda j, i, tg: (tg[i], j, 0))
     else:
@@ -82,8 +121,9 @@ def gmm(x, w, tile_group, num_tiles, transpose_w=False, tm=ROW_TILE,
         functools.partial(_gmm_kernel, transpose_w=transpose_w),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            # columns outside, tiles inside: successive tiles of one group
-            # keep their weight block
+            # columns outside, tiles inside (one block of columns where the
+            # width fits): successive tiles of one group keep their weight
+            # block
             grid=(n // tn, num_tiles),
             in_specs=[pl.BlockSpec((tm, k), lambda j, i, tg: (i, 0)), w_spec],
             out_specs=pl.BlockSpec((tm, tn), lambda j, i, tg: (i, j)),
@@ -123,7 +163,7 @@ def tgmm(x, dy, tile_group, num_tiles, groups, tm=ROW_TILE, interpret=False):
     rows, k = x.shape
     n = dy.shape[1]
     assert rows % tm == 0 and dy.shape[0] == rows, (x.shape, dy.shape, tm)
-    tn = _col_tile(n)
+    tn = _col_tile(tgmm_vmem_bytes, tm, k, n, x.dtype.itemsize)
     return pl.pallas_call(
         _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

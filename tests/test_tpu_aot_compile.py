@@ -263,16 +263,12 @@ def test_backward_in_spans_compiles_at_long_sequences(one_chip, bh, rows_kv, s, 
         block_k=512, interpret=False).compile(), 1)
 
 
-@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)],
-                         ids=["up-2048x1536", "down-1536x2048"])
-def test_grouped_products_compile_at_lfm2_widths(one_chip, k, n):
+def _compile_grouped_products(one_chip, k, n, rows, groups):
     """The three kernels a projection of the expert layer runs in a train
-    step (product, product against the transposed weights, weight gradient),
-    over the worst-case buffer of 2 x 4096 tokens x 4 experts, with the grid
-    a run-time value."""
+    step (product, product against the transposed weights, weight gradient)
+    over a buffer of `rows` bf16 rows, with the grid a run-time value."""
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import grouped_matmul as gm
-    rows, groups = 2 * 4096 * 4 + 8 * gm.ROW_TILE, 8
     x = _sds((rows, k), jnp.bfloat16, one_chip)
     dy = _sds((rows, n), jnp.bfloat16, one_chip)
     w = _sds((groups, k, n), jnp.bfloat16, one_chip)
@@ -281,6 +277,14 @@ def test_grouped_products_compile_at_lfm2_widths(one_chip, k, n):
     _assert_kernel(gm.gmm.lower(x, w, tiles, used).compile(), 1)
     _assert_kernel(gm.gmm.lower(dy, w, tiles, used, transpose_w=True).compile(), 1)
     _assert_kernel(gm.tgmm.lower(x, dy, tiles, used, groups=groups).compile(), 1)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)],
+                         ids=["up-2048x1536", "down-1536x2048"])
+def test_grouped_products_compile_at_lfm2_widths(one_chip, k, n):
+    """Over the worst-case buffer of 2 x 4096 tokens x 4 experts."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    _compile_grouped_products(one_chip, k, n, 2 * 4096 * 4 + 8 * gm.ROW_TILE, 8)
 
 
 ROW_MOVES = ["pack", "gather", "weighted-rows", "add-back", "combine", "pair-dots"]
@@ -373,17 +377,22 @@ def test_every_candidate_compiles_at_two_head_sizes(one_chip, cand):
 @pytest.mark.parametrize("k,n", [(2304, 1024), (1024, 2304)],
                          ids=["up-2304x1024", "down-1024x2304"])
 def test_grouped_products_compile_at_kimi_linear_widths(one_chip, k, n):
-    import jax.numpy as jnp
     from paddle_tpu.ops.pallas import grouped_matmul as gm
-    rows, groups = 2 * 4096 * 8 + 8 * gm.ROW_TILE, 8
-    x = _sds((rows, k), jnp.bfloat16, one_chip)
-    dy = _sds((rows, n), jnp.bfloat16, one_chip)
-    w = _sds((groups, k, n), jnp.bfloat16, one_chip)
-    tiles = _sds((rows // gm.ROW_TILE,), jnp.int32, one_chip)
-    used = _sds((), jnp.int32, one_chip)
-    _assert_kernel(gm.gmm.lower(x, w, tiles, used).compile(), 1)
-    _assert_kernel(gm.gmm.lower(dy, w, tiles, used, transpose_w=True).compile(), 1)
-    _assert_kernel(gm.tgmm.lower(x, dy, tiles, used, groups=groups).compile(), 1)
+    _compile_grouped_products(one_chip, k, n, 2 * 4096 * 8 + 8 * gm.ROW_TILE, 8)
+
+
+@pytest.mark.parametrize("k,n,pairs,groups", [
+    (2048, 1408, 8192 * 6, 8), (1408, 2048, 8192 * 6, 8), (2048, 768, 8192 * 8, 16)],
+    ids=["deepseek-up-2048x1408", "deepseek-down-1408x2048", "keye-up-2048x768"])
+def test_grouped_products_compile_at_deepseek_v2_lite_widths(one_chip, k, n, pairs, groups):
+    """The DeepSeek-V2-Lite cell's expert width, 1408 = 11 x 128, and the Keye
+    cell's 768, over a rank's rows by stand-ins: the whole output width is one
+    block there (the row operand crosses HBM once), which interpret mode
+    cannot hold against VMEM; Mosaic can."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    for reckon in (gm.gmm_vmem_bytes, gm.tgmm_vmem_bytes):
+        assert gm._col_tile(reckon, gm.ROW_TILE, k, n, 2) == n
+    _compile_grouped_products(one_chip, k, n, pairs + groups * gm.ROW_TILE, groups)
 
 
 @pytest.mark.parametrize("move", ROW_MOVES)
