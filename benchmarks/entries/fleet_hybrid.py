@@ -6,8 +6,8 @@ built with `tensor_parallel=True` (column- and row-parallel projections, a
 vocabulary-parallel embedding), wrapped by `fleet.distributed_model` and
 `fleet.distributed_optimizer`, and each batch is sharded over `data`. The
 seeded weights are made whole on the first chip and sharded by
-`distributed_model`, so the plain reference, which runs unsharded on that
-chip before any of this exists, starts from the same values.
+`distributed_model`, so the plain reference, which runs once all of this
+is gone, starts from the same values.
 """
 from benchmarks import program
 from benchmarks.entries import to_static_loop

@@ -130,7 +130,7 @@ def run(ctx):
                 "program": self.rounds[-1], "rounds": self.rounds,
                 "losses": self.losses[first:],
                 "compiles_in_window": ctx["events"].requests - self.requests,
-                "setup_s": t0 - ctx["t_process"] - ctx["reference_s"],
+                "setup_s": t0 - ctx["t_process"],
                 "eager_pass_s": call_s[0],
                 "compile_s": call_s[1] + call_s[2] - 2 * steady,
                 "window_s": now - t0, "steps": n,

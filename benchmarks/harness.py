@@ -153,8 +153,8 @@ def spread_over(devices):
     """A placement for the reference's float32 leaves: whole on one device;
     over several, every matrix split along its first axis that divides. The
     arithmetic is jax.numpy's either way; only where the numbers live
-    changes, so that on a four-chip cell the reference's peak stays under
-    the program's on every chip."""
+    changes, so that on a four-chip cell the reference needs a chip's share
+    of the room and not all of it on one."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
     if len(devices) == 1:
@@ -288,6 +288,27 @@ def compare(prog, ref, limits):
         row["ok"] = bool(np.isfinite(row["value"])
                          and row["value"] <= row["limit"])
     return rows
+
+
+# ---------------------------------------------------------------------------
+# memory
+
+def memory_reading(devices, key):
+    """The largest `memory_stats()[key]` over `devices`; 0 where the backend
+    keeps no such statistic (the CPU)."""
+    return max((d.memory_stats() or {}).get(key, 0) for d in devices)
+
+
+def held(devices):
+    """What is held now: `bytes_in_use` on the fullest of `devices` by its
+    allocator or, where the backend keeps no statistic (the CPU), the bytes
+    of the arrays alive; and how many arrays are alive (`live_arrays`)."""
+    import jax
+    live = jax.live_arrays()
+    stats = [d.memory_stats() for d in devices]
+    in_use = max(s["bytes_in_use"] for s in stats) if all(stats) \
+        else sum(a.nbytes for a in live)
+    return {"bytes_in_use": in_use, "live_arrays": len(live)}
 
 
 # ---------------------------------------------------------------------------

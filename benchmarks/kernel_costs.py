@@ -3,9 +3,10 @@ from the rows a run's counters say were present, and the least time the chip
 could take for them (the larger of operations over peak FLOP/s and bytes
 over peak bytes/s). benchmarks/flops.py counts what a training step
 requires; this counts what a kernel is asked to do each time it runs, so a
-rematerialised forward counts again. Kept conservative: padding rows that a
-kernel computes are not counted, and every operand is counted once however
-often a kernel re-reads it, so a share of the roofline can only be understated.
+rematerialised forward counts again, as often as the traced program runs it
+(`forward_passes`). Kept conservative: padding rows that a kernel computes
+are not counted, and every operand is counted once however often a kernel
+re-reads it, so a share of the roofline can only be understated.
 """
 
 
@@ -14,6 +15,20 @@ def roofline_seconds(flops, nbytes, peak):
     compute = flops / peak["bf16_flops_per_s"]
     memory = nbytes / peak["hbm_bytes_per_s"]
     return (compute, "compute") if compute >= memory else (memory, "memory")
+
+
+def forward_passes(kernels_a_layer, backward_kernels, kernels_a_pass=1, otherwise=1):
+    """Forward passes of a kernel scope in one training step, as the traced
+    program ran them: a layer's custom calls a step (program_trace.
+    kernels_a_layer) less its `backward_kernels`, `kernels_a_pass` to a
+    forward pass, where that comes to one pass or two; `otherwise` where the
+    trace does not say (for a model whose blocks are rematerialised whole, 2
+    with `recompute` and 1 without)."""
+    if kernels_a_layer is not None:
+        passes = (kernels_a_layer - backward_kernels) / kernels_a_pass
+        if passes in (1.0, 2.0):
+            return int(passes)
+    return otherwise
 
 
 def grouped_product(rows, k, n, groups, itemsize=2):

@@ -6,10 +6,10 @@ min(t + 1, topk), never the dense causal triangle; the index's products at
 every causal pair (a row's threshold needs them all); the index loss's
 second pass over the main scores once a pass. An implementation that
 multiplies the whole triangle and masks therefore reads a low share, and no
-share can pass 100. As kernel_costs.py: a rematerialised forward counts
-again; operands cross HBM once a pass.
+share can pass 100. As kernel_costs.py: a forward pass counts as often as
+the traced program runs it; operands cross HBM once a pass.
 """
-from benchmarks import kernel_costs, lfm2_readings, program_trace
+from benchmarks import kernel_costs, program, program_trace
 
 
 def causal_pairs(seq):
@@ -86,26 +86,28 @@ def index_seconds(batch, seq, hidden, heads, d, index_heads, index_d, topk,
     return forward_passes * forward + backward
 
 
-def cell_shares(cell, scope_ms, peak):
+def cell_shares(cell, scope_ms, peak, flash_passes=1, index_passes=1):
     """{metric: percent} of a traced run of a `keye_vl2` cell from its device
-    milliseconds a step by scope. A scope the trace lacks gives no entry."""
+    milliseconds a step by scope (program_trace.reduce's `scope_ms`) and the
+    forward passes the traced program ran. A scope the trace lacks gives no
+    entry. One pass where the trace does not say: the sparse-attention core
+    stands outside the rematerialised regions (PR 43)."""
     cfg, job = cell["cfg"], cell["job"]
-    sa, passes = cfg["sa_config"], 2 if cfg["recompute"] else 1
+    sa, layers = cfg["sa_config"], cfg["num_layers"]
     heads, d = cfg["num_attention_heads"], cfg["head_dim"]
-    layers = cfg["num_layers"]
     spent_index = (scope_ms.get("dsa_index") or 0.0) + (scope_ms.get("dsa_index_loss") or 0.0)
     least = {
         "dsa_flash_roofline_pct": (
             scope_ms.get("flash_attention"),
             layers * set_attention_seconds(
                 job["batch"], job["seq"], heads, cfg["num_key_value_heads"], d,
-                sa["topk"], passes, peak)),
+                sa["topk"], flash_passes, peak)),
         "dsa_index_roofline_pct": (
             spent_index,
             layers * index_seconds(
                 job["batch"], job["seq"], cfg["hidden_size"], heads, d,
                 sa["indexer_num_heads"], sa["indexer_head_dim"], sa["topk"],
-                passes, peak)),
+                index_passes, peak)),
     }
     return {name: 100.0 * seconds * 1e3 / spent
             for name, (spent, seconds) in least.items() if spent}
@@ -114,12 +116,20 @@ def cell_shares(cell, scope_ms, peak):
 def read_share(m, metric):
     """For a reader: `metric` of `cell_shares` for the traced run behind `m`;
     None for an untraced run or a trace without the metric's scope (a parent
-    of the PR that added it)."""
+    of the PR that added it). The passes are the trace's: a layer's flash pair
+    is one backward kernel and a forward kernel a pass; the index is a sets
+    kernel and a loss kernel a pass, its backward inside them."""
     reduced = program_trace.of(m)
     if reduced is None or not reduced["scope_ms"]:
         return None
-    return cell_shares(lfm2_readings.cell_of_the_run(), reduced["scope_ms"],
-                       m["peak"]).get(metric)
+    layers = m["cell"]["cfg"]["num_layers"]
+    return cell_shares(
+        m["cell"], reduced["scope_ms"], m["peak"],
+        kernel_costs.forward_passes(
+            program_trace.kernels_a_layer(m, ("flash_attention",), layers), 1),
+        kernel_costs.forward_passes(
+            program_trace.kernels_a_layer(m, ("dsa_index", "dsa_index_loss"), layers),
+            0, kernels_a_pass=2)).get(metric)
 
 
 def selected_pairs_per_step(m):
@@ -127,9 +137,9 @@ def selected_pairs_per_step(m):
     the device counter `dsa.selected_pairs_total` over the steps run since
     the model was built (`dsa.calls_total` over the layers). None where the
     program has no such counter."""
-    counters = (lfm2_readings.registry() or {}).get("counters", {})
+    counters = (program.registry() or {}).get("counters", {})
     pairs, calls = counters.get("dsa.selected_pairs_total"), counters.get("dsa.calls_total")
     if pairs is None or not calls:
         return None
-    layers = lfm2_readings.cell_of_the_run()["cfg"]["num_layers"]
+    layers = m["cell"]["cfg"]["num_layers"]
     return pairs * layers / calls

@@ -5,7 +5,7 @@ do each time it runs, so a rematerialised forward counts again; kept
 conservative (a triangular product counts its triangle, operands cross HBM
 once a pass), so a share of the roofline can only be understated.
 """
-from benchmarks import kernel_costs, lfm2_readings, program_trace
+from benchmarks import kernel_costs, program_trace
 
 KDA_CHUNK = 64      # paddle_tpu/ops/kda.py CHUNK, SUB
 KDA_SUB = 16
@@ -109,5 +109,4 @@ def read_share(m, metric):
     reduced = program_trace.of(m)
     if reduced is None or not reduced["scope_ms"]:
         return None
-    return cell_shares(lfm2_readings.cell_of_the_run(), reduced["scope_ms"],
-                       m["peak"]).get(metric)
+    return cell_shares(m["cell"], reduced["scope_ms"], m["peak"]).get(metric)

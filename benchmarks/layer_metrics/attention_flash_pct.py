@@ -1,8 +1,9 @@
 """The attention path the traced window took: the share of `attention_ms.train`
 under scope `flash_attention` (100: the Pallas kernel, 0: XLA's softmax
-attention, `sdpa`). The fusion policy decides per checkout and shape
-(paddle_tpu/ops/attention.py); a run whose neighbour took the other path
-differs by that and not by the change under test."""
+attention, `sdpa`). Which path a call takes is a rule of shapes, mask,
+dropout and platform (paddle_tpu/ops/attention.py::takes_flash, PR 30), the
+same in every checkout: a cell reads 100 or 0, and a change of the rule
+shows here before it shows in `attention_ms.train`."""
 from benchmarks import program_trace
 
 

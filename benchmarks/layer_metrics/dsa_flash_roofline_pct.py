@@ -1,6 +1,7 @@
 """Attention over the index's sets against its roofline: FlashAttention-2's
 product count at the pairs of the sets, sum over t of min(t + 1, topk), never
-the dense triangle (benchmarks/kernel_costs_keye.py), over the
+the dense triangle, the forward as often as the traced program runs it
+(benchmarks/kernel_costs_keye.py), over the
 `flash_attention` scope's device time, the copies XLA makes round the kernels
 and the set's tiling included. A pair that multiplies every causal tile and
 masks reads low. None where the trace has no such scope."""

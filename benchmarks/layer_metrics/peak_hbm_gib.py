@@ -1,4 +1,6 @@
-"""`peak_bytes_in_use` after the window, on the fullest chip."""
+"""`peak_bytes_in_use` on the fullest chip when the entry has returned and
+before the plain reference runs: the program's, whatever the reference
+needs afterwards (benchmarks/run.py)."""
 
 
 def read(m):

@@ -1,7 +1,9 @@
 """The program under test, as the entries build and read it: model and
 optimizer from a configuration, the seeded weights put in their place (and
 put back, once the step is compiled), and the two readings of its state
-that `correct` compares. The only benchmark module besides the entries that
+that `correct` compares, its registry of counters, and what gives the chips
+back when a run is over. Beside the entries and the readers of the program's
+own timeline (program_trace.py, setup_trace.py), the benchmark module that
 imports paddle_tpu."""
 from benchmarks import harness
 
@@ -111,3 +113,32 @@ def update_norms(ctx, model, opt, make_weights):
     read from the float32 masters where the optimizer keeps them."""
     return harness.diff_norms(
         _state_leaves(ctx, model, opt, "master_weight"), make_weights())
+
+
+def registry():
+    """{"counters": ..., "gauges": ...} of the program's registry, or None
+    where the program keeps none: reading it is what fetches the device
+    counters, from the layers that are alive."""
+    try:
+        from paddle_tpu.profiler import metrics
+        return metrics.get_registry().snapshot()
+    except Exception:
+        return None
+
+
+def release():
+    """Give the chips back once the entry has returned and let go of its
+    model, optimizer and step. What still holds them is the program's cache
+    of converted functions (jit/ast_transform.py keeps every function
+    `to_static` has rewritten, and the rewritten step's namespace holds the
+    closure it was made in: model and optimizer, so every parameter, master
+    and moment); then jax's own caches, whose executables keep their
+    constants and their code on the device. run.py measures what is left and
+    refuses to go on beside it, so a tree that keeps its state elsewhere
+    stops with a message and is not read wrongly."""
+    import gc
+    import jax
+    from paddle_tpu.jit import ast_transform
+    getattr(ast_transform, "_CACHE", {}).clear()
+    gc.collect()
+    jax.clear_caches()

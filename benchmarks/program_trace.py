@@ -25,7 +25,7 @@ scope costs; the time inside one fusion cannot be split.
 
 `load` reads the trace into plain lists, `reduce` is arithmetic on them
 (tests/benchmark/test_program_trace.py), `of(measured)` does both for the
-run's newest trace, once, and prints the `program_trace` phase line. A
+run's own trace, once, and prints the `program_trace` phase line. A
 program without these spans (the parent of the PR that added them) reduces
 to None, and every reader built on this returns None for it. A window
 whose program the trace does not carry, or carries with no scoped instruction
@@ -46,7 +46,7 @@ import sys
 if __name__ == "__main__":
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks import harness, trace_reduce
+from benchmarks import trace_reduce
 
 PROGRAM_SPANS = ("to_static.", "step/", "metrics.")
 CALL, LAUNCH = "to_static.call", "to_static.launch"
@@ -270,7 +270,10 @@ def device_by_scope(ops, modules, programs):
     """One device: the window trace_reduce takes (first to last execution of
     the longest-running program), its steps and busy time, and that busy time
     split by the scopes `programs` has for that program, None for operations
-    that carry none. `by_scope`, `held` and `unscoped_ops` are None where
+    that carry none; `kernels` counts the window's custom calls (the Pallas
+    kernels: an event is named by its instruction's text, `%name = type
+    custom-call(operands)`) by scope. `by_scope`, `held`, `unscoped_ops` and
+    `kernels` are None where
     `programs` lacks that program or has no scope on any of its instructions:
     what is read then is not what this program's code staged."""
     reduced = trace_reduce.reduce_device(ops, modules)
@@ -285,14 +288,14 @@ def device_by_scope(ops, modules, programs):
     out = {"steps": reduced["steps"], "busy_ns": reduced["busy_ns"], "program": program,
            "step_interval_ns": statistics.median(
                b - a for a, b in zip(starts, starts[1:])) if len(starts) > 1 else None,
-           "by_scope": None, "held": None, "unscoped_ops": None,
+           "by_scope": None, "held": None, "unscoped_ops": None, "kernels": None,
            "gaps": reduced["gaps"], "idle": 1.0 - reduced["busy_ns"] / (hi - lo)}
     scopes = programs.get(program, {})
     if not any(scope is not None for scope, *_ in scopes.values()):
         return out
     inside = [(n, s, d) for n, s, d in ops if s < hi and s + d > lo]
     shares = exclusive_ns(trace_reduce.clip(inside, lo, hi))
-    by_scope, held, unscoped = (collections.defaultdict(int) for _ in range(3))
+    by_scope, held, unscoped, kernels = (collections.defaultdict(int) for _ in range(4))
     for (name, _, _), ns in zip(inside, shares):
         scope, *others = scopes.get(instruction_of(name), (None,))
         by_scope[scope] += ns
@@ -300,7 +303,10 @@ def device_by_scope(ops, modules, programs):
             held[holder] += ns
         if scope is None:
             unscoped[name] += ns
-    return dict(out, by_scope=dict(by_scope), held=dict(held), unscoped_ops=dict(unscoped))
+        elif " custom-call(" in name:
+            kernels[scope] += 1
+    return dict(out, by_scope=dict(by_scope), held=dict(held), unscoped_ops=dict(unscoped),
+                kernels=dict(kernels))
 
 
 def calls_with_launch(spans):
@@ -327,8 +333,9 @@ def label_gap(gap, spans):
 
 def reduce(trace, expected=None):
     """Per step and in milliseconds: device busy time split by scope
-    (`scope_ms`, sums to `busy_ms`) and the time of the operations that hold
-    each scope (`held_ms`), both None where the trace has no scopes for the
+    (`scope_ms`, sums to `busy_ms`), the time of the operations that hold
+    each scope (`held_ms`) and the custom calls a step by scope
+    (`scope_kernels`), all None where the trace has no scopes for the
     window's program; the launch and the Python round it (medians over
     the window's calls, beside the benchmark's own dispatch span over the
     same calls); counters per call from the spans' attributes; the ten
@@ -353,8 +360,10 @@ def reduce(trace, expected=None):
     per_step_ms = 1e-6 / n / max(steps, 1)
     busy_ms = sum(d["busy_ns"] for d in devices) * per_step_ms
     scoped = all(d["by_scope"] is not None for d in devices)
-    by_scope, held, unscoped = (collections.defaultdict(float) for _ in range(3))
+    by_scope, held, unscoped, kernels = (collections.defaultdict(float) for _ in range(4))
     for d in devices if scoped else ():
+        for scope, count in d["kernels"].items():
+            kernels[scope] += count / n / max(steps, 1)
         for scope, ns in d["by_scope"].items():
             by_scope[UNSCOPED if scope is None else scope] += ns * per_step_ms
         for scope, ns in d["held"].items():
@@ -380,6 +389,9 @@ def reduce(trace, expected=None):
         "scoped_program": worst["program"] if scoped else None,
         "scope_ms": dict(sorted(by_scope.items(), key=lambda kv: -kv[1])) if scoped else None,
         "held_ms": dict(sorted(held.items(), key=lambda kv: -kv[1])) if scoped else None,
+        # custom calls (Pallas kernels) a step by scope: how often the traced
+        # program runs a kernel, a rematerialised forward among them
+        "scope_kernels": dict(kernels) if scoped else None,
         "unscoped_pct": 100.0 * by_scope.get(UNSCOPED, 0.0) / busy_ms if scoped else None,
         "unscoped_ops": sorted(unscoped.items(), key=lambda kv: -kv[1])[:8],
         "launch_ms": statistics.median(l[2] for _, l in whole) / 1e6 if whole else None,
@@ -404,13 +416,12 @@ def scope_ms(m, names, key="scope_ms"):
 # ---------------------------------------------------------------------------
 # the run's own trace
 
-def newest_trace():
-    """The newest trace under `.bench_trace/`, whichever cell's: what a
-    reader is handed does not name the directory its run wrote, and while the
-    run's process lives its own trace is the newest. `of` refuses one whose
-    steps and window are not the run's, so another's is never read."""
-    paths = glob.glob(os.path.join(harness.ROOT, ".bench_trace", "*", "plugins",
-                                   "profile", "*", "*.xplane.pb"))
+def newest_trace(m):
+    """The trace the run behind `m` wrote: the newest `.xplane.pb` under its
+    own `trace_dir` (`.bench_trace/<cell>/`, emptied when the run starts its
+    trace), or None."""
+    paths = glob.glob(os.path.join(m["trace_dir"], "plugins", "profile", "*",
+                                   "*.xplane.pb"))
     return max(paths, key=os.path.getmtime) if paths else None
 
 
@@ -420,13 +431,24 @@ def of(m):
     trace, or a program without the spans."""
     if "program_trace" not in m:
         m["program_trace"] = None
-        path = newest_trace()
-        if m["run"]["trace"] and path:
+        path = newest_trace(m) if m["run"]["trace"] else None
+        if path:
             reduced = reduce(load(path), expected=m["run"]["trace"])
             if reduced:
                 print(json.dumps({"phase": "program_trace", **reduced}), flush=True)
             m["program_trace"] = reduced
     return m["program_trace"]
+
+
+def kernels_a_layer(m, names, layers):
+    """For a reader: the custom calls a step under the scopes `names` in the
+    traced run behind `m`, a layer of `layers`; None where `of(m)` is, or has
+    no scopes, or the program stages no kernel there."""
+    reduced = of(m)
+    if reduced is None or not reduced.get("scope_kernels") or not layers:
+        return None
+    calls = sum(reduced["scope_kernels"].get(name, 0.0) for name in names)
+    return calls / layers if calls else None
 
 
 def counter(name):

@@ -64,46 +64,17 @@ def test_the_flash_pairs_roofline_counts_the_passes_the_program_runs():
     assert costs.flash_seconds(cfg, job, 2, PEAK) == pytest.approx(5 * (2 * fwd + bwd))
     assert 5 * (2 * fwd + bwd) == pytest.approx(40.1e-3, rel=2e-3)      # compute-bound
     # what the trace says: a layer's kernels less its one backward kernel
-    assert costs.flash_forward_passes(cfg, 3.0) == 2
-    assert costs.flash_forward_passes(cfg, 2.0) == 1
-    # where it does not say, what `recompute` means for this model: whole blocks
-    assert costs.flash_forward_passes(cfg) == 2
-    assert costs.flash_forward_passes(cfg, 2.4) == 2
-    assert costs.flash_forward_passes(dict(cfg, recompute=False)) == 1
-
-
-def test_kernels_a_step_from_a_loaded_trace():
-    """Two steps of a program whose five layers run a forward, a rerun
-    forward and a backward kernel under `flash_attention`; other custom
-    calls, other scopes and the warm-up program are not counted."""
-    scopes = {f"custom-call.{i}": ("flash_attention",) for i in range(15)}
-    scopes.update({"custom-call.90": ("moe_experts",), "fusion.1": ("flash_attention",)})
-    ops = []
-    for step in (0, 1):
-        at = 1000 + step * 500
-        ops += [(f"%custom-call.{i} = bf16[8]{{0}} custom-call(%p)", at + i, 1)
-                for i in range(15)]
-        ops += [("%custom-call.90 = bf16[8]{0} custom-call(%p)", at + 20, 1),
-                ("%fusion.1 = bf16[8]{0} fusion(%p)", at + 21, 1)]
-    trace = {"devices": {"/device:TPU:0": {
-        "modules": [("jit_pure_fn(1)", 1000, 400), ("jit_pure_fn(1)", 1500, 400),
-                    ("jit_warm(2)", 10, 5)],
-        "ops": ops + [("%custom-call.0 = bf16[8]{0} custom-call(%p)", 12, 1)]}},
-        "programs": {"jit_pure_fn(1)": scopes}}
-    assert costs.kernels_a_step(trace) == 15.0
-    assert costs.flash_forward_passes(CELL["cfg"], 15.0 / 5) == 2
-    assert costs.kernels_a_step(dict(trace, programs={})) == 0.0
-    assert costs.kernels_a_step({"devices": {}, "programs": {}}) is None
+    # (kernel_costs.forward_passes; tests/benchmark/test_lfm2_costs.py)
 
 
 def test_the_readers_with_nothing_to_read(monkeypatch):
-    from benchmarks import lfm2_readings
+    from benchmarks import program
     # an untraced run, and a program without the gauge (a parent of this PR)
     untraced = {"run": {"trace": None}, "peak": PEAK}
-    monkeypatch.setattr(lfm2_readings, "registry", lambda: {"counters": {}, "gauges": {}})
+    monkeypatch.setattr(program, "registry", lambda: {"counters": {}, "gauges": {}})
     for name in READERS:
         assert harness.load_reader("layer_metrics", name)(dict(untraced)) is None
-    monkeypatch.setattr(lfm2_readings, "registry", lambda: None)
+    monkeypatch.setattr(program, "registry", lambda: None)
     assert harness.load_reader("layer_metrics", "moe_router_max_over_mean")({}) is None
     # a traced run whose program stages neither scope
     traced = {"run": {"trace": {"steps": 1}}, "peak": PEAK,
@@ -112,21 +83,23 @@ def test_the_readers_with_nothing_to_read(monkeypatch):
         assert harness.load_reader("layer_metrics", name)(dict(traced)) is None
 
 
-def test_the_readers_with_something_to_read(monkeypatch):
-    from benchmarks import lfm2_readings, program_trace
+@pytest.mark.parametrize("kernels, passes", [
+    ({"flash_attention": 15.0}, 2),           # forward, rerun forward, backward: five layers
+    ({"flash_attention": 10.0}, 1),           # a tree that keeps the pair's operands
+    ({}, 2),                                  # a trace that does not say: `recompute`
+])
+def test_the_readers_with_something_to_read(kernels, passes, monkeypatch):
+    from benchmarks import program
     cell = dict(CELL, cfg=dict(CELL["cfg"], first_layer=0, num_layers=5))
-    monkeypatch.setattr(lfm2_readings, "cell_of_the_run", lambda: cell)
-    monkeypatch.setattr(program_trace, "newest_trace", lambda: "a path")
-    monkeypatch.setattr(program_trace, "load", lambda path: {"devices": {}, "programs": {}})
-    monkeypatch.setattr(lfm2_readings, "registry", lambda: {
+    monkeypatch.setattr(program, "registry", lambda: {
         "counters": {}, "gauges": {"moe.router_max_over_mean_ratio": 1.75}})
-    traced = {"run": {"trace": {"steps": 1}}, "peak": PEAK, "program_trace": {
+    traced = {"run": {"trace": {"steps": 1}}, "peak": PEAK, "cell": cell, "program_trace": {
         "scope_ms": {"flash_attention": 87.0, "mla_rope": 6.5, "moe_balance_loss": 1.25},
-        "held_ms": {}}}
+        "held_ms": {}, "scope_kernels": kernels}}
     read = {name: harness.load_reader("layer_metrics", name)(dict(traced))
             for name in READERS}
     assert read["mla_rope_ms.train"] == 6.5 and read["moe_balance_loss_ms.train"] == 1.25
     assert read["moe_router_max_over_mean"] == 1.75
     assert read["mla_rope_flash_roofline_pct"] == pytest.approx(
-        100 * costs.flash_seconds(cell["cfg"], cell["job"], 2, PEAK) * 1e3 / 87.0)
+        100 * costs.flash_seconds(cell["cfg"], cell["job"], passes, PEAK) * 1e3 / 87.0)
     assert 0 < read["mla_rope_flash_roofline_pct"] < 100

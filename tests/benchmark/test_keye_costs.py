@@ -94,15 +94,48 @@ def test_the_rooflines_count_every_pass():
         pytest.approx(2 * (fwd + bwd))
 
 
-def test_cell_shares_and_readers_with_nothing_to_read():
-    shares = costs.cell_shares(
-        CELL, {"flash_attention": 100.0, "dsa_index": 60.0, "dsa_index_loss": 40.0}, PEAK)
+@pytest.mark.parametrize("kernels, flash_passes, index_passes", [
+    # since PR 43 the core stands outside the rematerialised regions: a layer
+    # runs one forward and one backward flash kernel, one sets and one loss kernel
+    ({"flash_attention": 8.0, "dsa_index": 4.0, "dsa_index_loss": 4.0}, 1, 1),
+    # before it, the whole block was rerun: two forwards, the sets twice, the
+    # loss with its gradient and alone
+    ({"flash_attention": 12.0, "dsa_index": 8.0, "dsa_index_loss": 8.0}, 2, 2),
+    # a trace that does not say: one pass, whatever `recompute` is
+    ({}, 1, 1), ({"flash_attention": 9.0, "dsa_index": 5.0}, 1, 1),
+])
+def test_the_shares_count_the_passes_the_trace_holds(kernels, flash_passes, index_passes):
+    assert CELL["cfg"]["recompute"]
+    m = {"run": {"trace": {"steps": 20}}, "peak": PEAK, "cell": CELL, "program_trace": {
+        "scope_ms": {"flash_attention": 100.0, "dsa_index": 60.0, "dsa_index_loss": 40.0},
+        "held_ms": {}, "scope_kernels": kernels}}
+    shares = {name: costs.read_share(m, name)
+              for name in ("dsa_flash_roofline_pct", "dsa_index_roofline_pct")}
+    assert shares == costs.cell_shares(CELL, m["program_trace"]["scope_ms"], PEAK,
+                                       flash_passes, index_passes)
     assert shares["dsa_flash_roofline_pct"] == pytest.approx(
-        100 * 4 * costs.set_attention_seconds(1, 8192, 32, 4, 128, 2048, 2, PEAK) * 1e3 / 100.0)
+        100 * 4 * costs.set_attention_seconds(1, 8192, 32, 4, 128, 2048, flash_passes, PEAK)
+        * 1e3 / 100.0)
     assert shares["dsa_index_roofline_pct"] == pytest.approx(
-        100 * 4 * costs.index_seconds(1, 8192, 2048, 32, 128, 16, 64, 2048, 2, PEAK)
+        100 * 4 * costs.index_seconds(1, 8192, 2048, 32, 128, 16, 64, 2048, index_passes, PEAK)
         * 1e3 / 100.0)
     assert all(0 < v < 100 for v in shares.values())
+
+
+def test_the_shares_at_the_cells_own_readings():
+    # my chip run, PR 45 (ledger): 82.55 and 50.99 ms a step, where two
+    # forward passes read 26.6 and 26.4; the trace holds one
+    scope_ms = {"flash_attention": 82.54526795, "dsa_index": 13.5742894,
+                "dsa_index_loss": 37.4204689}
+    assert costs.cell_shares(CELL, scope_ms, PEAK, 2, 2)["dsa_flash_roofline_pct"] == \
+        pytest.approx(26.62516320036734)
+    shares = costs.cell_shares(CELL, scope_ms, PEAK)
+    assert shares["dsa_flash_roofline_pct"] == pytest.approx(26.62516320036734 * 7 / 9)
+    assert shares["dsa_flash_roofline_pct"] == pytest.approx(20.7, abs=0.05)
+    assert shares["dsa_index_roofline_pct"] == pytest.approx(17.42, abs=0.01)
+
+
+def test_cell_shares_and_readers_with_nothing_to_read():
     # a trace without the scopes (a parent of the PR that added them): no entry
     assert costs.cell_shares(CELL, {"linear": 60.0}, PEAK) == {}
     untraced = {"run": {"trace": None}, "peak": PEAK}
@@ -111,13 +144,12 @@ def test_cell_shares_and_readers_with_nothing_to_read():
 
 
 def test_the_counter_reader(monkeypatch):
-    from benchmarks import lfm2_readings
+    from benchmarks import program
     read = harness.load_reader("layer_metrics", "dsa_selected_pairs_per_step")
-    monkeypatch.setattr(lfm2_readings, "cell_of_the_run", lambda: CELL)
-    monkeypatch.setattr(lfm2_readings, "registry", lambda: {"counters": {
+    monkeypatch.setattr(program, "registry", lambda: {"counters": {
         "dsa.selected_pairs_total": 10 * 4 * 14681088.0, "dsa.calls_total": 40.0}})
-    assert read({}) == pytest.approx(4 * 14681088.0)        # ten steps of four layers
-    monkeypatch.setattr(lfm2_readings, "registry", lambda: {"counters": {}})
-    assert read({}) is None                                 # a program without the counter
-    monkeypatch.setattr(lfm2_readings, "registry", lambda: None)
-    assert read({}) is None
+    assert read({"cell": CELL}) == pytest.approx(4 * 14681088.0)   # ten steps of four layers
+    monkeypatch.setattr(program, "registry", lambda: {"counters": {}})
+    assert read({"cell": CELL}) is None                     # a program without the counter
+    monkeypatch.setattr(program, "registry", lambda: None)
+    assert read({"cell": CELL}) is None

@@ -115,3 +115,19 @@ def test_cell_shares_and_readers_with_nothing_to_read():
     untraced = {"run": {"trace": None}, "peak": PEAK}
     for name in ("kda_ms.train", "kda_roofline_pct", "mla_flash_roofline_pct"):
         assert harness.load_reader("layer_metrics", name)(dict(untraced)) is None
+
+
+def test_the_readers_score_the_cell_the_run_hands_them():
+    # a traced run's `measured` as run.py makes it: the cell that ran is in it
+    # (PR 46), so the shares take its sizes and not a guess from a directory
+    # (my chip run, PR 45: the scopes' ms a step)
+    traced = {"run": {"trace": {"steps": 20}}, "peak": PEAK, "cell": CELL,
+              "program_trace": {"scope_ms": {"kda": 100.6, "flash_attention": 17.34},
+                                "held_ms": {}, "scope_kernels": {"flash_attention": 3.0}}}
+    read = {name: harness.load_reader("layer_metrics", name)(dict(traced))
+            for name in ("kda_ms.train", "kda_roofline_pct", "mla_flash_roofline_pct")}
+    assert read["kda_ms.train"] == 100.6
+    assert read == dict(costs.cell_shares(CELL, traced["program_trace"]["scope_ms"], PEAK),
+                        **{"kda_ms.train": 100.6})
+    assert read["kda_roofline_pct"] == pytest.approx(8.56, abs=0.05)
+    assert read["mla_flash_roofline_pct"] == pytest.approx(46.3, abs=0.3)

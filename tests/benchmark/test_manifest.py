@@ -105,6 +105,14 @@ def test_cell_files_exist_and_load(cell):
                for m in BENCH["per_layer"])
 
 
+# a key that `reduced` may never name: a hidden, intermediate, latent, state
+# or projection size, a head size, an expansion factor, experts a token. The
+# key whole: `num_hidden_layers` and `num_attention_heads` are counts
+WIDTH = re.compile(r".*(_dim|_rank|_width)|d_model|hidden_size|(\w+_)?intermediate_size"
+                   r"|(\w+_)?(state|head|latent|proj\w*)_size|\w*expan\w*"
+                   r"|num_experts_per_tok(en)?|top_?k")
+
+
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
 def test_config_entry(config):
     assert set(config) == {"name", "source", "file", "reduced", "why"}
@@ -115,7 +123,19 @@ def test_config_entry(config):
         cfg = json.load(f)
     for key in config["reduced"]:
         assert key in cfg
-        assert not re.search(r"(_dim|_rank|hidden|intermediate|head)", key)
+        assert not WIDTH.fullmatch(key), key
+
+
+@pytest.mark.parametrize("key, is_width", [
+    ("num_hidden_layers", False), ("num_layers", False), ("vocab_size", False),
+    ("num_experts", False), ("n_routed_experts", False), ("first_k_dense_replace", False),
+    ("num_attention_heads", False), ("dropout", False),
+    ("hidden_size", True), ("moe_intermediate_size", True), ("intermediate_size", True),
+    ("head_dim", True), ("qk_rope_head_dim", True), ("kv_lora_rank", True),
+    ("num_experts_per_tok", True), ("ssm_state_size", True), ("expand", True),
+])
+def test_a_reduced_key_is_matched_whole(key, is_width):
+    assert bool(WIDTH.fullmatch(key)) == is_width
 
 
 PARKED = harness.read_json("parked.json")

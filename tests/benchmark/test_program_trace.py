@@ -97,6 +97,41 @@ def test_reduce_gives_launch_self_time_counters_and_gap_labels():
                               ["step/compute", pytest.approx(20e-9)]]
 
 
+def test_custom_calls_a_step_by_scope():
+    """Two steps of a program whose five layers run a forward, a rerun
+    forward and a backward kernel under `flash_attention`: the custom calls
+    of the window, by scope, a step; a fusion, an unscoped custom call and the
+    warm-up program's are not counted."""
+    scopes = {f"custom-call.{i}": ("flash_attention",) for i in range(15)}
+    scopes.update({"custom-call.90": ("moe_experts",), "fusion.1": ("flash_attention",),
+                   "custom-call.91": (None,)})
+    ops = []
+    for step in (0, 1):
+        at = 1000 + step * 500
+        ops += [(f"%custom-call.{i} = bf16[8]{{0}} custom-call(%p)", at + i, 1)
+                for i in range(15)]
+        ops += [("%custom-call.90 = bf16[8]{0} custom-call(%p)", at + 20, 1),
+                ("%custom-call.91 = bf16[8]{0} custom-call(%p)", at + 22, 1),
+                ("%fusion.1 = bf16[8]{0} fusion(%p)", at + 21, 1)]
+    modules = [("jit_pure_fn(1)", 1000, 400), ("jit_pure_fn(1)", 1500, 400),
+               ("jit_warm(2)", 10, 5)]
+    ops += [("%custom-call.0 = bf16[8]{0} custom-call(%p)", 12, 1)]
+    d = pt.device_by_scope(ops, modules, {"jit_pure_fn(1)": scopes})
+    assert d["kernels"] == {"flash_attention": 30, "moe_experts": 2}
+    assert pt.device_by_scope(ops, modules, {})["kernels"] is None
+    trace = dict(hand_trace(), programs={"jit_pure_fn(1)": scopes},
+                 devices={"/device:TPU:0": {"modules": modules, "ops": ops}})
+    r = pt.reduce(trace)
+    assert r["scope_kernels"] == {"flash_attention": 15.0, "moe_experts": 1.0}
+    m = {"run": {"trace": {"steps": 2}}, "program_trace": r}
+    assert pt.kernels_a_layer(m, ("flash_attention",), 5) == 3.0
+    assert pt.kernels_a_layer(m, ("flash_attention", "moe_experts"), 4) == 4.0
+    assert pt.kernels_a_layer(m, ("linear",), 5) is None      # no kernel there
+    assert pt.kernels_a_layer({"run": {"trace": None}}, ("flash_attention",), 5) is None
+    assert pt.reduce(hand_trace())["scope_kernels"] == {}
+    assert pt.reduce(dict(hand_trace(), programs={}))["scope_kernels"] is None
+
+
 def test_the_innermost_span_labels_a_gap_that_several_cover():
     spans = [("step/compute", 0, 100, {}, "python"),
              ("to_static.call", 10, 50, {}, "python"),
@@ -156,7 +191,7 @@ def test_readers_return_nothing_for_an_untraced_run_and_for_a_missing_counter():
 
 def test_of_reduces_the_runs_trace_once_and_prints_the_phase_line(monkeypatch, capsys):
     loads = []
-    monkeypatch.setattr(pt, "newest_trace", lambda: "some.xplane.pb")
+    monkeypatch.setattr(pt, "newest_trace", lambda m: "some.xplane.pb")
     monkeypatch.setattr(pt, "load", lambda path: loads.append(path) or hand_trace())
     m = {"run": {"trace": tr.reduce(hand_trace())}}
     read = harness.load_reader("layer_metrics", "optimizer_ms.train")

@@ -73,16 +73,18 @@ def test_the_cell_as_the_manifest_has_it():
     bench = harness.manifest()
     cell = harness.load_cell(CELL)
     cfg, family = cell["cfg"], cell["family"]
+    # found by name: a later PR appends after these, so no position is pinned
     entry = next(c for c in bench["configs"] if c["name"] == "kimi-linear-48b-a3b")
-    assert entry == bench["configs"][-1]                      # appended
-    assert bench["workloads"][-1] == cell["cell"] and cell["cell"]["chips"] == 1
+    assert cell["cell"] in bench["workloads"] and cell["cell"]["chips"] == 1
     assert cell["cell"]["traffic"] == "pretrain-1chip-b2-s4096"
     assert entry["reduced"] == ["num_layers", "first_k_dense_replace",
                                 "num_experts", "vocab_size"]
     assert entry["source"].endswith("Kimi-Linear-48B-A3B-Instruct/blob/main/config.json")
-    assert [m["name"] for m in bench["per_layer"][-3:]] == [
+    mine = [m for m in bench["per_layer"]
+            if m["name"] in ("kda_ms.train", "kda_roofline_pct", "mla_flash_roofline_pct")]
+    assert [m["name"] for m in mine] == [
         "kda_ms.train", "kda_roofline_pct", "mla_flash_roofline_pct"]
-    assert all(m["workloads"] == [CELL] for m in bench["per_layer"][-3:])
+    assert all(m["workloads"] == [CELL] for m in mine)
     # the published keys, and the four that differ beside their published values
     assert cfg["published"] == {"num_hidden_layers": 27, "first_k_dense_replace": 1,
                                 "num_experts": 256, "vocab_size": 163840}
