@@ -201,17 +201,16 @@ def phase_train(paddle, seed, cache_events):
     check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
     check(losses[-1] < losses[0], f"loss did not fall: {losses}")
     # call 1 is the eager discovery pass (dygraph on the chip); call 2 traces
-    # and compiles the step; call 3 compiles its buffer-donating twin (state
-    # assigned by the eager pass may not be donated); from call 4 on the
-    # compiled step is steady
+    # and compiles the step's one program, the buffer-donating one (on a TPU
+    # it consumes what the eager pass assigned); from call 3 on the compiled
+    # step is steady
     emit("train", steps=len(losses), losses=[round(v, 4) for v in losses],
          first_loss=losses[0], last_loss=losses[-1],
          eager_discovery_seconds=seconds[0],
-         compile_seconds=seconds[1] + seconds[2] - 2 * min(seconds[3:]),
+         compile_seconds=seconds[1] - min(seconds[2:]),
          first_compiled_call_seconds=seconds[1],
-         donating_compile_call_seconds=seconds[2],
-         step_seconds=float(np.median(seconds[3:])),
-         step_seconds_min=min(seconds[3:]), step_seconds_max=max(seconds[3:]))
+         step_seconds=float(np.median(seconds[2:])),
+         step_seconds_min=min(seconds[2:]), step_seconds_max=max(seconds[2:]))
 
     # which attention the compiled step holds: ops/attention.takes_flash
     # chooses from the shapes (XLA's attention under FLASH_MIN_SEQ_K keys),

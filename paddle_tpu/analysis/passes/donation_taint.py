@@ -8,9 +8,13 @@ attributes of :class:`~paddle_tpu.core.tensor.Tensor`:
   this way can alias external state, and donating it corrupts that state
   silently (the exact memory-corruption class the compiled step's donation
   gate exists to prevent).
-- ``_donate_unsafe``  — the taint bit the donation gate reads. Clearing it
-  anywhere but a contracted write-back seam re-arms donation on a buffer
-  whose aliasing the seam never proved.
+- ``_donate_unsafe``  — the taint bit the donation gate reads
+  (``jit/to_static.py::_donation_gate``). While it is set the gate donates
+  the value as it stands only where it can see that the value is neither
+  on the CPU (where it may be a numpy buffer PJRT merely imported) nor
+  held by anything but its tensor, and donates a device copy otherwise.
+  Clearing it anywhere but a contracted write-back seam re-arms plain
+  donation on a buffer whose aliasing the seam never proved.
 - ``_degen_cache``    — the degenerate-dim cache (ops/_param_guard.py).
   Re-initializing a value without invalidating it serves stale geometry
   (the ADVICE r5 ``set_state_dict`` bug class).

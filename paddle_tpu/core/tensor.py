@@ -48,24 +48,27 @@ class Tensor:
         "_version",    # in-place mutation counter (tensor_version parity)
         "_degen_cache",  # fused-op degenerate-weight check memo
                          # (ops/fused_conv_bn.py, ops/fused_residual_ln.py)
-        "_donate_unsafe",  # True while _val may be host-imported (numpy-
-                           # backed): PJRT-CPU imports host buffers without
-                           # taking ownership, so DONATING such an array to a
-                           # compiled step corrupts memory (to_static.py
-                           # donation gate). Cleared by the compiled
-                           # write-back, whose arrays are XLA-owned outputs.
+        "_donate_unsafe",  # the donation taint: True from any write of _val
+                           # from outside a compiled launch until a launch
+                           # writes its own output back. The value may then
+                           # be a numpy buffer that PJRT's CPU client imported
+                           # without taking ownership (donating that corrupts
+                           # memory), or be held by something else as well (a
+                           # tensor built from this one, the caller's array:
+                           # donating deletes it under them). What the gate
+                           # does with it: jit/to_static.py::_donation_gate.
         "__weakref__",
     )
 
     def __init__(self, value, dtype=None, place=None, stop_gradient=True,
                  name=None):
-        host_imported = False
+        tainted = False
         if isinstance(value, Tensor):
-            host_imported = value._donate_unsafe
+            tainted = True   # two holders of one array from here on
             value = value._val
         dtype = convert_dtype(dtype)
         if not isinstance(value, jax.Array):
-            host_imported = True
+            tainted = True
             arr = np.asarray(value)
             if dtype is None and arr.dtype == np.float64:
                 dtype = get_default_dtype()
@@ -91,7 +94,7 @@ class Tensor:
         self.trainable = True
         self._hooks = None
         self._version = 0
-        self._donate_unsafe = host_imported
+        self._donate_unsafe = tainted
         if _TraceHooks.on_create is not None:
             _TraceHooks.on_create(self)
 
@@ -110,11 +113,12 @@ class Tensor:
         if _TraceHooks.on_write is not None:
             _TraceHooks.on_write(self, v)
         self._val = v
-        # conservative donation taint: an externally assigned array may be
-        # host-imported (set_state_dict restore, checkpoint load, setitem) —
-        # donating such a buffer to a compiled step corrupts memory on the
-        # PJRT CPU backend. The compiled fast path clears this when it writes
-        # back its own XLA-owned outputs (to_static.py _run).
+        # conservative donation taint: an assigned array may be host-imported
+        # (set_state_dict restore, checkpoint load, setitem) or still be held
+        # by whoever assigned it. The compiled fast path clears this when it
+        # writes back its own XLA-owned outputs (to_static.py _run); until
+        # then its gate donates the value only where it can see that neither
+        # holds, and a copy otherwise.
         self._donate_unsafe = True
 
     @property

@@ -28,6 +28,7 @@ semantics as the reference's to_static for non-tensor conditions).
 from __future__ import annotations
 
 import functools
+import sys
 import threading
 
 import jax
@@ -287,6 +288,68 @@ class pause_donation:
         return False
 
 
+# The donation gate. A state tensor is tainted (`_donate_unsafe`) from any
+# write of its value until a compiled launch writes its own output back
+# (core/tensor.py). The taint stands for two hazards, and a tainted value is
+# donated as it stands only where the gate can see that neither applies:
+#   - the value may be backed by host memory: PJRT's CPU client imports a
+#     numpy buffer without taking ownership, and donating that corrupts
+#     memory. On any other platform the value was copied to device memory
+#     when it became a jax.Array, so only a value on the CPU is held to this;
+#   - something else may hold the same jax.Array (a tensor built from this
+#     one, `a.set_value(b)`, a value the caller kept): donating deletes it
+#     under the other holder, on every platform. The reference count says
+#     whether there is one. (Another jax.Array over the same buffer, as
+#     `jax.device_put` to the array's own device returns, is not seen.)
+# Any other tainted value is copied on its device and the copy is donated, so
+# no platform needs a second, non-donating program for the purpose: that one
+# is traced and compiled only for `pause_donation`, the gradient path and
+# FLAGS_donate_state_buffers off. What the eager discovery pass wrote is
+# operations' results that nothing else holds: on a TPU the first compiled
+# launch donates them as they are, and the state is held once.
+
+def _platform_of(value):
+    """Platform of the devices that hold `value`: "cpu", "tpu", ..."""
+    return next(iter(value.devices())).platform
+
+
+class _Slot:
+    __slots__ = ("held",)
+
+
+# what sys.getrefcount reads where a tensor's slot alone holds the value (the
+# slot and the call's own argument), taken as _donatable takes it
+_probe = _Slot()
+_probe.held = object()
+_SOLE_HOLDER = sys.getrefcount(_probe.held)
+del _probe
+
+
+def _donatable(t):
+    """The value of state tensor `t` as a donating program may consume it."""
+    if (not getattr(t, "_donate_unsafe", True)
+            or isinstance(t._val, jax.core.Tracer)):   # a step inside a trace
+        return t._val
+    if (_platform_of(t._val) != "cpu"
+            and sys.getrefcount(t._val) <= _SOLE_HOLDER):
+        return t._val
+    _count("rehomed_leaves")
+    # a copy where the value is, placed and committed as the value is, and
+    # no program compiled for it
+    return jax.device_put(t._val, may_alias=False)
+
+
+def _donation_gate(state, has_donating_twin):
+    """(donate, operands) for one launch over the state tensors `state`:
+    whether to run the donating executable, and the values to give it. The
+    one gate of `_run` and of `run_steps`' scan."""
+    if not has_donating_twin or _donation_paused[0]:
+        return False, tuple(t._val for t in state)
+    if any(getattr(t, "_donate_unsafe", True) for t in state):
+        return True, tuple(_donatable(t) for t in state)
+    return True, tuple(t._val for t in state)
+
+
 def _discovery_passes():
     """1 (default): one eager pass + traced set-extension fixpoint.
     2 (PADDLE_TPU_TWO_PASS_DISCOVERY=1): legacy two eager passes."""
@@ -433,13 +496,10 @@ class StaticFunction:
                      else tuple(t._val[i:] for t in leaves))
 
         def _exec_scan():   # write-seam: scan write-back of XLA-owned outputs clears taint
-            mut_vals = tuple(t._val for t in prog.mutated)
             ro_vals = tuple(t._val for t in prog.ro)
             rest = rest_vals
-            # same donation gate as _run: host-assigned state buffers
-            # (guard restore / checkpoint load) must not be donated
-            donate = not _donation_paused[0] and not any(
-                getattr(t, "_donate_unsafe", True) for t in prog.mutated)
+            donate, mut_vals = _donation_gate(
+                prog.mutated, prog.scanned_donate is not prog.scanned)
             exec_fn = prog.scanned_donate if donate else prog.scanned
             outs, new_state = exec_fn(mut_vals, ro_vals, rest)
             for t, v in zip(prog.mutated, new_state):
@@ -822,7 +882,6 @@ class StaticFunction:
 
     def _run(self, prog, args, kwargs):   # write-seam: compiled write-back of XLA-owned outputs clears taint
         arg_tensors = _flatten_tensors((args, kwargs), [])
-        mut_vals = tuple(t._val for t in prog.mutated)
         ro_vals = tuple(t._val for t in prog.ro)
         arg_vals = tuple(t._val for t in arg_tensors)
         n_outs = prog.n_outs
@@ -836,19 +895,13 @@ class StaticFunction:
                     diff_tensors.append(t)
 
         if not diff_tensors:
-            # donation gate: a mutated tensor whose value was assigned from
-            # the host since the last write-back (guard restore, checkpoint
-            # load) may be backed by an imported numpy buffer — donating it
-            # corrupts memory on the PJRT CPU backend (use-after-free; seen
-            # as silently wrong parameters and occasional segfaults). One
-            # un-donated launch re-homes the state in XLA-owned buffers.
-            donate = not _donation_paused[0] and not any(
-                getattr(t, "_donate_unsafe", True) for t in prog.mutated)
-            exec_fn = prog.jitted_donate if donate else prog.jitted
-            if not donate and prog.jitted_donate is not prog.jitted:
+            has_twin = prog.jitted_donate is not prog.jitted
+            donate, mut_vals = _donation_gate(prog.mutated, has_twin)
+            if has_twin and not donate:
                 _count("undonated_launches")
             flat = self._launch(prog, "donating" if donate else "plain",
-                                exec_fn, mut_vals, ro_vals, arg_vals)
+                                prog.jitted_donate if donate else prog.jitted,
+                                mut_vals, ro_vals, arg_vals)
             out_vals, new_state = flat[:n_outs], flat[n_outs:]
             for t, v in zip(prog.mutated, new_state):
                 t._val = v
@@ -882,6 +935,7 @@ class StaticFunction:
 
         # grad path: record the whole program as ONE tape op (run_program-grad
         # parity). Donation is off (residuals alias inputs).
+        mut_vals = tuple(t._val for t in prog.mutated)
         all_tensors = list(prog.mutated) + list(prog.ro) + arg_tensors
         all_vals = list(mut_vals) + list(ro_vals) + list(arg_vals)
         diff_idx = [i for i, t in enumerate(all_tensors)

@@ -211,8 +211,9 @@ class TestGuardAndDonation:
         assigned from the host (set_state_dict / checkpoint load) may be
         backed by an imported numpy buffer, which PJRT-CPU must NOT donate
         (donating one corrupts memory — silently wrong parameters, sometimes
-        a segfault). The taint forces one un-donated launch that re-homes the
-        state in XLA-owned buffers, then donation re-engages."""
+        a segfault). The gate donates a copy of a tainted value instead
+        (tests/test_donation_gate.py), and the launch's write-back clears
+        the taint."""
         paddle.set_flags({"FLAGS_donate_state_buffers": True})
         model = _mlp(seed=3)
         opt = paddle.optimizer.SGD(learning_rate=0.1,
@@ -229,7 +230,7 @@ class TestGuardAndDonation:
         model.set_state_dict(snap)
         assert p0._donate_unsafe is True   # host-imported: must not donate
         step(paddle.to_tensor(xs[3]), paddle.to_tensor(ys[3]))
-        assert p0._donate_unsafe is False  # laundered by one un-donated step
+        assert p0._donate_unsafe is False  # the launch wrote its own output back
 
     def test_stepguard_rollback_parity(self):
         """A NaN batch under the compiled step restores pre-step state

@@ -67,7 +67,7 @@ def gpt_step():
 @pytest.fixture(scope="module")
 def warm():
     """(step, batch, model, what the counters moved by over the step's
-    first three calls: eager discovery, plain compile, donating compile)."""
+    first three calls: eager discovery, the donating compile, a steady step)."""
     before = counters()
     step, batch, model = gpt_step()
     after_discovery = None
@@ -207,7 +207,7 @@ def test_span_is_on_the_trace_with_no_recorder_on(steady_trace, name):
     assert len(named(steady_trace, name)) == 1
 
 
-def test_first_calls_show_discovery_probes_and_both_compiles(tmp_path):
+def test_first_calls_show_discovery_probes_and_the_one_compile(tmp_path):
     step, batch, _ = gpt_step()
     before = counters()
     events = traced(tmp_path, lambda: [step(*batch()) for _ in range(3)])
@@ -216,7 +216,8 @@ def test_first_calls_show_discovery_probes_and_both_compiles(tmp_path):
     assert discover[4]["fn"].endswith("train_step")
     assert named(events, "to_static.probe")
     compiles = named(events, "to_static.compile")
-    assert [c[4]["program"] for c in compiles] == ["plain", "donating"]
+    # the first compiled launch is the donating one; no plain twin is built
+    assert [c[4]["program"] for c in compiles] == ["donating"]
     for c in compiles:   # the launch that compiled is inside its span
         assert [e[1] for e in inside(events, c)
                 if e[1].startswith("to_static.")] == ["to_static.launch"]
@@ -262,28 +263,29 @@ def test_ops_are_dispatched_in_discovery_and_traces_not_in_compiled_steps(warm):
     assert moved(before) == {"to_static.launches_total": 3}
 
 
-def test_first_three_calls_compile_twice_and_launch_the_plain_program_once(warm):
+def test_first_three_calls_compile_once_and_never_launch_the_plain_program(warm):
     *_, first_three = warm
-    assert first_three["to_static.compiles_total"] == 2
+    assert first_three["to_static.compiles_total"] == 1
     assert first_three["to_static.launches_total"] == 2
-    # call 2 could not donate what the eager pass had assigned
-    assert first_three["to_static.undonated_launches_total"] == 1
+    # call 2 donated what the eager pass had assigned (on the CPU, its copy)
+    assert "to_static.undonated_launches_total" not in first_three
     for seconds in ("to_static.trace_sec", "to_static.lower_sec",
                     "to_static.backend_compile_sec"):
         assert first_three[seconds] > 0
     assert "to_static.grad_path_launches_total" not in first_three
 
 
-def test_host_assigned_state_costs_one_undonated_launch(warm):
+def test_host_assigned_state_costs_one_copy_and_no_undonated_launch(warm):
     step, batch, model, *_ = warm
     p = model.parameters()[0]
     p.set_value(np.asarray(p._val, dtype="float32"))   # as a checkpoint load does
     assert p._donate_unsafe
-    before = counters()
+    before, copies = counters(), REG.counter_value("to_static.rehomed_leaves_total")
     step(*batch())
     step(*batch())
-    assert moved(before) == {"to_static.launches_total": 2,
-                             "to_static.undonated_launches_total": 1}
+    assert moved(before) == {"to_static.launches_total": 2}
+    assert REG.counter_value("to_static.rehomed_leaves_total") - copies == 1
+    assert not p._donate_unsafe
 
 
 def test_pause_donation_counts_as_undonated(warm):
