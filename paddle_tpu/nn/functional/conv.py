@@ -11,7 +11,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ...core.dispatch import apply
+from ...core.dispatch import apply, unwrap
 
 __all__ = ["conv1d", "conv2d", "conv3d", "conv1d_transpose", "conv2d_transpose",
            "conv3d_transpose", "short_conv", "short_conv_silu"]
@@ -206,14 +206,47 @@ def _causal_taps(u, w):
     return sum(padded[:, j:j + seq] * taps[:, j] for j in range(k))
 
 
-def short_conv_silu(x, weight, name=None):
+def short_conv_silu(x, weight, norm_head_dim=None, epsilon=1e-06, name=None):
     """silu of a causal depthwise convolution over `x` (batch, seq,
     channels): y_t = silu(sum_j weight[:, j] * x_{t - (K-1) + j}), zero
     before the sequence's start, as the linear-attention mixers put it after
     their q, k and v projections (Kimi Delta Attention: K = 4). `weight` is
     (channels, K), the last tap on the current step; the same shifted
-    multiply-adds as `short_conv`, in float32."""
-    def prim(v, w):
-        return jax.nn.silu(_causal_taps(v.astype(jnp.float32), w)).astype(v.dtype)
+    multiply-adds as `short_conv`, in float32. With `norm_head_dim` the
+    result, rounded to x's dtype, is then divided by its L2 norm over every
+    head of that many channels, sqrt(sum(y^2) + epsilon) in float32, as
+    `l2_norm` over the heads would: the mixers' queries and keys.
 
+    On a TPU a stream of float32 or bfloat16 in whole lane chunks is one pass
+    forward and one backward (ops/pallas/short_conv.py, which keeps the input
+    alone for its backward); everything else runs the jnp rule below, which
+    is the oracle of the other (`short_conv.kernel_total`,
+    `short_conv.xla_total` count the traced calls by path)."""
+    from ...ops import attention
+    from ...ops.pallas import short_conv as kernels
+    from ...ops.pallas.flash_attention import _interpret
+    from ...profiler import metrics
+    xv = unwrap(x)
+    kernel = kernels.takes(xv.shape, xv.dtype, unwrap(weight).shape[1], norm_head_dim,
+                           attention._platform(), attention._on_mesh(xv))
+    metrics.get_registry().inc_counter(
+        "short_conv.kernel_total" if kernel else "short_conv.xla_total")
+    if kernel:
+        interp = _interpret(xv)
+
+        def prim(v, w):
+            return kernels.short_conv_silu(v, w, norm_head_dim, epsilon, interp)
+    else:
+        def prim(v, w):
+            return _silu_taps(v, w, norm_head_dim, epsilon)
     return apply(prim, x, weight, name="short_conv")
+
+
+def _silu_taps(v, w, norm_head_dim=None, epsilon=1e-06):
+    """The jnp rule of `short_conv_silu`."""
+    y = jax.nn.silu(_causal_taps(v.astype(jnp.float32), w)).astype(v.dtype)
+    if norm_head_dim is None:
+        return y
+    f = y.astype(jnp.float32).reshape(y.shape[:-1] + (-1, norm_head_dim))
+    return (f * jax.lax.rsqrt(jnp.sum(jnp.square(f), axis=-1, keepdims=True)
+                              + epsilon)).astype(v.dtype).reshape(y.shape)

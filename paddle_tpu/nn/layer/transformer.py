@@ -498,10 +498,10 @@ class KimiDeltaAttention(Layer):
         from ...core.dispatch import apply
         b, s, _ = x.shape
         heads = [b, s, self.num_heads, self.head_dim]
-        q = F.l2_norm(M.reshape(
-            F.short_conv_silu(self.q_proj(x), self.q_conv1d), heads))
-        k = F.l2_norm(M.reshape(
-            F.short_conv_silu(self.k_proj(x), self.k_conv1d), heads))
+        q = M.reshape(F.short_conv_silu(
+            self.q_proj(x), self.q_conv1d, norm_head_dim=self.head_dim), heads)
+        k = M.reshape(F.short_conv_silu(
+            self.k_proj(x), self.k_conv1d, norm_head_dim=self.head_dim), heads)
         v = M.reshape(F.short_conv_silu(self.v_proj(x), self.v_conv1d), heads)
 
         def decay(f, a_log, dt_bias):

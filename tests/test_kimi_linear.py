@@ -194,7 +194,9 @@ def test_loss_gradients_and_three_adamw_steps(cell, leaves):
 def test_a_rematerialised_step_stages_the_scopes(monkeypatch):
     """`kda`, `flash_attention` and `moe_experts` on forward, rerun and
     backward instructions of a step whose blocks are rematerialised; the
-    latent layer takes the flash pair where the platform rule says TPU."""
+    latent layer takes the flash pair where the platform rule says TPU, and
+    the mixer's q, k and v streams the short convolution's pass (heads of a
+    whole lane chunk, as the published model has them)."""
     from paddle_tpu.jit.to_static import _flatten_tensors
     from paddle_tpu.ops import attention
     from paddle_tpu.profiler import metrics
@@ -203,6 +205,7 @@ def test_a_rematerialised_step_stages_the_scopes(monkeypatch):
     monkeypatch.setattr(attention, "FLASH_MIN_SEQ_Q", 128)
     cell = tiny(recompute=True, num_layers=2, first_layer=6,
                 qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=64)
+    cell["cfg"]["linear_attn_config"].update(num_heads=2, head_dim=128)
     family, cfg = cell["family"], cell["cfg"]
     model, _ = build(cell, seeded(cell))
     opt = paddle.optimizer.AdamW(learning_rate=1e-4, parameters=model.parameters())
@@ -217,13 +220,17 @@ def test_a_rematerialised_step_stages_the_scopes(monkeypatch):
 
     x, y = (paddle.to_tensor(a) for a in family.Stream(cfg, cell["job"], SEED).next())
     counters = metrics.get_registry().snapshot()["counters"]
-    before = [counters.get(n, 0.0) for n in ("attention.flash_total", "kda.calls_total")]
+    before = [counters.get(n, 0.0) for n in (
+        "attention.flash_total", "kda.calls_total", "short_conv.kernel_total",
+        "short_conv.xla_total")]
     step(x, y)                                    # the eager discovery pass
     (prog,) = step.programs.values()
     step._build(prog, (x, y), {})                 # traces; compiles nothing
     counters = metrics.get_registry().snapshot()["counters"]
     assert counters["attention.flash_total"] > before[0]
     assert counters["kda.calls_total"] > before[1]
+    assert counters["short_conv.kernel_total"] > before[2]
+    assert counters.get("short_conv.xla_total", 0.0) == before[3]
     text = prog.jitted_donate.lower(
         tuple(t._val for t in prog.mutated), tuple(t._val for t in prog.ro),
         tuple(t._val for t in _flatten_tensors(((x, y), {}), []))
@@ -238,4 +245,13 @@ def test_a_rematerialised_step_stages_the_scopes(monkeypatch):
         assert any(n.startswith(f"jit(pure_fn)/jvp({scope})") for n in mine), scope
         assert any(f"transpose(jvp(jvp({scope})))" in n for n in mine), scope
         assert any(f"transpose(jvp(transpose(" in n for n in mine), scope
+    # the streams are the fused op in all three passes: its forward twice,
+    # its backward once, and no l2_norm left beside them
+    conv = [n for n in names if program_trace.scope_of(n + "/op") == "short_conv"]
+    assert any(n.startswith("jit(pure_fn)/jvp(short_conv)/jit(stream_forward)")
+               for n in conv)
+    assert any("transpose(jvp(jvp(short_conv)))/jit(stream_forward)" in n for n in conv)
+    assert any("jit(stream_backward)" in n and "transpose(jvp(transpose(" in n
+               for n in conv)
+    assert not [n for n in names if program_trace.scope_of(n + "/op") == "l2_norm"]
     assert "checkpoint" not in text               # a custom_vjp region keeps the names

@@ -443,6 +443,32 @@ def test_kda_op_compiles_with_its_backward(one_chip):
     assert "reduce-window" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("norm", [pytest.param(128, id="heads-of-128"),
+                                  pytest.param(None, id="plain")])
+def test_short_conv_pass_compiles_at_kimi_linear_widths(one_chip, norm):
+    """A stream of the Kimi Linear mixer, (2, 4096, 4096) bf16 with 4 taps,
+    with the per-head norm (q, k) and without (v): the forward kernel, and the
+    op differentiated, whose backward holds no float32 array of a stream's
+    size (134 MB): it keeps the bf16 input and forms the taps again."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import short_conv as sc
+    x = _sds((2, 4096, 4096), jnp.bfloat16, one_chip)
+    w = _sds((4096, 4), jnp.bfloat16, one_chip)
+    assert sc.takes(x.shape, x.dtype, 4, norm, "tpu")
+    forward = sc.stream_forward.lower(x, w, norm, 1e-6, False).compile()
+    _assert_kernel(forward, 1)
+    assert forward.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+    def pulled(v, taps, dy):
+        y, pull = jax.vjp(lambda a, b: sc.short_conv_silu(a, b, norm, 1e-6, False), v, taps)
+        return (y, *pull(dy))
+    compiled = jax.jit(pulled).lower(x, w, x).compile()
+    _assert_kernel(compiled, 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 134 * 10 ** 6
+    assert "f32[2,4096,4096]" not in compiled.as_text()
+
+
 # ---------------------------------------------------------------------------
 # Keye-VL-2.0 widths: attention over a set a query at 8192 positions
 

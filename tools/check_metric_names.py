@@ -60,6 +60,7 @@ SUBSYSTEMS = [
     "runtime",       # the process's own set-up: the package's import, the
                      # set-up timeline's bound (profiler/compile_events.py)
     "serving",       # inference server
+    "short_conv",    # the path F.short_conv_silu took (nn/functional/conv.py)
     "slo",           # SLO burn-rate accounting (serving/metrics.py)
     "spec",          # speculative decoding (serving/decode/specdecode.py)
     "steptime",      # per-rank step-time health beacons
