@@ -418,6 +418,14 @@ class MultiHeadLatentAttention(Layer):
         self.o_proj = linear(num_heads * v_head_dim, hidden_size)
 
     def forward(self, x):
+        """A block runs the three parts itself, the core between its
+        rematerialised regions."""
+        return self.project(self.core(*self.operands(x)))
+
+    def operands(self, x):
+        """(q, k, v) from the block's normed input: what the attention core
+        reads, the projections, the latent's norm, the rotation and `mla_kv`
+        behind them."""
         import jax.numpy as jnp
         from ...core.dispatch import apply
         b, s, _ = x.shape
@@ -446,9 +454,18 @@ class MultiHeadLatentAttention(Layer):
             return (jnp.concatenate([kv_[..., :nope], pe], axis=-1),
                     kv_[..., nope:])
         k, v = apply(keys_values, kv, shared, name="mla_kv")
-        out = F.scaled_dot_product_attention(
+        return q, k, v
+
+    def core(self, q, k, v):
+        """The heads' outputs (batch, seq, heads, `v_head_dim`): causal
+        attention, which a rematerialised block keeps on the tape
+        (docs/kernels.md, "What a rematerialised block keeps")."""
+        return F.scaled_dot_product_attention(
             q, k, v, is_causal=True, training=self.training, scale=self.scale)
-        return self.o_proj(M.reshape(out, [b, s, heads * dv]))
+
+    def project(self, out):
+        b, s = out.shape[:2]
+        return self.o_proj(M.reshape(out, [b, s, self.num_heads * self.v_head_dim]))
 
 
 class KimiDeltaAttention(Layer):
@@ -493,6 +510,16 @@ class KimiDeltaAttention(Layer):
         self.o_proj = linear(width, hidden_size)
 
     def forward(self, x):
+        """A block runs the three parts itself, the core between its
+        rematerialised regions."""
+        *operands, gate = self.operands(x)
+        return self.project(self.core(*operands), gate)
+
+    def operands(self, x):
+        """(q, k, v, g, beta, gate) from the block's normed input: what the
+        delta-rule core reads and, last, the output gate's low-rank
+        activation (batch, seq, `gate_rank`), which `project` reads: all of
+        x that is needed after the core, at a hundredth of the gate's bytes."""
         import jax
         import jax.numpy as jnp
         from ...core.dispatch import apply
@@ -511,6 +538,16 @@ class KimiDeltaAttention(Layer):
         g = apply(decay, self.f_b_proj(self.f_a_proj(x)), self.A_log,
                   self.dt_bias, name="kda_gate")
         beta = F.sigmoid(self.b_proj(x))
-        o = self.o_norm(F.kimi_delta_attention(q, k, v, g, beta))
-        gate = M.reshape(F.sigmoid(self.g_b_proj(self.g_a_proj(x))), heads)
-        return self.o_proj(M.reshape(o * gate, [b, s, heads[2] * heads[3]]))
+        return q, k, v, g, beta, self.g_a_proj(x)
+
+    def core(self, q, k, v, g, beta):
+        """o (batch, seq, heads, head_dim): the chunked gated delta rule,
+        which a rematerialised block keeps on the tape (docs/kernels.md,
+        "What a rematerialised block keeps")."""
+        return F.kimi_delta_attention(q, k, v, g, beta)
+
+    def project(self, o, gate):
+        b, s = o.shape[:2]
+        gate = M.reshape(F.sigmoid(self.g_b_proj(gate)), o.shape)
+        return self.o_proj(M.reshape(self.o_norm(o) * gate,
+                                     [b, s, self.num_heads * self.head_dim]))

@@ -104,12 +104,29 @@ class DeepseekV2Block(nn.Layer):
                 renormalize=cfg.norm_topk_prob,
                 balance_alpha=cfg.aux_loss_alpha)
 
-    def forward(self, x):
+    def forward(self, x, rematerialise=False):
         """(y, load, balance loss, picks): the last three are the expert
         layer's (`DroplessMoELayer.forward`), None under a dense
         feed-forward; the model records and adds them outside any
-        rematerialised region."""
-        x = x + self.self_attn(self.input_layernorm(x))
+        rematerialised region.
+
+        With `rematerialise` the block is two regions of
+        `fleet.utils.recompute` round the attention core, and the core runs
+        once, on the tape: its rerun would be the flash forward for results
+        (the heads' outputs, the logsumexp) the pair's own backward rule
+        keeps at 168 MB a layer at 8192 positions (docs/kernels.md, "What a
+        rematerialised block keeps")."""
+        if rematerialise:
+            from ...distributed.fleet.utils import recompute as region
+        else:
+            def region(function, *args):
+                return function(*args)
+        operands = region(
+            lambda v: self.self_attn.operands(self.input_layernorm(v)), x)
+        return region(self._after_core, x, self.self_attn.core(*operands))
+
+    def _after_core(self, x, out):
+        x = x + self.self_attn.project(out)
         a = self.post_attention_layernorm(x)
         if self.is_dense:
             return x + self.mlp(a), None, None, None
@@ -135,11 +152,9 @@ class DeepseekV2Model(nn.Layer):
         None where no layer held has experts)."""
         x = self.embed_tokens(input_ids)
         remat = self.config.recompute and self.training
-        if remat:
-            from ...distributed.fleet.utils import recompute
         balance = None
         for block in self.layers:
-            x, load, layer_loss, picks = recompute(block, x) if remat else block(x)
+            x, load, layer_loss, picks = block(x, remat)
             if load is not None:
                 block.mlp.record_load(load, layer_loss, picks)
                 balance = layer_loss if balance is None else balance + layer_loss

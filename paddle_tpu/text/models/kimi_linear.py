@@ -92,6 +92,7 @@ class KimiLinearBlock(nn.Layer):
                 cfg.norm_eps, weight_attr=w)
         else:
             raise ValueError(f"layer type {layer_type!r}")
+        self.is_kda = layer_type == "kda"
         self.is_dense = dense
         if dense:
             self.mlp = nn.SwiGLUFFN(h, cfg.intermediate_size, weight_attr=w)
@@ -104,11 +105,32 @@ class KimiLinearBlock(nn.Layer):
                 shared_width=cfg.num_shared_experts * cfg.moe_intermediate_size
                 or None)
 
-    def forward(self, x):
+    def forward(self, x, rematerialise=False):
         """(y, load): `load` is the expert layer's rows per held expert,
         None under a dense feed-forward; the model adds it to the layer's
-        counters outside any rematerialised region."""
-        x = x + self.self_attn(self.input_layernorm(x))
+        counters outside any rematerialised region.
+
+        With `rematerialise` the block is two regions of
+        `fleet.utils.recompute` round the mixer's core, and the core runs
+        once, on the tape: its rerun would be the delta rule's forward loops
+        (the flash forward in a latent layer) for results the op's own
+        backward rule keeps at 0.67 GB a layer (0.34 GB) at 2 x 4096 tokens
+        (docs/kernels.md, "What a rematerialised block keeps")."""
+        if rematerialise:
+            from ...distributed.fleet.utils import recompute as region
+        else:
+            def region(function, *args):
+                return function(*args)
+        operands = region(
+            lambda v: self.self_attn.operands(self.input_layernorm(v)), x)
+        # a KDA layer's last operand is `project`'s, not the core's
+        operands, carried = ((operands[:-1], operands[-1:]) if self.is_kda
+                             else (operands, ()))
+        out = self.self_attn.core(*operands)
+        return region(self._after_core, x, out, *carried)
+
+    def _after_core(self, x, out, *carried):
+        x = x + self.self_attn.project(out, *carried)
         a = self.post_attention_layernorm(x)
         if self.is_dense:
             return x + self.mlp(a), None
@@ -132,10 +154,8 @@ class KimiLinearModel(nn.Layer):
     def forward(self, input_ids):
         x = self.embed_tokens(input_ids)
         remat = self.config.recompute and self.training
-        if remat:
-            from ...distributed.fleet.utils import recompute
         for block in self.layers:
-            x, load = recompute(block, x) if remat else block(x)
+            x, load = block(x, remat)
             if load is not None:
                 block.mlp.record_load(load)
         return self.norm(x)

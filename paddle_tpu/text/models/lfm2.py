@@ -57,7 +57,8 @@ class LFM2Config:
         self.rope_theta = rope_theta
         self.norm_eps = norm_eps
         # rematerialise each block in the backward pass (fleet.utils.recompute):
-        # a block keeps its input only, which buys batch or sequence on one chip
+        # a block keeps its input, an attention block what its attention core
+        # keeps as well, which buys batch or sequence on one chip
         self.recompute = recompute
 
 
@@ -79,15 +80,30 @@ class LFM2Attention(nn.Layer):
         self.k_norm = nn.RMSNorm(self.head_dim, cfg.norm_eps)
 
     def forward(self, x):
-        b, s, h = x.shape
+        """A block runs the three parts itself, the core between its
+        rematerialised regions."""
+        return self.project(self.core(*self.operands(x)))
+
+    def operands(self, x):
+        """(q, k, v) from the block's normed input: what the attention core
+        reads."""
+        b, s, _ = x.shape
         q = M.reshape(self.q_proj(x), [b, s, self.num_heads, self.head_dim])
         k = M.reshape(self.k_proj(x), [b, s, self.num_kv_heads, self.head_dim])
         v = M.reshape(self.v_proj(x), [b, s, self.num_kv_heads, self.head_dim])
         q, k = F.rotary_position_embedding(self.q_norm(q), self.k_norm(k),
                                            theta=self.rope_theta)
-        out = F.scaled_dot_product_attention(q, k, v, is_causal=True,
-                                             training=self.training)
-        return self.out_proj(M.reshape(out, [b, s, h]))
+        return q, k, v
+
+    def core(self, q, k, v):
+        """The heads' outputs (batch, seq, heads, head_dim): causal attention,
+        which a rematerialised block keeps on the tape (`LFM2Block.forward`)."""
+        return F.scaled_dot_product_attention(q, k, v, is_causal=True,
+                                              training=self.training)
+
+    def project(self, out):
+        b, s = out.shape[:2]
+        return self.out_proj(M.reshape(out, [b, s, self.num_heads * self.head_dim]))
 
 
 class LFM2Block(nn.Layer):
@@ -115,12 +131,34 @@ class LFM2Block(nn.Layer):
                 routed_scaling_factor=cfg.routed_scaling_factor,
                 weight_attr=w)
 
-    def forward(self, x):
+    def forward(self, x, rematerialise=False):
         """(y, load): `load` is the expert layer's rows per held expert,
         None under a dense feed-forward; the model adds it to the layer's
-        counters outside any rematerialised region."""
-        a = self.operator_norm(x)
-        x = x + (self.conv(a) if self.is_conv else self.self_attn(a))
+        counters outside any rematerialised region.
+
+        With `rematerialise` a `conv` block is one region of
+        `fleet.utils.recompute`: its mixer has no core worth its bytes. An
+        attention block is two regions round the attention core, and the
+        core runs once, on the tape: its rerun would be the flash forward
+        for results (the heads' outputs, the logsumexp) the pair's own
+        backward rule keeps (docs/kernels.md, "What a rematerialised block
+        keeps")."""
+        if rematerialise:
+            from ...distributed.fleet.utils import recompute as region
+        else:
+            def region(function, *args):
+                return function(*args)
+        if self.is_conv:
+            return region(lambda v: self._after_mixer(
+                v, self.conv(self.operator_norm(v))), x)
+        operands = region(
+            lambda v: self.self_attn.operands(self.operator_norm(v)), x)
+        out = self.self_attn.core(*operands)
+        return region(lambda v, o: self._after_mixer(
+            v, self.self_attn.project(o)), x, out)
+
+    def _after_mixer(self, x, mixed):
+        x = x + mixed
         a = self.ffn_norm(x)
         if self.is_dense:
             return x + self.feed_forward(a), None
@@ -144,10 +182,8 @@ class LFM2Model(nn.Layer):
     def forward(self, input_ids):
         x = self.embed_tokens(input_ids)
         remat = self.config.recompute and self.training
-        if remat:
-            from ...distributed.fleet.utils import recompute
         for block in self.layers:
-            x, load = recompute(block, x) if remat else block(x)
+            x, load = block(x, remat)
             if load is not None:
                 block.feed_forward.record_load(load)
         return self.embedding_norm(x)

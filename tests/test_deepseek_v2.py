@@ -29,6 +29,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 import paddle_tpu as paddle  # noqa: E402
 import paddle_tpu.nn.functional as F  # noqa: E402
+import rematerialised_step  # noqa: E402
 from benchmarks import harness  # noqa: E402
 from benchmarks.reference import adamw  # noqa: E402
 from benchmarks.reference import deepseek_v2 as ref  # noqa: E402
@@ -390,65 +391,88 @@ def test_a_fault_is_not_within_the_tolerances(cell, leaves, model, fault):
 # ---------------------------------------------------------------------------
 # what a step stages
 
-def test_a_rematerialised_step_stages_the_scopes_and_moves_the_counters(monkeypatch):
-    """`mla_rope`, `moe_balance_loss`, `flash_attention` and the expert
-    layer's scopes on forward, rerun and backward instructions of a
-    `to_static` step whose blocks are rematerialised; the registry's two new
-    readings move with the step."""
-    from paddle_tpu.jit.to_static import _flatten_tensors
-    from paddle_tpu.ops import attention
+@pytest.fixture(scope="module")
+def traced_step():
+    """One training step over three rematerialised blocks (a dense one and
+    two with experts), on a platform rule that says TPU so that attention
+    takes the flash pair; the model and the registry's gauges after it."""
     from paddle_tpu.profiler import metrics
-    from benchmarks import program_trace
-    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
-    monkeypatch.setattr(attention, "FLASH_MIN_SEQ_K", 128)
-    monkeypatch.setattr(attention, "FLASH_MIN_SEQ_Q", 128)
-    cell = tiny(recompute=True, absent_experts="stand_in", qk_nope_head_dim=64,
-                qk_rope_head_dim=64, v_head_dim=64)
-    cell["job"].update(batch=1, seq=128)
-    family, cfg = cell["family"], cell["cfg"]
-    model, _ = build(cell, seeded(cell))
-    opt = paddle.optimizer.AdamW(learning_rate=1e-4, parameters=model.parameters())
+    with pytest.MonkeyPatch.context() as patch:
+        rematerialised_step.flash_on_a_cpu(patch)
+        cell = tiny(recompute=True, absent_experts="stand_in", qk_nope_head_dim=64,
+                    qk_rope_head_dim=64, v_head_dim=64)
+        cell["job"].update(batch=1, seq=128)
+        model, _ = build(cell, seeded(cell))
+        (x, y), = batch(cell)
+        got = rematerialised_step.traced_step(
+            model, cell["family"].loss_of, paddle.to_tensor(x), paddle.to_tensor(y))
+        return dict(got, model=model, layers=cell["cfg"]["num_layers"],
+                    gauges=metrics.get_registry().snapshot()["gauges"])
 
-    @paddle.jit.to_static
-    def step(x, y):
-        loss = family.loss_of(model, x, y)
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-        return loss
 
-    (x, y), = batch(cell)
-    x, y = paddle.to_tensor(x), paddle.to_tensor(y)
-    before = metrics.get_registry().snapshot()["counters"]
-    step(x, y)                                    # the eager discovery pass
-    (prog,) = step.programs.values()
-    step._build(prog, (x, y), {})                 # traces; compiles nothing
-    snap = metrics.get_registry().snapshot()
-    after = snap["counters"]
-    assert after["attention.flash_total"] > before.get("attention.flash_total", 0.0)
-    layers = [b.mlp for b in model.model.layers if not b.is_dense]
+def test_a_rematerialised_step_stages_the_scopes_and_moves_the_counters(traced_step):
+    """`flash_attention`, the attention core, on forward and backward
+    instructions of a `to_static` step whose blocks are rematerialised and on
+    none of a rerun: the core is outside the blocks' regions. `mla_rope`,
+    `mla_kv`, the products, the norms, `moe_balance_loss` and the expert
+    layer's scopes are inside them, on forward, rerun and backward
+    instructions; the registry's two readings of the router move with the
+    step."""
+    moved, names = traced_step["moved"]["both"], traced_step["names"]
+    assert moved["attention.flash_total"] > 0
+    layers = [b.mlp for b in traced_step["model"].model.layers if not b.is_dense]
     calls = sum(float(m.calls_total._val) for m in layers)
     assert calls == 2 and sum(float(m.rows_total._val) for m in layers) == 2 * 128 * 3
     # the term summed over the layers and calls: about alpha each (f P sums
     # to 1 under a router in balance, more under one that is not)
     total = sum(float(m.balance_total._val) for m in layers)
     assert 0.001 * calls <= total < 0.004 * calls
-    assert after["moe.balance_loss_total"] - before.get("moe.balance_loss_total", 0.0) \
-        == pytest.approx(total, rel=1e-5)
+    assert moved["moe.balance_loss_total"] == pytest.approx(total, rel=1e-5)
     # the most-picked of 16 published experts over the mean: 1 to 16 / 3
-    assert 1.0 <= snap["gauges"]["moe.router_max_over_mean_ratio"] <= 16 / 3
-    text = prog.jitted_donate.lower(
-        tuple(t._val for t in prog.mutated), tuple(t._val for t in prog.ro),
-        tuple(t._val for t in _flatten_tensors(((x, y), {}), []))
-    ).as_text(debug_info=True)
-    names = set(re.findall(r'loc\("(jit\(pure_fn\)/[^"]*)"', text))
-    for scope in ("mla_rope", "mla_kv", "flash_attention", "moe_balance_loss",
+    assert 1.0 <= traced_step["gauges"]["moe.router_max_over_mean_ratio"] <= 16 / 3
+    assert rematerialised_step.passes_of(names, "flash_attention") == {"forward", "backward"}
+    assert "transpose(jvp(jvp(flash_attention)))" not in traced_step["text"]
+    for scope in ("mla_rope", "mla_kv", "linear", "rms_norm", "moe_balance_loss",
                   "moe_route", "moe_experts"):
-        mine = [n for n in names if program_trace.scope_of(n + "/op") == scope]
         # the forward, the rematerialised forward and the backward
-        assert any(n.startswith(f"jit(pure_fn)/jvp({scope})") for n in mine), scope
-        assert any(f"transpose(jvp(jvp({scope})))" in n for n in mine), scope
-    assert "checkpoint" not in text               # a custom_vjp region keeps the names
+        assert {"forward", "rerun"} <= rematerialised_step.passes_of(names, scope), scope
+    for scope in ("mla_rope", "mla_kv", "linear", "rms_norm", "moe_experts"):
+        assert "backward" in rematerialised_step.passes_of(names, scope), scope
+    assert "checkpoint" not in traced_step["text"]  # a custom_vjp region keeps the names
+
+
+@pytest.mark.parametrize("which", ["eager", "traced"])
+def test_a_rematerialised_step_runs_its_attention_core_once(traced_step, which):
+    """A layer a pass of the step's body (the eager discovery pass; each
+    trace of the step program) one flash forward: none in a region's
+    discovery, first run or rerun, which made it three a layer a pass while a
+    block was one region."""
+    runs = traced_step["passes"][which] * traced_step["layers"]
+    assert runs > 0
+    assert traced_step["moved"][which]["attention.flash_total"] == runs
+
+
+@pytest.mark.parametrize("absent", ["drop", "stand_in"])
+def test_a_rematerialised_model_is_the_plain_model(absent):
+    """Two regions round the attention core and the core on the tape are the
+    plain block's arithmetic: both parts of the loss and every leaf's
+    gradient, under both ways of cutting the expert layer (a region runs as
+    one program, so float32 sums come in another order: to 1.02e-6 of a
+    leaf's norm read)."""
+    cell = tiny(absent_experts=absent)
+    leaves = seeded(cell)
+    (x, y), = batch(cell)
+    x, y = paddle.to_tensor(x), paddle.to_tensor(y)
+    got = {}
+    for recompute in (False, True):
+        model, names = build(dict(cell, cfg=dict(cell["cfg"], recompute=recompute)),
+                             leaves)
+        loss, lm, balance = model(x, labels=y)
+        got[recompute] = (float(lm.item()), float(balance.item()),
+                          rematerialised_step.grads_by_leaf(model, names, loss))
+    (lm, balance, grads), (lm_r, balance_r, grads_r) = got[False], got[True]
+    assert abs(lm_r - lm) <= 1e-6 * lm and abs(balance_r - balance) <= 1e-6 * balance
+    rematerialised_step.assert_the_same_gradients(grads, grads_r, tol=4e-6)
 
 
 # ---------------------------------------------------------------------------

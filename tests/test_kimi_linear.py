@@ -19,6 +19,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 import paddle_tpu as paddle  # noqa: E402
+import rematerialised_step  # noqa: E402
 from benchmarks import harness  # noqa: E402
 from benchmarks.reference import adamw  # noqa: E402
 
@@ -191,67 +192,95 @@ def test_loss_gradients_and_three_adamw_steps(cell, leaves):
             < 2e-2 * moved, leaf
 
 
-def test_a_rematerialised_step_stages_the_scopes(monkeypatch):
-    """`kda`, `flash_attention` and `moe_experts` on forward, rerun and
-    backward instructions of a step whose blocks are rematerialised; the
-    latent layer takes the flash pair where the platform rule says TPU, and
-    the mixer's q, k and v streams the short convolution's pass (heads of a
-    whole lane chunk, as the published model has them)."""
-    from paddle_tpu.jit.to_static import _flatten_tensors
-    from paddle_tpu.ops import attention
-    from paddle_tpu.profiler import metrics
-    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
-    monkeypatch.setattr(attention, "FLASH_MIN_SEQ_K", 128)
-    monkeypatch.setattr(attention, "FLASH_MIN_SEQ_Q", 128)
-    cell = tiny(recompute=True, num_layers=2, first_layer=6,
-                qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=64)
-    cell["cfg"]["linear_attn_config"].update(num_heads=2, head_dim=128)
-    family, cfg = cell["family"], cell["cfg"]
-    model, _ = build(cell, seeded(cell))
-    opt = paddle.optimizer.AdamW(learning_rate=1e-4, parameters=model.parameters())
-
-    @paddle.jit.to_static
-    def step(x, y):
+def test_a_rematerialised_model_is_the_plain_model(cell, leaves):
+    """Two regions round each mixer's core and the core on the tape are the
+    plain block's arithmetic: the loss and every leaf's gradient, over three
+    KDA layers and a latent one, dense and expert feed-forwards. A region
+    runs as one program and the plain block op by op, so float32 sums come
+    in another order: the decay's leaves (`a_log`, `dt_bias`: sums over every
+    token of exp and softplus terms) read 5e-6 to 2.4e-5 of their norm, as
+    they did while the whole block was one region (5e-6 to 1.1e-5), every
+    other leaf under 4e-6."""
+    family = cell["family"]
+    x, y = (paddle.to_tensor(a) for a in
+            family.Stream(cell["cfg"], cell["job"], SEED).next())
+    got = {}
+    for recompute in (False, True):
+        model, names = build(dict(cell, cfg=dict(cell["cfg"], recompute=recompute)),
+                             leaves)
+        assert model.training
         loss = family.loss_of(model, x, y)
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-        return loss
+        got[recompute] = float(loss.item()), rematerialised_step.grads_by_leaf(
+            model, names, loss)
+    (loss, grads), (loss_r, grads_r) = got[False], got[True]
+    assert abs(loss_r - loss) <= 1e-6 * loss
+    rematerialised_step.assert_the_same_gradients(grads, grads_r, tol=5e-5)
 
-    x, y = (paddle.to_tensor(a) for a in family.Stream(cfg, cell["job"], SEED).next())
-    counters = metrics.get_registry().snapshot()["counters"]
-    before = [counters.get(n, 0.0) for n in (
-        "attention.flash_total", "kda.calls_total", "short_conv.kernel_total",
-        "short_conv.xla_total")]
-    step(x, y)                                    # the eager discovery pass
-    (prog,) = step.programs.values()
-    step._build(prog, (x, y), {})                 # traces; compiles nothing
-    counters = metrics.get_registry().snapshot()["counters"]
-    assert counters["attention.flash_total"] > before[0]
-    assert counters["kda.calls_total"] > before[1]
-    assert counters["short_conv.kernel_total"] > before[2]
-    assert counters.get("short_conv.xla_total", 0.0) == before[3]
-    text = prog.jitted_donate.lower(
-        tuple(t._val for t in prog.mutated), tuple(t._val for t in prog.ro),
-        tuple(t._val for t in _flatten_tensors(((x, y), {}), []))
-    ).as_text(debug_info=True)
-    import re
+
+@pytest.fixture(scope="module")
+def traced_step():
+    """One training step over a rematerialised KDA block and a rematerialised
+    latent block, on a platform rule that says TPU: the latent layer takes
+    the flash pair and the mixer's q, k and v streams the short convolution's
+    pass (heads of a whole lane chunk, as the published model has them)."""
+    with pytest.MonkeyPatch.context() as patch:
+        rematerialised_step.flash_on_a_cpu(patch)
+        cell = tiny(recompute=True, num_layers=2, first_layer=6,
+                    qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=64)
+        cell["cfg"]["linear_attn_config"].update(num_heads=2, head_dim=128)
+        family, cfg = cell["family"], cell["cfg"]
+        model, _ = build(cell, seeded(cell))
+        assert [b.is_kda for b in model.model.layers] == [True, False]
+        x, y = (paddle.to_tensor(a) for a in family.Stream(cfg, cell["job"], SEED).next())
+        return rematerialised_step.traced_step(model, family.loss_of, x, y)
+
+
+def test_a_rematerialised_step_stages_the_scopes(traced_step):
+    """The mixers' cores, `kda` and `flash_attention`, on forward and backward
+    instructions of a step whose blocks are rematerialised, and on none of a
+    rerun: the cores are outside the blocks' regions. What the regions hold
+    is staged in all three passes: the products, the norms, `mla_kv`, the
+    decay and the expert layer. The mixer's q, k and v streams are the fused
+    op (scope `short_conv`) forward and backward; the rerun of the first
+    region drops their forward, since nothing but the core read its result
+    and the stream's backward recomputes from the region's input."""
     from benchmarks import program_trace
-    names = set(re.findall(r'loc\("(jit\(pure_fn\)/[^"]*)"', text))
-    for scope in ("kda", "flash_attention", "moe_experts", "short_conv", "mla_kv"):
-        mine = [n for n in names if program_trace.scope_of(n + "/op") == scope]
-        # the forward, the rematerialised forward (the region's backward runs
-        # the block again) and the backward
-        assert any(n.startswith(f"jit(pure_fn)/jvp({scope})") for n in mine), scope
-        assert any(f"transpose(jvp(jvp({scope})))" in n for n in mine), scope
-        assert any(f"transpose(jvp(transpose(" in n for n in mine), scope
-    # the streams are the fused op in all three passes: its forward twice,
-    # its backward once, and no l2_norm left beside them
+    names, moved = traced_step["names"], traced_step["moved"]["both"]
+    assert moved["attention.flash_total"] > 0 and moved["kda.calls_total"] > 0
+    assert moved["short_conv.kernel_total"] > 0
+    assert moved.get("short_conv.xla_total", 0.0) == 0
+    for scope in ("kda", "flash_attention"):
+        assert rematerialised_step.passes_of(names, scope) == {"forward", "backward"}, scope
+    assert "transpose(jvp(jvp(kda)))" not in traced_step["text"]
+    assert "transpose(jvp(jvp(flash_attention)))" not in traced_step["text"]
+    for scope in ("linear", "rms_norm", "moe_experts", "mla_kv", "kda_gate"):
+        assert rematerialised_step.passes_of(names, scope) == {
+            "forward", "rerun", "backward"}, scope
+    # the streams are the fused op: its forward once, its backward once, and
+    # no l2_norm left beside them
     conv = [n for n in names if program_trace.scope_of(n + "/op") == "short_conv"]
     assert any(n.startswith("jit(pure_fn)/jvp(short_conv)/jit(stream_forward)")
                for n in conv)
-    assert any("transpose(jvp(jvp(short_conv)))/jit(stream_forward)" in n for n in conv)
+    assert not [n for n in conv if "transpose(" in n and "jit(stream_forward)" in n]
     assert any("jit(stream_backward)" in n and "transpose(jvp(transpose(" in n
                for n in conv)
     assert not [n for n in names if program_trace.scope_of(n + "/op") == "l2_norm"]
-    assert "checkpoint" not in text               # a custom_vjp region keeps the names
+    assert "checkpoint" not in traced_step["text"]  # a custom_vjp region keeps the names
+
+
+@pytest.mark.parametrize("which", ["eager", "traced"])
+def test_a_rematerialised_step_runs_its_mixer_cores_once(traced_step, which):
+    """A layer a pass of the step's body (the eager discovery pass; each
+    trace of the step program) one call of the KDA op in the KDA layer and
+    one flash forward in the latent layer: none in a region's discovery,
+    first run or rerun, which made it three a pass while a block was one
+    region."""
+    passes = traced_step["passes"][which]
+    assert passes > 0
+    moved = traced_step["moved"][which]
+    assert moved["kda.calls_total"] == passes
+    assert moved["kda.tokens_total"] == passes * 2 * 128
+    assert moved["attention.flash_total"] == passes
+    # the streams stay inside the first region: q, k and v in its discovery,
+    # its first run and its rerun
+    assert moved["short_conv.kernel_total"] == passes * 3 * 3

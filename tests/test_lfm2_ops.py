@@ -14,6 +14,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 import paddle_tpu as paddle  # noqa: E402
 import paddle_tpu.nn.functional as F  # noqa: E402
+import rematerialised_step  # noqa: E402
 from benchmarks import harness  # noqa: E402
 from benchmarks.families import lfm2_moe as family  # noqa: E402
 from benchmarks.reference import lfm2_moe as ref  # noqa: E402
@@ -204,6 +205,15 @@ def seeded(cfg, seed):
     return {k: 8 * v if v.ndim >= 2 else v for k, v in p.items()}
 
 
+def built(cfg, p):
+    model = family.build_model(cfg)
+    names = family.program_names(cfg)
+    missing, unexpected = model.set_state_dict(
+        {names[k]: paddle.Tensor(v) for k, v in p.items()})
+    assert not missing and not unexpected
+    return model, names
+
+
 @pytest.mark.parametrize("recompute", [False, True], ids=["plain", "remat"])
 def test_model_loss_and_gradients_match_the_reference(recompute):
     cfg = small_cfg(recompute=recompute)
@@ -212,11 +222,7 @@ def test_model_loss_and_gradients_match_the_reference(recompute):
     with jax.default_matmul_precision("highest"):
         want, want_g = jax.value_and_grad(
             lambda p: ref.loss_fn(p, jnp.asarray(x), jnp.asarray(y), cfg))(p)
-    model = family.build_model(cfg)
-    names = family.program_names(cfg)
-    missing, unexpected = model.set_state_dict(
-        {names[k]: paddle.Tensor(v) for k, v in p.items()})
-    assert not missing and not unexpected
+    model, names = built(cfg, p)
     loss = family.loss_of(model, paddle.to_tensor(x), paddle.to_tensor(y))
     loss.backward()
     assert float(loss.item()) == pytest.approx(float(want), rel=1e-5)
@@ -228,6 +234,64 @@ def test_model_loss_and_gradients_match_the_reference(recompute):
             continue
         err = np.linalg.norm(np.asarray(got._val) - np.asarray(g))
         assert err <= 2e-5 * max(np.linalg.norm(np.asarray(g)), 1e-3), leaf
+
+
+def test_a_rematerialised_model_is_the_plain_model():
+    """A `conv` block as one region, an attention block as two regions round
+    its attention core with the core on the tape: the plain blocks'
+    arithmetic, in the loss and in every leaf's gradient."""
+    p = seeded(small_cfg(), 3)
+    x, y = map(paddle.to_tensor, family.Stream(small_cfg(), {"batch": 2, "seq": 128}, 3).next())
+    got = {}
+    for recompute in (False, True):
+        model, names = built(small_cfg(recompute=recompute), p)
+        assert [b.is_conv for b in model.model.layers].count(False) == 1
+        loss = family.loss_of(model, x, y)
+        got[recompute] = float(loss.item()), rematerialised_step.grads_by_leaf(
+            model, names, loss)
+    (loss, grads), (loss_r, grads_r) = got[False], got[True]
+    assert abs(loss_r - loss) <= 1e-6 * loss
+    rematerialised_step.assert_the_same_gradients(grads, grads_r, tol=4e-6)
+
+
+@pytest.fixture(scope="module")
+def traced_step():
+    """One training step over the cell's five rematerialised blocks (four
+    `conv`, one attention), heads of 64 on a platform rule that says TPU so
+    that attention takes the flash pair."""
+    with pytest.MonkeyPatch.context() as patch:
+        rematerialised_step.flash_on_a_cpu(patch)
+        cfg = small_cfg(recompute=True, hidden_size=128, num_attention_heads=2,
+                        num_key_value_heads=1)
+        model, _ = built(cfg, seeded(cfg, 3))
+        x, y = map(paddle.to_tensor, family.Stream(cfg, {"batch": 2, "seq": 128}, 3).next())
+        return rematerialised_step.traced_step(model, family.loss_of, x, y)
+
+
+def test_a_rematerialised_step_stages_the_scopes(traced_step):
+    """`flash_attention`, the attention core, on forward and backward
+    instructions and on none of a rerun: the core is outside the attention
+    block's two regions. The products, the norms, the rotation, the short
+    convolution and the expert layer are inside a region, on forward, rerun
+    and backward instructions."""
+    names = traced_step["names"]
+    assert rematerialised_step.passes_of(names, "flash_attention") == {"forward", "backward"}
+    assert "transpose(jvp(jvp(flash_attention)))" not in traced_step["text"]
+    for scope in ("linear", "rms_norm", "rope", "short_conv", "moe_experts"):
+        assert rematerialised_step.passes_of(names, scope) == {
+            "forward", "rerun", "backward"}, scope
+    assert "checkpoint" not in traced_step["text"]  # a custom_vjp region keeps the names
+
+
+@pytest.mark.parametrize("which", ["eager", "traced"])
+def test_a_rematerialised_step_runs_its_attention_core_once(traced_step, which):
+    """A pass of the step's body (the eager discovery pass; each trace of
+    the step program) one flash forward for the one attention layer: none in
+    a region's discovery, first run or rerun, which made it three a pass
+    while the block was one region."""
+    passes = traced_step["passes"][which]
+    assert passes > 0
+    assert traced_step["moved"][which]["attention.flash_total"] == passes
 
 
 def test_the_model_is_exported_and_trains_under_to_static():
