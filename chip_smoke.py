@@ -167,7 +167,7 @@ def phase_static_executor(paddle):
 
 def phase_train(paddle, seed, cache_events):
     import jax
-    from paddle_tpu.ops import autotune
+    from paddle_tpu.ops.pallas import flash_attention
     from paddle_tpu.profiler import metrics
     from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
@@ -225,19 +225,12 @@ def phase_train(paddle, seed, cache_events):
     check((took["flash"] > 0) != (took["xla"] > 0),
           f"the step's attention took both paths or none: {took}")
     path = "flash" if took["flash"] else "xla"
-    tuner = autotune.get_tuner()
     emit("attention_path", path=path, calls_traced=took,
          tpu_custom_calls_in_compiled_step=kernels,
          flash_kernels_if_flash=2 * cfg.num_layers,
-         autotune_decisions=tuner.decisions(),
-         autotune_times_seconds=tuner.last_times,
-         autotune_counters=autotune.counters(),
-         autotune_first_failure=tuner.first_failure,
-         autotune_cache_dir=autotune.default_cache_dir())
+         flash_tiles=flash_attention.tiles(SEQ, SEQ))
     check(kernels == (2 * cfg.num_layers if path == "flash" else 0),
           f"attention took the {path} path but the compiled step holds {kernels} kernels")
-    check(autotune.counters()["candidate_failures"] == 0,
-          f"an autotune candidate failed on the chip: {tuner.first_failure}")
     stats = jax.devices()[0].memory_stats()
     emit("memory", peak_bytes_in_use=stats["peak_bytes_in_use"],
          bytes_limit=stats.get("bytes_limit"),

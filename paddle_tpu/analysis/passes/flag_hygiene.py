@@ -40,8 +40,7 @@ INERT = [
     "FLAGS_allocator_strategy",              # jax owns device memory
     "FLAGS_use_standalone_executor",         # single executor path
     "FLAGS_deterministic",                   # XLA is deterministic by
-                                             # default; gates future
-                                             # nondeterministic autotune
+                                             # default; nothing to gate
     "FLAGS_cudnn_deterministic",             # cudnn parity alias of the
                                              # above; no cudnn here
     "FLAGS_log_level",                       # reference tracer-verbosity

@@ -14,7 +14,7 @@ _FLAGS: dict[str, Any] = {
     # numerical sanitizer (framework/details/nan_inf_utils_detail.cc parity)
     "FLAGS_check_nan_inf": False,
     # determinism (FLAGS_cudnn_deterministic parity): XLA is deterministic by
-    # default; this gates any nondeterministic autotune choices we add later.
+    # default, and no kernel's tiles are drawn by a clock
     "FLAGS_deterministic": True,
     "FLAGS_cudnn_deterministic": True,
     # eager-op log level (imperative/tracer verbosity)
@@ -42,10 +42,6 @@ _FLAGS: dict[str, Any] = {
     # step/h2d). The loader's exact-resume cursor only advances when a batch
     # is actually consumed, so checkpoint/resume stays exact.
     "FLAGS_input_prefetch": True,
-    # kernel tier (paddle_tpu/ops/autotune.py, docs/kernels.md): master
-    # switch for the Pallas block-size search; off-device runs never search
-    # regardless (each kernel's deterministic fallback tiles)
-    "FLAGS_autotune": True,
     # resilience subsystem (paddle_tpu/resilience, docs/resilience.md)
     # fault-injection spec, e.g. "fs.upload:0.3,collective.all_reduce:0.1"
     "FLAGS_fault_injection": "",

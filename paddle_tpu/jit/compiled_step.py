@@ -31,12 +31,6 @@ parameters placed by ``distributed.spec_layout.shard_params`` and batches by
 ``shard_batch`` carry ``NamedSharding``s, and jit propagates them through
 the whole fused program (GSPMD), folding the hand-wired MULTICHIP dp/ZeRO
 collectives into the compiled step.
-
-Autotuner interplay (PR 5): tuned block sizes resolve at *trace* time — the
-kernel seam calls ``ops.autotune.get_tuner().get(...)`` while jax traces
-``pure_fn``, and tracer operands fall through to the memoised winner (or the
-deterministic off-device fallback), so a warm cache means the compiled
-program bakes in the tuned tiles with zero in-trace searches.
 """
 from __future__ import annotations
 

@@ -72,23 +72,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# 512-blocks measured 2.7x faster than 128-blocks on v5e (0.66 vs 1.78
-# ms/iter fwd+bwd at b4/s1024/h16/d64): bigger MXU matmuls, fewer inner-loop
-# trips. Public entry points clamp to the sequence length, so short-seq
-# callers (BERT s=128) degrade gracefully to seq-sized blocks. These are the
-# deterministic fallbacks; on TPU the autotuner (ops/autotune.py) searches
-# the candidate grids below and caches the winner per signature.
-DEFAULT_BLOCK_Q = 512
-DEFAULT_BLOCK_K = 512
-
-# Fwd candidates: (block_q, block_k).
-_FWD_CANDIDATES = (
-    (512, 512), (256, 512), (512, 256), (256, 256), (1024, 512),
-)
-
-# Bwd candidates: (block_q, block_k) of the one-pass kernel, which tiles k
-# over the grid and loops q. The same grid until a measurement parts them.
-_BWD_CANDIDATES = _FWD_CANDIDATES
+# The kernels' tiles, set from device time on a v5e and never drawn by a clock
+# (docs/kernels.md, "Tiles", has the table): 512 x 512 in both passes, and
+# query blocks of 1024 from LONG_SEQ_Q positions on, where the masked work a
+# larger query block adds on the diagonal, block_q / s_q of the pass, has
+# shrunk under what it saves.
+BLOCK = 512
+LONG_SEQ_Q = 8192
 
 # What a group's q, dO (double-buffered), dQ block and float32 dQ scratch may
 # take of a v5e's 128 MiB of VMEM before the query range is cut into spans;
@@ -467,99 +457,15 @@ def _from_bh(x, b, h):
     return jnp.swapaxes(x.reshape(b, h, s, d), 1, 2)
 
 
-def _synth_bh(shapes, dtypes):
-    """Concrete probe operands for a tuning run (fixed seed: the timings are
-    value-independent, the arrays just have to exist on device)."""
-    import numpy as np
-    rng = np.random.default_rng(0)
-    out = []
-    for shape, dtype in zip(shapes, dtypes):
-        if jnp.issubdtype(jnp.dtype(dtype), jnp.inexact):
-            out.append(jnp.asarray(
-                rng.standard_normal(shape, dtype=np.float32)).astype(dtype))
-        else:
-            out.append(jnp.zeros(shape, dtype))
-    return out
-
-
-def _group_tag(group, d, d_v):
-    """A signature's suffix for grouped-query shapes and for value heads of
-    another size than the keys'; none for plain multi-head attention, whose
-    cached configurations stay valid."""
-    return ("" if group == 1 else "|g%d" % group) + (
-        "" if d_v == d else "|v%d" % d_v)
-
-
-def _tuned_fwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1,
-                      d_v=None):
-    """(block_q, block_k) for the forward kernel: deterministic defaults
-    under interpret/CPU, autotuned (and cached) on TPU."""
-    fallback = (_clamp(DEFAULT_BLOCK_Q, s_q), _clamp(DEFAULT_BLOCK_K, s_k))
-    if interp:
-        return fallback
-    d_v = d if d_v is None else d_v
-    from ..autotune import get_tuner, shape_bucket, short_dtype, \
-        source_version
-    cands = list(dict.fromkeys(
-        (_clamp(bq, s_q), _clamp(bk, s_k)) for bq, bk in _FWD_CANDIDATES))
-    if len(cands) == 1:
-        return cands[0]
-    sig = "fwd|bh%d|s%dx%d|d%d|%s|c%d" % (
-        shape_bucket((bh,))[0], s_q, s_k, d, short_dtype(dtype), int(causal)
-    ) + _group_tag(group, d, d_v)
-
-    def build(cand):
-        return functools.partial(
-            _flash_fwd_bh, causal=causal, scale=1.0,
-            block_q=cand[0], block_k=cand[1], interpret=False)
-
-    def make_args():
-        return _synth_bh([(bh, s_q, d), (bh // group, s_k, d),
-                          (bh // group, s_k, d_v)], [dtype] * 3)
-
-    return get_tuner().get(
-        "flash_attention", sig, candidates=cands, build=build,
-        make_args=make_args, fallback=fallback,
-        version=source_version(__name__))
-
-
-def _tuned_bwd_blocks(bh, s_q, s_k, d, dtype, causal, interp, group=1,
-                      d_v=None):
-    """(block_q, block_k) for the one-pass backward kernel: the forward's
-    deterministic defaults under interpret/CPU (whatever the dtype: the
-    kernel's VMEM limit follows its shapes), autotuned (and cached) on TPU."""
-    def clamp2(c):
-        return (_clamp(c[0], s_q), _clamp(c[1], s_k))
-    fallback = clamp2((DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K))
-    if interp:
-        return fallback
-    d_v = d if d_v is None else d_v
-    from ..autotune import get_tuner, shape_bucket, short_dtype, \
-        source_version
-    cands = list(dict.fromkeys(clamp2(c) for c in _BWD_CANDIDATES))
-    if len(cands) == 1:
-        return cands[0]
-    sig = "bwd|bh%d|s%dx%d|d%d|%s|c%d" % (
-        shape_bucket((bh,))[0], s_q, s_k, d, short_dtype(dtype), int(causal)
-    ) + _group_tag(group, d, d_v)
-
-    def build(cand):
-        return functools.partial(
-            _flash_bwd_bh, causal=causal, scale=1.0,
-            block_q=cand[0], block_k=cand[1], interpret=False)
-
-    def make_args():
-        args = _synth_bh(
-            [(bh, s_q, d), (bh // group, s_k, d), (bh // group, s_k, d_v),
-             (bh, s_q, d_v)], [dtype] * 4)
-        lse = jnp.zeros((bh, s_q), jnp.float32)
-        do = _synth_bh([(bh, s_q, d_v)], [dtype])[0]
-        return args + [lse, do]
-
-    return get_tuner().get(
-        "flash_attention", sig, candidates=cands, build=build,
-        make_args=make_args, fallback=fallback,
-        version=source_version(__name__))
+def tiles(s_q, s_k):
+    """(block_q, block_k) of the forward kernel, which tiles q over the grid
+    and loops the key tiles, and of the one-pass backward, which tiles k over
+    the grid and loops the query blocks: a rule of shapes, the same on every
+    platform and in every process (docs/kernels.md, "Tiles"), clamped to a
+    divisor of the sequence, so short-seq callers (BERT s=128) get seq-sized
+    blocks."""
+    block_q = 2 * BLOCK if s_q >= LONG_SEQ_Q else BLOCK
+    return _clamp(block_q, s_q), _clamp(BLOCK, s_k)
 
 
 def flash_attention(q, k, v, causal=False, scale=1.0,
@@ -569,8 +475,8 @@ def flash_attention(q, k, v, causal=False, scale=1.0,
     through jax.custom_vjp). interpret=None resolves per call from placement
     (_interpret); pass an explicit bool when the caller already resolved it
     (attention.py bakes it through the custom_vjp static args). block_q /
-    block_k default to the tuned (or fallback) configuration; pass explicit
-    values to pin them."""
+    block_k default to the rule's (`tiles`); pass explicit values to pin
+    them."""
     out, _ = flash_attention_fwd(q, k, v, causal, scale, block_q, block_k,
                                  interpret)
     return out
@@ -583,14 +489,10 @@ def flash_attention_fwd(q, k, v, causal=False, scale=1.0,
     b, s, h, d = q.shape
     s_k = k.shape[1]
     interp = _interpret(q) if interpret is None else interpret
-    if block_q is None and block_k is None:
-        bq, bk = _tuned_fwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp,
-                                   group=h // k.shape[2], d_v=v.shape[3])
-    else:
-        bq = _clamp(block_q or DEFAULT_BLOCK_Q, s)
-        bk = _clamp(block_k or DEFAULT_BLOCK_K, s_k)
+    bq, bk = tiles(s, s_k)
     out, lse = _flash_fwd_bh(_to_bh(q), _to_bh(k), _to_bh(v), causal, scale,
-                             bq, bk, interp)
+                             _clamp(block_q or bq, s), _clamp(block_k or bk, s_k),
+                             interp)
     return _from_bh(out, b, h), lse.reshape(b, h, s)
 
 
@@ -598,20 +500,15 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=1.0,
                         block_q=None, block_k=None, interpret=None):
     """FlashAttention-2 backward in one pass: dq (B, S, H, D) and dk, dv in
     k's and v's shape and dtype. With no explicit blocks the kernel's
-    (block_q, block_k) is the tuned (or fallback) pair; explicit values pin
-    it."""
+    (block_q, block_k) is the rule's (`tiles`); explicit values pin it."""
     b, s, h, d = q.shape
     s_k = k.shape[1]
     interp = _interpret(q) if interpret is None else interpret
-    if block_q is None and block_k is None:
-        bq, bk = _tuned_bwd_blocks(b * h, s, s_k, d, q.dtype, causal, interp,
-                                   group=h // k.shape[2], d_v=v.shape[3])
-    else:
-        bq, bk = block_q or DEFAULT_BLOCK_Q, block_k or DEFAULT_BLOCK_K
+    bq, bk = tiles(s, s_k)
     dq, dk, dv = _flash_bwd_bh(
         _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(out),
         lse.reshape(b * h, s), _to_bh(do), causal, scale,
-        _clamp(bq, s), _clamp(bk, s_k), interp)
+        _clamp(block_q or bq, s), _clamp(block_k or bk, s_k), interp)
     h_kv = k.shape[2]
     return (_from_bh(dq, b, h), _from_bh(dk, b, h_kv), _from_bh(dv, b, h_kv))
 
@@ -628,7 +525,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=1.0,
 # with none is never multiplied, a tile that is whole runs the body with no
 # select. The statistics, L and every gradient are over the set. A query
 # whose set is empty gets no defined output. Tiles are `block` x `block`
-# (the published chunk sizes, 512) and not searched.
+# (the published chunk sizes, 512).
 
 SET_BLOCK = 512
 

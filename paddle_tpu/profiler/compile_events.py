@@ -3,11 +3,11 @@ request by phase, always on.
 
 A *set-up span* (:func:`setup_span`) is opened round work a process pays
 before its steady state: `to_static.discover`, `to_static.probe`,
-`to_static.compile` (jit/to_static.py), `autotune.search` (ops/autotune.py).
-It is a `jax.profiler.TraceAnnotation`, so a profiler trace that covers
-set-up shows it on the device's clock, and, always, two `perf_counter` reads
-that add its wall seconds to the registry counter ``<name>_sec`` and keep one
-record on an in-memory timeline (:func:`setup_timeline`; `TIMELINE_BOUND`
+`to_static.compile` (jit/to_static.py). It is a
+`jax.profiler.TraceAnnotation`, so a profiler trace that covers set-up shows
+it on the device's clock, and, always, two `perf_counter` reads that add its
+wall seconds to the registry counter ``<name>_sec`` and keep one record on
+an in-memory timeline (:func:`setup_timeline`; `TIMELINE_BOUND`
 records, then ``runtime.setup_records_dropped_total``). The open spans of a
 thread are a stack: a record names its parent, and a phase's self time is
 its own less its children's. `runtime.import` is a record without an
@@ -15,8 +15,8 @@ annotation (:func:`record_import`, from `paddle_tpu/__init__.py`).
 
 The `jax.monitoring` listeners (:func:`listen`, at the package's import)
 count every compile request of the process under the label ``phase``: the
-innermost open set-up span of the thread (`discover`, `probe`, `compile`,
-`autotune`), else `eager` where the package's own code asked (layer
+innermost open set-up span of the thread (`discover`, `probe`,
+`compile`), else `eager` where the package's own code asked (layer
 constructors, initialisers, `set_state_dict`, the optimizer's first state),
 else `user` (a `jax.jit` of the caller's own, with no frame of the package
 under it):
@@ -61,7 +61,7 @@ import jax.monitoring
 from . import metrics as _metrics
 
 __all__ = ["setup_span", "compile_span", "setup_timeline", "timed_ops",
-           "on_thread", "listen", "record_import", "OP_TIMER"]
+           "listen", "record_import", "OP_TIMER"]
 
 _TRACE = "/jax/core/compile/jaxpr_trace_duration"
 _LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
@@ -76,7 +76,7 @@ TIMELINE_BOUND = 4096   # records kept; later ones are counted, not kept
 NAMES_BOUND = 16        # missed programs, and discovery's ops, a record
 
 _PHASES = {"to_static.discover": "discover", "to_static.probe": "probe",
-           "to_static.compile": "compile", "autotune.search": "autotune"}
+           "to_static.compile": "compile"}
 _PACKAGE = os.path.dirname(os.path.dirname(os.path.abspath(__file__))) + os.sep
 
 # core/dispatch.apply and core/autograd.backward check this slot an op; it
@@ -182,18 +182,6 @@ def compile_span(name, watch, **attrs):
             if watching["trace_sec"]:
                 _metrics.get_registry().inc_counter("to_static.trace_sec",
                                                     watching["trace_sec"])
-
-
-@contextlib.contextmanager
-def on_thread(record):
-    """`record`, a span open on another thread, as this thread's innermost:
-    the autotuner's search runs on a thread of its own."""
-    stack = _stack()
-    stack.append(record)
-    try:
-        yield
-    finally:
-        stack.pop()
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,7 @@ new label combinations fold into one ``{overflow="true"}`` series and the
 cardinality bug degrades gracefully instead of eating the heap.
 
 The exporter writes per-rank snapshots into ``PADDLE_TPU_ARTIFACTS_DIR``
-(same directory as flight-recorder dumps) with the autotune cache's
+(same directory as flight-recorder dumps) with FileStore's
 tmp+``os.replace`` discipline, so a crash mid-export can never leave a torn
 file: ``metrics_rank<N>.prom`` (Prometheus text, node_exporter-style
 textfile collector format) and ``metrics_rank<N>.jsonl`` (recent snapshot
@@ -147,7 +147,7 @@ class MetricsRegistry:
     """Process-wide, thread-safe, always-on metric store.
 
     Independent of profiler enablement by design: ``record_counter`` (and
-    through it every serving / integrity / autotune gauge) lands here
+    through it every serving / integrity gauge) lands here
     whether or not anyone is tracing.
     """
 
@@ -374,7 +374,7 @@ def _prom_val(v):
 
 
 def _atomic_write(path, text):
-    """tmp + os.replace, the autotune-cache discipline: readers only ever
+    """tmp + os.replace, FileStore.put's discipline: readers only ever
     see a complete file. Carries the ``fs.write`` chaos site."""
     from ..resilience.faults import maybe_inject
     d = os.path.dirname(path)

@@ -1,8 +1,11 @@
 """Which kernel an op runs is a rule of code, shapes and platform
 (docs/kernels.md, "Which kernel runs"): attention chooses by
-`ops.attention.takes_flash`, the fused ops run their custom-vjp path, and
-nothing between an op's entry point and `dispatch.apply` times anything.
+`ops.attention.takes_flash`, the flash pair's tiles by
+`flash_attention.tiles` ("Tiles"), the fused ops run their custom-vjp path,
+and nothing between an op's entry point and `dispatch.apply` times anything.
 """
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,9 +15,10 @@ import paddle_tpu as paddle
 from paddle_tpu import nn
 from paddle_tpu.core.dispatch import unwrap
 from paddle_tpu.core.tensor import Tensor
-from paddle_tpu.ops import (attention, autotune, fused_conv_bn, fused_ffn,
+from paddle_tpu.ops import (attention, fused_conv_bn, fused_ffn,
                             fused_residual_ln)
-from paddle_tpu.profiler import metrics
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.profiler import metrics, setup_timeline
 
 K = attention.FLASH_MIN_SEQ_K
 GIB = 2 ** 30
@@ -117,7 +121,102 @@ def test_use_pallas_true_refuses_a_mask_and_dropout():
 
 
 # ---------------------------------------------------------------------------
-# the fused ops run what their names say, and ask no tuner
+# the flash pair's tiles: `flash_attention.tiles`, a rule of shapes
+
+# (case, query shape, key shape, value shape, dtype, (block_q, block_k))
+TILES = [
+    ("LFM2's heads at 4096", (2, 4096, 32, 64), (2, 4096, 8, 64), (2, 4096, 8, 64),
+     jnp.bfloat16, (512, 512)),
+    ("Kimi Linear's latent layer at 4096", (2, 4096, 32, 192), (2, 4096, 32, 192),
+     (2, 4096, 32, 128), jnp.bfloat16, (512, 512)),
+    ("DeepSeek-V2-Lite's heads at 8192", (1, 8192, 16, 192), (1, 8192, 16, 192),
+     (1, 8192, 16, 128), jnp.bfloat16, (1024, 512)),
+    ("GPT's heads at 2048", (2, 2048, 16, 128), (2, 2048, 16, 128), (2, 2048, 16, 128),
+     jnp.bfloat16, (512, 512)),
+    ("a sequence shorter than a tile", (1, 256, 2, 64), (1, 256, 1, 64), (1, 256, 1, 64),
+     jnp.bfloat16, (256, 256)),
+    ("BERT's 128 positions", (8, 128, 12, 64), (8, 128, 12, 64), (8, 128, 12, 64),
+     jnp.float32, (128, 128)),
+    ("a length 512 does not divide", (1, 768, 2, 64), (1, 768, 2, 64), (1, 768, 2, 64),
+     jnp.bfloat16, (256, 256)),
+    ("a long length 1024 does not divide", (1, 8704, 2, 64), (1, 8704, 2, 64),
+     (1, 8704, 2, 64), jnp.bfloat16, (512, 512)),
+    ("float32", (1, 2048, 4, 128), (1, 2048, 4, 128), (1, 2048, 4, 128),
+     jnp.float32, (512, 512)),
+    ("16384 positions, the backward in spans", (1, 16384, 8, 64), (1, 16384, 2, 64),
+     (1, 16384, 2, 64), jnp.bfloat16, (1024, 512)),
+    ("32768 positions, the backward in spans", (1, 32768, 2, 128), (1, 32768, 2, 128),
+     (1, 32768, 2, 128), jnp.bfloat16, (1024, 512)),
+]
+
+
+def staged_tiles(backward, q_shape, k_shape, v_shape, dtype, monkeypatch):
+    """The (block_q, block_k) the public entry point hands its kernel for
+    operands of these shapes: staged, never run."""
+    seen = []
+    kernel = "_flash_bwd_bh" if backward else "_flash_fwd_bh"
+
+    def spy(*args, **kwargs):
+        seen.append(args[-3:-1])
+        raise StopIteration
+    monkeypatch.setattr(fa, kernel, spy)
+    q, k, v = (jax.ShapeDtypeStruct(s, dtype) for s in (q_shape, k_shape, v_shape))
+    with pytest.raises(StopIteration):
+        if backward:
+            out = jax.ShapeDtypeStruct(q_shape[:3] + v_shape[3:], dtype)
+            lse = jax.ShapeDtypeStruct((q_shape[0], q_shape[2], q_shape[1]), jnp.float32)
+            jax.eval_shape(lambda *a: fa.flash_attention_bwd(*a, causal=True),
+                           q, k, v, out, lse, out)
+        else:
+            jax.eval_shape(lambda *a: fa.flash_attention_fwd(*a, causal=True), q, k, v)
+    (blocks,) = seen
+    return blocks
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+@pytest.mark.parametrize("case", TILES, ids=[c[0] for c in TILES])
+def test_the_tiles_are_a_rule_of_shapes(case, backward, monkeypatch):
+    _, q_shape, k_shape, v_shape, dtype, want = case
+    assert fa.tiles(q_shape[1], k_shape[1]) == want
+    # and what the kernel is handed is the rule's
+    assert staged_tiles(backward, q_shape, k_shape, v_shape, dtype, monkeypatch) == want
+    assert q_shape[1] % want[0] == 0 and k_shape[1] % want[1] == 0
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+@pytest.mark.parametrize("case", TILES[:3], ids=[c[0] for c in TILES[:3]])
+def test_the_tiles_are_the_same_on_a_tpu_as_on_the_cpu(case, backward, monkeypatch):
+    _, q_shape, k_shape, v_shape, dtype, _ = case
+    got = {}
+    for platform in ("cpu", "tpu"):
+        with monkeypatch.context() as patch:
+            patch.setattr(attention, "_platform", lambda: platform)
+            patch.setattr(jax, "default_backend", lambda: platform)
+            assert fa._interpret() == (platform == "cpu")
+            got[platform] = (fa.tiles(q_shape[1], k_shape[1]), staged_tiles(
+                backward, q_shape, k_shape, v_shape, dtype, patch))
+    assert got["cpu"] == got["tpu"]
+
+
+def test_pinned_tiles_change_the_schedule_and_not_the_numbers():
+    rng = np.random.RandomState(0)
+    q, k, v = [jnp.asarray(rng.randn(1, 256, 2, 64).astype("float32")) for _ in range(3)]
+    out, lse = fa.flash_attention_fwd(q, k, v, causal=True, scale=0.125)
+    pinned_out, pinned_lse = fa.flash_attention_fwd(q, k, v, causal=True, scale=0.125,
+                                                    block_q=64, block_k=128)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(pinned_out), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(pinned_lse), rtol=2e-5, atol=2e-5)
+    do = jnp.asarray(rng.randn(*out.shape).astype("float32"))
+    ruled = fa.flash_attention_bwd(q, k, v, out, lse, do, causal=True, scale=0.125)
+    pinned = fa.flash_attention_bwd(q, k, v, out, lse, do, causal=True, scale=0.125,
+                                    block_q=128, block_k=64)
+    for a, b in zip(ruled, pinned):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the fused ops and the flash pair run what their names say, on the thread
+# that called them
 
 class FFN(nn.Layer):
     diff = fused_ffn._fused_ffn_diff
@@ -162,6 +261,21 @@ class ConvBN(nn.Layer):
             padding=1)
 
 
+class Flash(nn.Layer):
+    diff = attention._flash_attention_diff
+    x_shape = (1, 256, 128)
+
+    def __init__(self):
+        super().__init__()
+        self.qkv = nn.Linear(128, 3 * 128)
+
+    def forward(self, x):
+        q, k, v = (t.reshape([1, 256, 2, 64])
+                   for t in paddle.split(self.qkv(x), 3, axis=-1))
+        return attention.scaled_dot_product_attention(
+            q, k, v, is_causal=True, use_pallas=True)
+
+
 def eager(layer, x):
     layer(x).sum().backward()
 
@@ -187,26 +301,12 @@ def recomputed(layer, x):
     recompute(layer, x).sum().backward()
 
 
-@pytest.fixture
-def tuner_that_may_not_time(tmp_path):
-    """A tuner on a placement that could search, whose every measurement
-    fails: a choice that timed its candidates would raise AutotuneError."""
-    def measure(fn, args):
-        raise AssertionError("a kernel choice timed a candidate")
-    old = autotune.set_tuner(autotune.Autotuner(
-        cache_dir=str(tmp_path / "autotune"), searchable=lambda: True,
-        measure_fn=measure))
-    autotune.reset_counters()
-    yield autotune.get_tuner()
-    autotune.set_tuner(old)
-
-
 @pytest.mark.parametrize("mode", [eager, to_static_step, recomputed],
                          ids=lambda f: f.__name__)
-@pytest.mark.parametrize("make", [FFN, ResidualLN, ConvBN],
+@pytest.mark.parametrize("make", [FFN, ResidualLN, ConvBN, Flash],
                          ids=lambda c: c.__name__)
 def test_a_fused_op_runs_its_custom_vjp_and_searches_nothing(
-        make, mode, tuner_that_may_not_time, monkeypatch, tmp_path):
+        make, mode, monkeypatch):
     diff, backward_rule_calls = make.diff, []
     rule = diff.bwd
 
@@ -214,6 +314,11 @@ def test_a_fused_op_runs_its_custom_vjp_and_searches_nothing(
         backward_rule_calls.append(1)
         return rule(*args)
     monkeypatch.setattr(diff, "bwd", spied)
+
+    threads = []
+    monkeypatch.setattr(threading.Thread, "start",
+                        lambda thread: threads.append(thread.name))
+    at = len(setup_timeline())
 
     paddle.seed(0)
     layer = make()
@@ -226,29 +331,28 @@ def test_a_fused_op_runs_its_custom_vjp_and_searches_nothing(
         del backward_rule_calls[:]
         staged()
     assert backward_rule_calls, "the op's own backward rule never ran"
-    assert autotune.counters()["searches"] == 0
-    assert autotune.counters()["candidate_failures"] == 0
-    assert tuner_that_may_not_time.decisions() == {}
-    assert not (tmp_path / "autotune").exists()
+    assert threads == []
+    # set-up spans of the step itself and none inside them
+    records = setup_timeline()[at:]
+    assert [r["parent"] for r in records] == [None] * len(records)
+    assert {r["name"] for r in records} <= {
+        "to_static.discover", "to_static.probe", "to_static.compile"}
 
 
 # ---------------------------------------------------------------------------
 # one program text for one code, whatever a clock would say
 
-def lowered_gpt_step(cache_dir, fastest):
-    """The lowered text of a small GPT train step built under a fresh tuner
-    on a placement that could search, whose clock prefers the `fastest`-th
-    candidate of whatever it is asked to time."""
+def lowered_gpt_step(monkeypatch, clock_rate):
+    """The lowered text of a small GPT train step built in a process whose
+    host clock runs `clock_rate` times as fast."""
+    import time
+
     from paddle_tpu.jit.to_static import _flatten_tensors
     from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
-    def measure(fn, args):
-        measure.calls += 1
-        return 1.0 if measure.calls % 2 == fastest else 2.0
-    measure.calls = 0
-    old = autotune.set_tuner(autotune.Autotuner(
-        cache_dir=str(cache_dir), searchable=lambda: True, measure_fn=measure))
-    try:
+    real = time.perf_counter
+    with monkeypatch.context() as patch:
+        patch.setattr(time, "perf_counter", lambda: real() * clock_rate)
         paddle.seed(0)
         model = GPTForCausalLM(GPTConfig(
             vocab_size=128, hidden_size=32, num_layers=2, num_heads=2,
@@ -269,18 +373,21 @@ def lowered_gpt_step(cache_dir, fastest):
         step(x, y)
         (prog,) = step.programs.values()
         step._build(prog, (x, y), {})
-        text = jax.jit(prog.pure_fn).lower(
+        return jax.jit(prog.pure_fn).lower(
             tuple(t._val for t in prog.mutated),
             tuple(t._val for t in prog.ro),
             tuple(t._val for t in _flatten_tensors(((x, y), {}), []))).as_text()
-        return text, measure.calls
-    finally:
-        autotune.set_tuner(old)
 
 
-def test_a_gpt_step_lowers_to_one_text_under_two_clocks(tmp_path):
-    first, timed_first = lowered_gpt_step(tmp_path / "a", fastest=0)
-    second, timed_second = lowered_gpt_step(tmp_path / "b", fastest=1)
-    assert timed_first == timed_second == 0
-    assert first == second
-    assert not (tmp_path / "a").exists() and not (tmp_path / "b").exists()
+def test_a_gpt_step_lowers_to_one_text_under_two_clocks(monkeypatch):
+    assert lowered_gpt_step(monkeypatch, 1.0) == lowered_gpt_step(monkeypatch, 1000.0)
+
+
+def test_the_registry_holds_no_search_and_no_phase_of_one():
+    # after everything above ran in this process: eager calls, to_static
+    # steps and rematerialised steps, the flash pair among them
+    counters = metrics.get_registry().snapshot()["counters"]
+    assert not [name for name in counters if name.startswith("autotune.")]
+    phases = {name.partition('phase="')[2].rstrip('"}') for name in counters
+              if name.startswith("compile.requests_total{")}
+    assert phases and phases <= {"discover", "probe", "compile", "eager", "user"}

@@ -87,11 +87,7 @@ def test_the_path_rule_and_the_tile_search_take_both_sizes():
     assert not attention.takes_flash(q, k, *args, (2, 4096, 32, 80))
     assert not attention.takes_flash(q, k, jnp.bfloat16, False, 0.0, "cpu", v)
     assert fa.supports(q, k, v) and fa.supports(q, k)
-    # a signature of its own in the tuner's cache; none where the sizes agree
-    assert fa._group_tag(1, 192, 128) == "|v128" and fa._group_tag(4, 64, 64) == "|g4"
-    assert fa._group_tag(1, 128, 128) == ""
-    assert fa._tuned_fwd_blocks(64, 4096, 4096, 192, jnp.bfloat16, True, True,
-                                d_v=128) == (512, 512)
+    assert fa.tiles(4096, 4096) == (512, 512)
 
 
 # the parent's (commit f494db6) forward and backward kernels traced at

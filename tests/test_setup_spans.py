@@ -1,7 +1,7 @@
 """Set-up from inside the program, all on the CPU: the set-up spans' records
 and `<name>_sec` counters, every compile request counted by phase, the
-programs a cache miss compiled, the discovery pass by op, the timeline's
-bound, and the autotuner's counters in the registry.
+programs a cache miss compiled, the discovery pass by op, and the timeline's
+bound.
 """
 import os
 
@@ -12,7 +12,6 @@ import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
-from paddle_tpu.ops import autotune
 from paddle_tpu.profiler import compile_events as ce
 from paddle_tpu.profiler import metrics, setup_timeline
 
@@ -122,7 +121,7 @@ def test_the_import_is_a_record_and_a_counter():
 def test_a_span_inside_another_names_its_parent():
     at = len(setup_timeline())
     with ce.setup_span("to_static.discover", fn="outer"):
-        with ce.setup_span("autotune.search", op="k") as inner:
+        with ce.setup_span("to_static.probe", op="k") as inner:
             inner["attrs"]["candidates"] = 3
     outer, inner = setup_timeline()[at:]
     assert inner["parent"] == at and outer["parent"] is None
@@ -237,30 +236,6 @@ def test_a_record_keeps_the_longest_missed_programs():
         == [float(i) for i in range(4, ce.NAMES_BOUND + 4)]
 
 
-def test_the_searchs_thread_counts_under_the_search(tmp_path):
-    build, v = fresh_jit()
-    def measure(fn, args):
-        fn(*args).block_until_ready()
-        return 0.0
-
-    tuner = autotune.Autotuner(cache_dir=str(tmp_path), searchable=lambda: True,
-                               measure_fn=measure)
-    at, before = len(setup_timeline()), counters()
-    with ce.setup_span("to_static.discover", fn="t"):
-        tuner.get("op", "sig", candidates=[1, 2], build=lambda c: build(),
-                  make_args=lambda: (v,), fallback=1)
-    discover, search = setup_timeline()[at:]
-    assert search["name"] == "autotune.search" and search["parent"] == at
-    assert search["attrs"] == {"op": "op", "signature": "sig",
-                               "candidates": 2, "failed": 0}
-    got = moved(before)
-    assert got['compile.requests_total{phase="autotune"}'] == search["requests"] == 2
-    assert got["autotune.search_sec"] == pytest.approx(
-        search["end"] - search["start"], abs=1e-9)
-    assert got["autotune.searches_total"] == 1
-    assert discover["requests"] == 0
-
-
 # ---------------------------------------------------------------------------
 # the discovery pass by op
 
@@ -329,30 +304,3 @@ def test_the_timeline_is_bounded_and_says_what_it_dropped(monkeypatch):
     got = moved(before)
     assert got["runtime.setup_records_dropped_total"] == 2
     assert got["to_static.probe_sec"] > 0   # the seconds are counted all the same
-
-
-# ---------------------------------------------------------------------------
-# the autotuner's counters
-
-@pytest.mark.parametrize("name", ["searches", "disk_hits", "mem_hits", "fallbacks",
-                                  "candidate_failures", "cache_errors"])
-def test_autotune_counters_are_a_view_over_the_registry(name):
-    autotune.reset_counters()
-    assert autotune.counters()[name] == 0
-    before = REG.counter_value(f"autotune.{name}_total")
-    autotune._count(name)
-    assert autotune.counters()[name] == 1
-    assert REG.counter_value(f"autotune.{name}_total") == before + 1
-    assert not hasattr(autotune, "_COUNTERS")
-
-
-def test_autotune_counters_count_from_zero_after_the_registry_is_emptied(monkeypatch):
-    reg = metrics.MetricsRegistry()
-    reg.inc_counter("autotune.fallbacks_total", 5)
-    with monkeypatch.context() as patch:
-        patch.setattr(metrics, "get_registry", lambda: reg)
-        autotune.reset_counters()
-        reg.reset()
-        reg.inc_counter("autotune.fallbacks_total", 2)
-        assert autotune.counters()["fallbacks"] == 2
-    autotune.reset_counters()

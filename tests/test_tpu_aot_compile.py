@@ -3,11 +3,11 @@ widths the models use — for a chip that is described, not attached.
 
 Interpret mode (every other flash test on CPU) cannot see what Mosaic
 refuses: a block that does not fit VMEM, a slice off the tiling, compiler
-params that no longer exist. These compiles can, and cost no chip time:
-each default block configuration and each autotune candidate of
-ops/pallas/flash_attention.py (the forward kernel, the one-pass backward
-kernel) is lowered with interpret=False for one v5e chip and must contain
-the kernel (`tpu_custom_call`).
+params that no longer exist. These compiles can, and cost no chip time: the
+forward kernel and the one-pass backward kernel of
+ops/pallas/flash_attention.py, at the tiles its rule gives (`tiles`: what the
+chip runs), are lowered with interpret=False for one v5e chip and must
+contain the kernel (`tpu_custom_call`).
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process at a time may load libtpu, so nothing here may touch it at
@@ -17,11 +17,22 @@ import os
 
 import pytest
 
+# (query rows b x h, key/value rows, positions, query/key size, value size, dtype)
 SHAPES = [
-    pytest.param(32, 1024, 128, id="gpt1p3b-bh32-s1024-d128"),
-    pytest.param(64, 1024, 64, id="gpt-medium-bh64-s1024-d64"),
-    pytest.param(32, 2048, 128, id="bh32-s2048-d128"),
-    pytest.param(64, 2048, 64, id="bh64-s2048-d64"),
+    pytest.param(32, 32, 1024, 128, 128, "bfloat16", id="gpt1p3b-bh32-s1024-d128"),
+    pytest.param(64, 64, 1024, 64, 64, "bfloat16", id="gpt-medium-bh64-s1024-d64"),
+    pytest.param(32, 32, 2048, 128, 128, "bfloat16", id="bh32-s2048-d128"),
+    pytest.param(64, 64, 2048, 64, 64, "bfloat16", id="bh64-s2048-d64"),
+    pytest.param(64, 16, 4096, 64, 64, "bfloat16", id="lfm2-group4-s4096-d64"),
+    pytest.param(64, 64, 4096, 192, 128, "bfloat16", id="kimi-linear-s4096-d192-dv128"),
+    pytest.param(16, 16, 8192, 192, 128, "bfloat16", id="deepseek-v2-lite-s8192-d192-dv128"),
+    pytest.param(32, 32, 2048, 192, 128, "bfloat16", id="latent-s2048-d192-dv128"),
+    pytest.param(32, 4, 8192, 128, 128, "bfloat16", id="group8-s8192-d128"),
+    pytest.param(8, 2, 16384, 64, 64, "bfloat16", id="group4-s16384-d64-in-spans"),
+    pytest.param(2, 2, 32768, 128, 128, "bfloat16", id="s32768-d128-in-spans"),
+    pytest.param(8, 8, 768, 64, 64, "bfloat16", id="s768-a-length-512-does-not-divide"),
+    pytest.param(4, 2, 256, 64, 64, "bfloat16", id="s256-shorter-than-a-tile"),
+    pytest.param(8, 8, 2048, 128, 128, "float32", id="float32-s2048-d128"),
 ]
 
 
@@ -57,23 +68,19 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile_fwd(one_chip, bh, s, d, blocks):
-    import jax.numpy as jnp
+def _compile_at_the_rules_tiles(one_chip, backward, bh, rows_kv, s, d, d_v, dtype):
     from paddle_tpu.ops.pallas import flash_attention as fa
-    x = _sds((bh, s, d), jnp.bfloat16, one_chip)
-    return fa._flash_fwd_bh.lower(
-        x, x, x, causal=True, scale=d ** -0.5, block_q=blocks[0],
-        block_k=blocks[1], interpret=False).compile()
-
-
-def _compile_bwd(one_chip, bh, s, d, blocks):
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas import flash_attention as fa
-    x = _sds((bh, s, d), jnp.bfloat16, one_chip)
-    lse = _sds((bh, s), jnp.float32, one_chip)
-    return fa._flash_bwd_bh.lower(
-        x, x, x, x, lse, x, causal=True, scale=d ** -0.5,
-        block_q=blocks[0], block_k=blocks[1], interpret=False).compile()
+    q = _sds((bh, s, d), dtype, one_chip)
+    k = _sds((rows_kv, s, d), dtype, one_chip)
+    v = _sds((rows_kv, s, d_v), dtype, one_chip)
+    block_q, block_k = fa.tiles(s, s)
+    kw = dict(causal=True, scale=d ** -0.5, block_q=block_q, block_k=block_k,
+              interpret=False)
+    if not backward:
+        return fa._flash_fwd_bh.lower(q, k, v, **kw).compile()
+    o = _sds((bh, s, d_v), dtype, one_chip)
+    lse = _sds((bh, s), "float32", one_chip)
+    return fa._flash_bwd_bh.lower(q, k, v, o, lse, o, **kw).compile()
 
 
 def _assert_kernel(compiled, n_kernels):
@@ -102,36 +109,13 @@ def test_compiler_params_carry_dimension_semantics():
     assert fa._tpu_params(True, ("parallel", "parallel")) == {}
 
 
-@pytest.mark.parametrize("bh,s,d", SHAPES)
-def test_fwd_default_blocks_compile(one_chip, bh, s, d):
-    from paddle_tpu.ops.pallas import flash_attention as fa
-    blocks = (fa._clamp(fa.DEFAULT_BLOCK_Q, s), fa._clamp(fa.DEFAULT_BLOCK_K, s))
-    _assert_kernel(_compile_fwd(one_chip, bh, s, d, blocks), 1)
-
-
-@pytest.mark.parametrize("bh,s,d", SHAPES)
-def test_bwd_default_blocks_compile(one_chip, bh, s, d):
-    from paddle_tpu.ops.pallas import flash_attention as fa
-    blocks = (fa._clamp(fa.DEFAULT_BLOCK_Q, s), fa._clamp(fa.DEFAULT_BLOCK_K, s))
-    _assert_kernel(_compile_bwd(one_chip, bh, s, d, blocks), 1)
-
-
-@pytest.mark.parametrize("bh,s,d", SHAPES)
-def test_every_fwd_candidate_compiles(one_chip, bh, s, d):
-    """A candidate Mosaic refuses belongs out of _FWD_CANDIDATES, not in a
-    run-time skip: the search on the chip must find every one runnable."""
-    from paddle_tpu.ops.pallas import flash_attention as fa
-    for cand in fa._FWD_CANDIDATES:
-        blocks = (fa._clamp(cand[0], s), fa._clamp(cand[1], s))
-        _assert_kernel(_compile_fwd(one_chip, bh, s, d, blocks), 1)
-
-
-@pytest.mark.parametrize("bh,s,d", SHAPES)
-def test_every_bwd_candidate_compiles(one_chip, bh, s, d):
-    from paddle_tpu.ops.pallas import flash_attention as fa
-    for cand in fa._BWD_CANDIDATES:
-        blocks = tuple(fa._clamp(b, s) for b in cand)
-        _assert_kernel(_compile_bwd(one_chip, bh, s, d, blocks), 1)
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+@pytest.mark.parametrize("bh,rows_kv,s,d,d_v,dtype", SHAPES)
+def test_the_rules_tiles_compile(one_chip, bh, rows_kv, s, d, d_v, dtype, backward):
+    """What the chip runs at these shapes: a tile Mosaic refuses (VMEM, a
+    slice off the tiling) fails here, without a chip, and the rule changes."""
+    _assert_kernel(_compile_at_the_rules_tiles(
+        one_chip, backward, bh, rows_kv, s, d, d_v, dtype), 1)
 
 
 def test_public_vjp_pair_compiles_at_gpt1p3b_widths(one_chip):
@@ -221,23 +205,6 @@ def test_grouped_head_vjp_pair_compiles_at_lfm2_widths(one_chip):
         assert partial_or_wide not in entry, partial_or_wide
     assert [o.shape for o in compiled.out_info] == [
         (2, 4096, 32, 64), (2, 4096, 8, 64), (2, 4096, 8, 64)]
-
-
-@pytest.mark.parametrize("cand", range(5))
-def test_every_candidate_compiles_at_grouped_heads_and_4096(one_chip, cand):
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas import flash_attention as fa
-    q = _sds((64, 4096, 64), jnp.bfloat16, one_chip)
-    kv = _sds((16, 4096, 64), jnp.bfloat16, one_chip)
-    lse = _sds((64, 4096), jnp.float32, one_chip)
-    bq, bk = fa._FWD_CANDIDATES[cand]
-    _assert_kernel(fa._flash_fwd_bh.lower(
-        q, kv, kv, causal=True, scale=0.125, block_q=bq, block_k=bk,
-        interpret=False).compile(), 1)
-    bq, bk = fa._BWD_CANDIDATES[cand]
-    _assert_kernel(fa._flash_bwd_bh.lower(
-        q, kv, kv, q, lse, q, causal=True, scale=0.125, block_q=bq,
-        block_k=bk, interpret=False).compile(), 1)
 
 
 @pytest.mark.parametrize("bh,rows_kv,s,d", [
@@ -359,19 +326,6 @@ def test_two_head_sizes_vjp_pair_compiles_at_kimi_linear_widths(one_chip):
     _assert_kernel(compiled, 2)
     assert [o.shape for o in compiled.out_info] == [
         (2, 4096, 32, 192), (2, 4096, 32, 192), (2, 4096, 32, 128)]
-
-
-@pytest.mark.parametrize("cand", range(5))
-def test_every_candidate_compiles_at_two_head_sizes(one_chip, cand):
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas import flash_attention as fa
-    qk = _sds((64, 4096, 192), jnp.bfloat16, one_chip)
-    v = _sds((64, 4096, 128), jnp.bfloat16, one_chip)
-    lse = _sds((64, 4096), jnp.float32, one_chip)
-    bq, bk = fa._FWD_CANDIDATES[cand]
-    kw = dict(causal=True, scale=192 ** -0.5, block_q=bq, block_k=bk, interpret=False)
-    _assert_kernel(fa._flash_fwd_bh.lower(qk, qk, v, **kw).compile(), 1)
-    _assert_kernel(fa._flash_bwd_bh.lower(qk, qk, v, v, lse, v, **kw).compile(), 1)
 
 
 @pytest.mark.parametrize("k,n", [(2304, 1024), (1024, 2304)],

@@ -18,7 +18,7 @@ Names whose first argument is a bare variable cannot be extracted and are
 skipped; the convention is enforced where names are minted, i.e. at literal
 call sites. Pre-existing names that predate the convention are pinned in
 ``GRANDFATHERED`` (renaming them would break recorded artifacts and the
-integrity/autotune test assertions) — do not add new entries.
+integrity test assertions) — do not add new entries.
 
 Run directly or via tests/test_lints.py / tests/test_observability.py.
 """
@@ -37,7 +37,6 @@ SCAN = ["paddle_tpu", "bench.py"]
 # review.
 SUBSYSTEMS = [
     "attention",     # the path scaled_dot_product_attention took (ops/attention.py)
-    "autotune",      # kernel-tier block autotuning
     "campaign",      # chaos-campaign engine (resilience/campaign.py)
     "ckpt",          # zero-stall checkpointing (resilience/snapshot.py)
     "compile",       # every compile request of the process, by set-up phase
@@ -74,8 +73,8 @@ SUBSYSTEMS = [
 UNITS = ["bytes", "count", "ms", "per_sec", "ratio", "sec", "total", "us"]
 
 # Names minted before this convention existed. Renaming them would orphan
-# recorded BENCH/flight artifacts and break assertions in tests/test_autotune
-# and tests/test_integrity, so they are pinned, not fixed. FROZEN: new names
+# recorded BENCH/flight artifacts and break assertions in
+# tests/test_integrity, so they are pinned, not fixed. FROZEN: new names
 # must pass the pattern instead.
 GRANDFATHERED = [
     "straggler.rank{}",     # value is a ratio; name predates unit suffixes
