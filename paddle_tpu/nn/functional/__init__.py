@@ -28,7 +28,7 @@ def sequence_mask(lengths, maxlen=None, dtype="int64", name=None):
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None, key_set=None,
-                                 return_lse=False, scale=None):
+                                 return_lse=False, scale=None, window=None):
     """Fused attention entry point (reference: operators/fused/fused_attention).
 
     Shapes: (batch, seq, heads, head_dim) — paddle convention. Uses the Pallas
@@ -38,11 +38,14 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     the logsumexp of each query's scores (batch, heads, seq)). `scale`
     multiplies the scores before the softmax: head_dim ** -0.5 of the
     query/key heads by default (a model under YaRN passes its own).
+    `window` (with `is_causal`) keeps the last `window` causal keys of each
+    query, its own among them (sliding-window attention): on a TPU the flash
+    pair over its banded grid, elsewhere a masked square.
     """
     from ...ops.attention import scaled_dot_product_attention as sdpa
     return sdpa(query, key, value, attn_mask=attn_mask, dropout_p=dropout_p,
                 is_causal=is_causal, training=training, key_set=key_set,
-                return_lse=return_lse, scale=scale)
+                return_lse=return_lse, scale=scale, window=window)
 
 
 def sparse_attention_index(q_index, k_index, weights, topk, name=None):

@@ -115,13 +115,16 @@ def yarn_scales(rope_scaling):
 def _rope_tables(dim, seq, theta, position_offset, rope_scaling):
     """(cos, sin), each (seq, dim / 2) float32, of positions
     position_offset ... at theta^(-2n/dim), or under YaRN (`rope_scaling`) at
-    `yarn_frequencies` and times `yarn_scales`' first factor: what
-    `_rotate_pairs` reads. jax.numpy out."""
+    `yarn_frequencies` and times `yarn_scales`' first factor, or the
+    `attention_factor` the dict gives in its place: what `_rotate_pairs`
+    reads. jax.numpy out."""
     if rope_scaling is None:
         inv, table_scale = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim), 1.0
     else:
         inv = jnp.asarray(yarn_frequencies(dim, theta, rope_scaling))
-        table_scale = yarn_scales(rope_scaling)[0]
+        table_scale = rope_scaling.get("attention_factor")
+        if table_scale is None:
+            table_scale = yarn_scales(rope_scaling)[0]
     t = jnp.arange(position_offset, position_offset + seq, dtype=jnp.float32)
     angle = t[:, None] * inv[None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
@@ -146,7 +149,8 @@ def _rotate_pairs(v, cos, sin, interleaved):
 
 def rotary_position_embedding(q, k, theta=10000.0, position_offset=0,
                               name=None, position_ids=None, sections=None,
-                              rope_scaling=None, interleaved=False):
+                              rope_scaling=None, interleaved=False,
+                              rotary_dim=None):
     """Rotary positions (Su et al. 2021) on `q` and `k`, each (batch, seq,
     heads, head_dim); their head counts may differ (one key head under many
     query heads). Angles and the rotation are float32; the results keep
@@ -163,7 +167,13 @@ def rotary_position_embedding(q, k, theta=10000.0, position_offset=0,
     Frequencies. Pair n at position t turns by t * theta^(-2n/head_dim) by
     default; by YaRN's blend where `rope_scaling` is a dict of `type` "yarn"
     (`yarn_frequencies`; cos and sin then carry `yarn_scales`' first factor,
-    and the caller owes the softmax its second).
+    and the caller owes the softmax its second; a dict with an
+    `attention_factor` has cos and sin carry that, and the softmax is owed
+    nothing).
+
+    `rotary_dim` (a `partial_rotary_factor` times head_dim) turns entries
+    0 ... rotary_dim - 1 of each head, pairing and frequencies as above over
+    a head of that size, and passes the rest as they are.
 
     `position_ids` gives the positions instead: (batch, seq), or (streams,
     batch, seq) with `sections`, how many of the head_dim / 2 frequency pairs
@@ -176,6 +186,13 @@ def rotary_position_embedding(q, k, theta=10000.0, position_offset=0,
             raise ValueError(f"rope_scaling of type {kind!r}: only 'yarn' is computed")
 
     def prim(qv, kv, *pos):
+        if rotary_dim is not None and rotary_dim < qv.shape[-1]:
+            turned = turn_heads(qv[..., :rotary_dim], kv[..., :rotary_dim], *pos)
+            return tuple(jnp.concatenate([t, v[..., rotary_dim:]], axis=-1)
+                         for t, v in zip(turned, (qv, kv)))
+        return turn_heads(qv, kv, *pos)
+
+    def turn_heads(qv, kv, *pos):
         d, s = qv.shape[-1], qv.shape[1]
         if rope_scaling is not None or interleaved:
             if pos:

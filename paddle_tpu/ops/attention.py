@@ -32,7 +32,7 @@ XLA_SCORE_TENSORS = 4
 
 
 def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, dropout_key,
-                   return_lse=False):
+                   return_lse=False, window=None):
     # q,k,v: (B, S, H, D) paddle layout -> compute in (B, H, S, D)
     group = q.shape[2] // k.shape[2]
     if group > 1:
@@ -46,6 +46,9 @@ def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, dropout_key,
     if is_causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         causal = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        if window is not None:
+            # the band: the `window` keys up to and with the query's own
+            causal = causal & ~jnp.tril(causal, k=s_k - s_q - window)
         logits = jnp.where(causal, logits, -1e30)
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -66,7 +69,7 @@ def _xla_attention(q, k, v, mask, scale, is_causal, dropout_p, dropout_key,
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, use_pallas=None, scale=None,
-                                 key_set=None, return_lse=False):
+                                 key_set=None, return_lse=False, window=None):
     """(batch, seq, heads, head_dim) attention. `key` and `value` may hold
     fewer heads than `query`, a divisor of its count (grouped-query
     attention): query head h reads key/value head h // (heads / kv_heads).
@@ -84,6 +87,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     `return_lse` gives (out, lse): the logsumexp of each query's scaled scores
     over the keys it attends to, (batch, heads, seq) float32, no gradient:
     what forms the probabilities again (`F.sparse_attention_index_loss`).
+    `window` (with `is_causal`; no mask and no `key_set` beside it) keeps of
+    the causal keys the `window` last, the query's own among them: query t
+    reads the keys j with t - window < j <= t. The flash pair walks the band
+    alone (its banded grid: `attention.window_total` counts the calls that
+    took it); XLA's attention masks the square. A window at least as long as
+    the keys is plain causal attention.
     `use_pallas=None` lets `takes_flash` choose the path from the shapes;
     True or False is the caller's own choice (True with a mask or dropout
     raises). The path a call took is counted: `attention.flash_total`,
@@ -96,6 +105,15 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     head_dim = qv.shape[-1]
     if scale is None:
         scale = 1.0 / (head_dim ** 0.5)
+    if window is not None:
+        if not is_causal or attn_mask is not None or key_set is not None:
+            raise ValueError(
+                "window: a band under the causal diagonal; it takes is_causal=True "
+                "and neither attn_mask nor key_set")
+        if int(window) < 1:
+            raise ValueError(f"a window of {window} keys holds no key")
+        from .pallas.flash_attention import band
+        window = band(window, unwrap(key).shape[1])
     dropout_kd = None
     if dropout_p > 0.0 and training:
         from ..core.random import next_key_data
@@ -125,8 +143,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     if return_lse and use_pallas:
         raise ValueError("return_lse: the plain flash pair keeps its logsumexp")
     if use_pallas:
-        return apply(_flash_prim(qv, is_causal, scale), query, key, value,
-                     name="flash_attention")
+        if window is not None:
+            _metrics.get_registry().inc_counter("attention.window_total")
+        return apply(_flash_prim(qv, is_causal, scale, window), query, key,
+                     value, name="flash_attention")
 
     def prim(q, k, v, *rest):
         rest = list(rest)
@@ -146,7 +166,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                 -1e30)
         dk = jax.random.wrap_key_data(kd) if kd is not None else None
         return _xla_attention(q, k, v, m, scale, is_causal, dropout_p, dk,
-                              return_lse)
+                              return_lse, window)
 
     extra = [attn_mask] if attn_mask is not None else []
     if key_set is not None:
@@ -157,7 +177,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     return (out[0], out[1].detach()) if return_lse else out
 
 
-def _flash_prim(qv, is_causal, scale):
+def _flash_prim(qv, is_causal, scale, window=None):
     """The flash-attention primitive for operands like `qv`.
 
     The interpret decision is resolved HERE, from the unwrapped value: its
@@ -176,7 +196,7 @@ def _flash_prim(qv, is_causal, scale):
     interp = _interpret(qv)
 
     def prim(q, k, v):
-        return _flash_attention_diff(q, k, v, is_causal, scale, interp)
+        return _flash_attention_diff(q, k, v, is_causal, scale, interp, window)
 
     mesh = operand_mesh(qv)
     if mesh is None:
@@ -239,8 +259,8 @@ def _flash_set_bwd(scale, interpret, res, g):
 _flash_set_diff.defvjp(_flash_set_fwd, _flash_set_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention_diff(q, k, v, is_causal, scale, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention_diff(q, k, v, is_causal, scale, interpret, window=None):
     """Pallas flash attention, forward AND backward.
 
     The forward saves only (q, k, v, out, lse); the backward re-forms each
@@ -250,21 +270,21 @@ def _flash_attention_diff(q, k, v, is_causal, scale, interpret):
     directions in tests/test_tpu_native.py (TestFlashAttentionBackward)."""
     from .pallas.flash_attention import flash_attention
     return flash_attention(q, k, v, causal=is_causal, scale=scale,
-                           interpret=interpret)
+                           interpret=interpret, window=window)
 
 
-def _flash_fwd(q, k, v, is_causal, scale, interpret):
+def _flash_fwd(q, k, v, is_causal, scale, interpret, window):
     from .pallas.flash_attention import flash_attention_fwd
     out, lse = flash_attention_fwd(q, k, v, causal=is_causal, scale=scale,
-                                   interpret=interpret)
+                                   interpret=interpret, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(is_causal, scale, interpret, res, g):
+def _flash_bwd(is_causal, scale, interpret, window, res, g):
     from .pallas.flash_attention import flash_attention_bwd
     q, k, v, out, lse = res
     return flash_attention_bwd(q, k, v, out, lse, g, causal=is_causal,
-                               scale=scale, interpret=interpret)
+                               scale=scale, interpret=interpret, window=window)
 
 
 _flash_attention_diff.defvjp(_flash_fwd, _flash_bwd)
@@ -282,7 +302,9 @@ def takes_flash(q_shape, k_shape, dtype, masked, dropout_p, platform,
     is no mask in this sense: the pair takes it by the same rule, except
     where the operands are spread over a mesh (`on_mesh`: the kernels over a
     set are not mapped over one), and XLA's attention reads it as a boolean
-    mask where the rule says no."""
+    mask where the rule says no. Nor is a window: the band is a second bound
+    on the causal grid, taken by the same rule as plain causal attention and
+    mapped over a mesh as it is (batch and heads split, the band whole)."""
     if (platform != "tpu" or masked or dropout_p > 0.0
             or (key_set and on_mesh)
             or q_shape[1] < FLASH_MIN_SEQ_Q

@@ -147,7 +147,7 @@ def _tiles_restored(x_t):
 # ---------------------------------------------------------------------------
 
 def _attn_fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, l_ref, *, scale, causal,
-                     block_k):
+                     block_k, window=None):
     # q_ref: (block_q, d); k_ref: (seq_k, d); vt_ref: (seq_k / block_k, d_v,
     # block_k), each value tile transposed; ot_ref: (d_v, block_q), the output
     # tile transposed; l_ref: (1, block_q), the logsumexp rows lane-dense.
@@ -173,7 +173,10 @@ def _attn_fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, l_ref, *, scale, causal,
                 jnp.int32, (block_k, block_q), 0)
             q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1)
-            s_t = jnp.where(q_pos >= k_pos, s_t, _MASKED)
+            keep = q_pos >= k_pos
+            if masked == "band":
+                keep = keep & (q_pos - k_pos < window)
+            s_t = jnp.where(keep, s_t, _MASKED)
         m_new = jnp.maximum(m_prev, jnp.max(s_t, axis=0, keepdims=True))
         p_t = jnp.exp(s_t - m_new)                      # (block_k, block_q)
         correction = jnp.exp(m_prev - m_new)
@@ -192,8 +195,19 @@ def _attn_fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, l_ref, *, scale, causal,
         clear = jnp.minimum((q_idx * block_q + 1) // block_k, num_k_blocks)
         last = jnp.minimum(((q_idx + 1) * block_q + block_k - 1) // block_k,
                            num_k_blocks)
+        first = 0
+        if window is not None:
+            # the band's lower edge: the tiles wholly under it are never
+            # visited, the ones it crosses build both masks (a short window
+            # lets the diagonal cross them too), and `clear` starts after them
+            first = jnp.maximum(q_idx * block_q - (window - 1), 0) // block_k
+            inner = jnp.clip((jnp.maximum((q_idx + 1) * block_q - window, 0)
+                              + block_k - 1) // block_k, first, last)
+            carry = jax.lax.fori_loop(
+                first, inner, lambda kb, c: step(kb, c, "band"), carry)
+            first, clear = inner, jnp.clip(clear, inner, last)
         carry = jax.lax.fori_loop(
-            0, clear, lambda kb, c: step(kb, c, False), carry)
+            first, clear, lambda kb, c: step(kb, c, False), carry)
         carry = jax.lax.fori_loop(
             clear, last, lambda kb, c: step(kb, c, True), carry)
     else:
@@ -206,8 +220,9 @@ def _attn_fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, l_ref, *, scale, causal,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
-                                             "block_k", "interpret"))
-def _flash_fwd_bh(q, k, v, causal, scale, block_q, block_k, interpret):
+                                             "block_k", "interpret", "window"))
+def _flash_fwd_bh(q, k, v, causal, scale, block_q, block_k, interpret,
+                  window=None):
     # q: (BH, S, D), k: (BH / group, S, D), v: (BH / group, S, Dv)
     # -> out (BH, S, Dv), lse (BH, S)
     bh, seq_q, d = q.shape
@@ -218,9 +233,10 @@ def _flash_fwd_bh(q, k, v, causal, scale, block_q, block_k, interpret):
             + 4 * block_q * block_k * 4)
     out_t, lse = pl.pallas_call(
         functools.partial(_attn_fwd_kernel, scale=scale, causal=causal,
-                          block_k=block_k),
+                          block_k=block_k, window=window),
         grid=(bh, tiles_q),
         interpret=interpret,
+        name=window and "flash_window_fwd",
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, seq_k, d), lambda b, i: (b // group, 0, 0)),
@@ -246,7 +262,7 @@ def _flash_fwd_bh(q, k, v, causal, scale, block_q, block_k, interpret):
 
 def _attn_bwd_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref, kt_ref,
                      dqt_ref, dk_ref, dv_ref, dqt_acc, *, scale, causal,
-                     block_q):
+                     block_q, window=None):
     # one key tile j of one key/value head, against one span of the group's
     # query rows. q_ref: (group, span, d); do_ref: (group, span, d_v); l_ref,
     # dd_ref: (group, span / block_q, block_q) float32, a query block a row;
@@ -293,7 +309,10 @@ def _attn_bwd_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref, kt_ref,
                 jnp.int32, (block_k, block_q), 0)
             q_pos = q_off + i * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1)
-            s_t = jnp.where(q_pos >= k_pos, s_t, _MASKED)
+            keep = q_pos >= k_pos
+            if masked == "band":
+                keep = keep & (q_pos - k_pos < window)
+            s_t = jnp.where(keep, s_t, _MASKED)
         p_t = jnp.exp(s_t - lse)                        # (block_k, block_q)
         dv = dv + jnp.dot(p_t.astype(do.dtype), do,
                           preferred_element_type=jnp.float32)
@@ -315,12 +334,28 @@ def _attn_bwd_kernel(q_ref, do_ref, l_ref, dd_ref, k_ref, v_ref, kt_ref,
             // block_q, first, num_q_blocks)
     else:
         first = clear = 0
+    end = num_q_blocks
+    if window is not None:
+        # the band's lower edge: the query blocks wholly past it are never
+        # visited; from `edge` on it crosses the blocks, which build both
+        # masks (a short window lets the diagonal cross them too)
+        end = jnp.clip(jnp.maximum(
+            (j + 1) * block_k + window - 2 - q_off + block_q, 0) // block_q,
+            first, num_q_blocks)
+        edge = jnp.maximum(j * block_k + window - q_off, 0) // block_q
+        clear = jnp.clip(jnp.minimum(clear, edge), first, end)
+        edge = jnp.clip(edge, clear, end)
 
     def head(h, carry):
         carry = jax.lax.fori_loop(
             first, clear, lambda i, c: pair(h, i, c, True), carry)
+        if window is None:
+            return jax.lax.fori_loop(
+                clear, end, lambda i, c: pair(h, i, c, False), carry)
+        carry = jax.lax.fori_loop(
+            clear, edge, lambda i, c: pair(h, i, c, False), carry)
         return jax.lax.fori_loop(
-            clear, num_q_blocks, lambda i, c: pair(h, i, c, False), carry)
+            edge, end, lambda i, c: pair(h, i, c, "band"), carry)
 
     zeros = jnp.zeros((block_k, d), jnp.float32)
     dk, dv = jax.lax.fori_loop(
@@ -363,9 +398,9 @@ def _bwd_q_span(group, seq_q, d, itemsize, block_q, d_v=None):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "interpret", "q_span"))
+    "causal", "scale", "block_q", "block_k", "interpret", "q_span", "window"))
 def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q, block_k,
-                  interpret, q_span=None):
+                  interpret, q_span=None, window=None):
     # q (BH, S, D), k (BH / group, S, D), v (BH / group, S, Dv), o and do
     # (BH, S, Dv), lse (BH, S); returns dq (BH, S, D) and dk, dv in k's and
     # v's shapes. `q_span` pins the rows resident a step (tests); None takes
@@ -402,9 +437,10 @@ def _flash_bwd_bh(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                           lambda r, c, j: (r, j, 0, 0))
     dq_t, dk, dv = pl.pallas_call(
         functools.partial(_attn_bwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q),
+                          block_q=block_q, window=window),
         grid=(rows_kv, spans, seq_k // block_k),
         interpret=interpret,
+        name=window and "flash_window_bwd",
         in_specs=[wide(d), wide(d_v), stat, stat, tile(d), tile(d_v), tile_t],
         out_specs=[pl.BlockSpec((group, blocks, d, block_q),
                                 lambda r, c, j: (r, c, 0, 0)),
@@ -457,58 +493,75 @@ def _from_bh(x, b, h):
     return jnp.swapaxes(x.reshape(b, h, s, d), 1, 2)
 
 
-def tiles(s_q, s_k):
+def tiles(s_q, s_k, window=None):
     """(block_q, block_k) of the forward kernel, which tiles q over the grid
     and loops the key tiles, and of the one-pass backward, which tiles k over
     the grid and loops the query blocks: a rule of shapes, the same on every
     platform and in every process (docs/kernels.md, "Tiles"), clamped to a
     divisor of the sequence, so short-seq callers (BERT s=128) get seq-sized
-    blocks."""
-    block_q = 2 * BLOCK if s_q >= LONG_SEQ_Q else BLOCK
-    return _clamp(block_q, s_q), _clamp(BLOCK, s_k)
+    blocks. Under a `window` shorter than the keys the query block stays at
+    BLOCK however long the row: a block of `block_q` queries visits the key
+    tiles that hold its window + block_q - 1 keys, so what a larger block
+    adds is work outside the band (docs/kernels.md, "The banded grid")."""
+    long_row = s_q >= LONG_SEQ_Q and band(window, s_k) is None
+    return _clamp(2 * BLOCK if long_row else BLOCK, s_q), _clamp(BLOCK, s_k)
+
+
+def band(window, s_k):
+    """The window the kernels are built for: None where there is none or it
+    is at least as long as the keys, which is the causal grid as it stands."""
+    return None if window is None or window >= s_k else int(window)
 
 
 def flash_attention(q, k, v, causal=False, scale=1.0,
-                    block_q=None, block_k=None, interpret=None):
+                    block_q=None, block_k=None, interpret=None, window=None):
     """q, k: (B, S, H, D), v: (B, S, H, Dv) -> (B, S, H, Dv). Forward only; use
     flash_attention_vjp for the Pallas-backward pair (attention.py wires it
     through jax.custom_vjp). interpret=None resolves per call from placement
     (_interpret); pass an explicit bool when the caller already resolved it
     (attention.py bakes it through the custom_vjp static args). block_q /
     block_k default to the rule's (`tiles`); pass explicit values to pin
-    them."""
+    them. `window` (with `causal`): query t reads the keys j with
+    t - window < j <= t, over the banded grid."""
     out, _ = flash_attention_fwd(q, k, v, causal, scale, block_q, block_k,
-                                 interpret)
+                                 interpret, window)
     return out
 
 
 def flash_attention_fwd(q, k, v, causal=False, scale=1.0,
-                        block_q=None, block_k=None, interpret=None):
+                        block_q=None, block_k=None, interpret=None,
+                        window=None):
     """Returns (out, lse) with lse (B, H, S) float32 — the residual the
     Pallas backward needs."""
     b, s, h, d = q.shape
     s_k = k.shape[1]
     interp = _interpret(q) if interpret is None else interpret
-    bq, bk = tiles(s, s_k)
+    window = band(window, s_k)
+    if window is not None and not causal:
+        raise ValueError("a window is a band under the causal diagonal")
+    bq, bk = tiles(s, s_k, window)
     out, lse = _flash_fwd_bh(_to_bh(q), _to_bh(k), _to_bh(v), causal, scale,
                              _clamp(block_q or bq, s), _clamp(block_k or bk, s_k),
-                             interp)
+                             interp, window=window)
     return _from_bh(out, b, h), lse.reshape(b, h, s)
 
 
 def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=1.0,
-                        block_q=None, block_k=None, interpret=None):
+                        block_q=None, block_k=None, interpret=None,
+                        window=None):
     """FlashAttention-2 backward in one pass: dq (B, S, H, D) and dk, dv in
     k's and v's shape and dtype. With no explicit blocks the kernel's
     (block_q, block_k) is the rule's (`tiles`); explicit values pin it."""
     b, s, h, d = q.shape
     s_k = k.shape[1]
     interp = _interpret(q) if interpret is None else interpret
-    bq, bk = tiles(s, s_k)
+    window = band(window, s_k)
+    bq, bk = tiles(s, s_k, window)
     dq, dk, dv = _flash_bwd_bh(
         _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(out),
         lse.reshape(b * h, s), _to_bh(do), causal, scale,
-        _clamp(block_q or bq, s), _clamp(block_k or bk, s_k), interp)
+        _clamp(block_q or bq, s), _clamp(block_k or bk, s_k), interp,
+        window=window)
     h_kv = k.shape[2]
     return (_from_bh(dq, b, h), _from_bh(dk, b, h_kv), _from_bh(dv, b, h_kv))
 

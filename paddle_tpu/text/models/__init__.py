@@ -12,6 +12,7 @@ from .keye_vl2 import (  # noqa: F401
 from .kimi_linear import (  # noqa: F401
     KimiLinearConfig, KimiLinearForCausalLM, KimiLinearModel,
 )
+from .laguna import LagunaConfig, LagunaForCausalLM, LagunaModel  # noqa: F401
 from .lfm2 import LFM2Config, LFM2ForCausalLM, LFM2Model  # noqa: F401
 
 __all__ = ["BertModel", "BertForSequenceClassification", "GPTModel",
@@ -20,4 +21,5 @@ __all__ = ["BertModel", "BertForSequenceClassification", "GPTModel",
            "LFM2ForCausalLM", "KimiLinearConfig", "KimiLinearModel",
            "KimiLinearForCausalLM", "KeyeVL2Config", "KeyeVL2Model",
            "KeyeVL2ForCausalLM", "DeepseekV2Config", "DeepseekV2Model",
-           "DeepseekV2ForCausalLM"]
+           "DeepseekV2ForCausalLM", "LagunaConfig", "LagunaModel",
+           "LagunaForCausalLM"]

@@ -28,6 +28,7 @@ SHAPES = [
     pytest.param(16, 16, 8192, 192, 128, "bfloat16", id="deepseek-v2-lite-s8192-d192-dv128"),
     pytest.param(32, 32, 2048, 192, 128, "bfloat16", id="latent-s2048-d192-dv128"),
     pytest.param(32, 4, 8192, 128, 128, "bfloat16", id="group8-s8192-d128"),
+    pytest.param(48, 8, 8192, 128, 128, "bfloat16", id="laguna-full-group6-s8192-d128"),
     pytest.param(8, 2, 16384, 64, 64, "bfloat16", id="group4-s16384-d64-in-spans"),
     pytest.param(2, 2, 32768, 128, 128, "bfloat16", id="s32768-d128-in-spans"),
     pytest.param(8, 8, 768, 64, 64, "bfloat16", id="s768-a-length-512-does-not-divide"),
@@ -68,14 +69,15 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile_at_the_rules_tiles(one_chip, backward, bh, rows_kv, s, d, d_v, dtype):
+def _compile_at_the_rules_tiles(one_chip, backward, bh, rows_kv, s, d, d_v, dtype,
+                                window=None):
     from paddle_tpu.ops.pallas import flash_attention as fa
     q = _sds((bh, s, d), dtype, one_chip)
     k = _sds((rows_kv, s, d), dtype, one_chip)
     v = _sds((rows_kv, s, d_v), dtype, one_chip)
-    block_q, block_k = fa.tiles(s, s)
+    block_q, block_k = fa.tiles(s, s, window)
     kw = dict(causal=True, scale=d ** -0.5, block_q=block_q, block_k=block_k,
-              interpret=False)
+              interpret=False, window=window)
     if not backward:
         return fa._flash_fwd_bh.lower(q, k, v, **kw).compile()
     o = _sds((bh, s, d_v), dtype, one_chip)
@@ -116,6 +118,22 @@ def test_the_rules_tiles_compile(one_chip, bh, rows_kv, s, d, d_v, dtype, backwa
     slice off the tiling) fails here, without a chip, and the rule changes."""
     _assert_kernel(_compile_at_the_rules_tiles(
         one_chip, backward, bh, rows_kv, s, d, d_v, dtype), 1)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+@pytest.mark.parametrize("bh, rows_kv, s, window", [
+    pytest.param(64, 8, 8192, 512, id="laguna-window-group8-s8192-w512"),
+    pytest.param(12, 2, 4096, 100, id="group6-s4096-a-window-shorter-than-a-tile"),
+    pytest.param(8, 8, 2048, 1536, id="s2048-a-window-of-three-tiles"),
+])
+def test_the_banded_grid_compiles(one_chip, bh, rows_kv, s, window, backward):
+    """The flash pair with a window, at the banded rule's tiles: Mosaic takes
+    the loops that start at the band's edge and the second mask, and the
+    kernels carry their own names into the compiled program."""
+    compiled = _compile_at_the_rules_tiles(
+        one_chip, backward, bh, rows_kv, s, 128, 128, "bfloat16", window)
+    _assert_kernel(compiled, 1)
+    assert ("flash_window_bwd" if backward else "flash_window_fwd") in compiled.as_text()
 
 
 def test_public_vjp_pair_compiles_at_gpt1p3b_widths(one_chip):
